@@ -18,6 +18,11 @@ RUN pip install --no-cache-dir /wheels/*.whl
 COPY --from=build /src/native/build/libkueue_native.so \
     /usr/local/lib/kueue_tpu/libkueue_native.so
 ENV KUEUE_TPU_NATIVE_LIB=/usr/local/lib/kueue_tpu/libkueue_native.so
+# The package is installed, not a checkout, so the compile cache's
+# default (<checkout>/.jax_cache, kueue_tpu/utils/startup.py) would land
+# in site-packages and die with the container. deploy/ mounts a volume
+# here for both the oracle and the engine.
+ENV JAX_COMPILATION_CACHE_DIR=/var/cache/kueue-tpu/jax
 # The oracle serving boundary (snapshot-in / verdicts-out). Bind all
 # interfaces so the published port actually reaches the service.
 EXPOSE 7461

@@ -39,6 +39,9 @@ test-device: native
 	  tests/test_multichip_parity.py $(PYTEST_FLAGS)
 
 # The perf suite (BASELINE.json configs 2-5); FAST=1 for a smoke run.
+# Needs a TPU and fails without one; `JAX_PLATFORMS=cpu make bench`
+# rehearses on the CPU and stamps every row `cpu`. The quickest proof
+# that the main path runs on the chip is `python3 chip_smoke.py`.
 bench:
 	$(PY) bench.py
 

@@ -763,6 +763,23 @@ class Engine:
         executor = None
         if remote_address is not None:
             from kueue_tpu.oracle.service import RemoteExecutor
+
+            # A chip belongs to one process, and in this layout that is
+            # the oracle service. The bridge still builds its cycle
+            # inputs (and runs sim nomination and TAS planning) with
+            # JAX, so this process pins itself to the CPU platform —
+            # before any backend starts — instead of opening the chip
+            # the sidecar beside it needs. Once a backend has started
+            # the pin is a silent no-op, so the result is checked (the
+            # bridge starts the backend at its first cycle anyway).
+            jax.config.update("jax_platforms", "cpu")
+            if jax.default_backend() != "cpu":
+                raise RuntimeError(
+                    "attach_oracle(remote_address=...) must keep this "
+                    "process on the CPU platform, but JAX's "
+                    f"{jax.default_backend()!r} backend had already "
+                    "started: attach the remote oracle before anything "
+                    "touches JAX, or start with JAX_PLATFORMS=cpu.")
             executor = RemoteExecutor(*remote_address)
         self.oracle = OracleBridge(self, max_depth=max_depth,
                                    executor=executor)

@@ -16,12 +16,9 @@ single None-check.
 
 from __future__ import annotations
 
-from kueue_tpu.obs import hooks
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
-try:  # pragma: no cover - import guard exercised only without jax
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # noqa: BLE001 — jax absent or too old
-    _TraceAnnotation = None
+from kueue_tpu.obs import hooks
 
 
 class PhaseAnnotator:
@@ -32,8 +29,7 @@ class PhaseAnnotator:
     def __init__(self) -> None:
         # Latched at cycle start: a tracer that detaches mid-cycle must
         # not leave a dangling open scope.
-        self._enabled = (_TraceAnnotation is not None
-                         and hooks.CURRENT is not None)
+        self._enabled = hooks.CURRENT is not None
         self._cur = None
 
     def phase(self, name: str) -> None:

@@ -14,17 +14,28 @@ Each kernel keeps the whole problem resident in VMEM (a 50k-workload rank
 vector is ~200 KB — the scheduler's "model" is tiny by TPU standards) and
 folds the big axis tile-by-tile with an in-kernel fori_loop, producing the
 whole reduction in one fused kernel with no HBM round-trips for the
-accumulator. Kernels are written gridless because the deployment target's
-Mosaic toolchain rejects grid-partitioned pallas_calls (func.return
-legalization); the fori_loop formulation compiles everywhere.
+accumulator. The kernels are gridless, with int32 loop bounds and selects
+written as mask multiplies. Asked of the installed compiler (JAX 0.9.0,
+libtpu 0.0.34, v5e, under jax_enable_x64 — PR 22): both kernels compile
+as written; a grid-partitioned pallas_call compiles too, provided its
+index maps return int32 (a Python ``0`` there becomes an i64 result,
+which Mosaic refuses to legalize in ``func.return`` — the failure once
+put down to grids as such); Python-int loop bounds compile; bool selects
+compile, and what recurses in lowering is a bare Python scalar as an
+operand of ``jnp.where`` inside the kernel (64-bit under x64), not the
+select. So the gridless mask-multiply form is a choice that still
+compiles, not a necessity; changing it is a performance question.
 
-On non-TPU backends (tests, CPU fallback) the kernels run in interpreter
-mode or fall through to the jnp reference implementations; numerical
-parity is enforced by tests/test_pallas_kernels.py.
+Which implementation serves is never silent on the chip: chip_smoke.py
+fails there unless `pallas_enabled()` is true and `_interpret()` false,
+checks the compiled cycle program for its `tpu_custom_call`, and prints
+which leaf-count path ran. tests/test_tpu_compile.py keeps both kernels
+compiling for the chip; numerical parity with the jnp references is
+tests/test_pallas_kernels.py (interpret mode, CPU).
 
-Dispatch: `pallas_enabled()` — on by default on TPU backends, forced
-on/off with KUEUE_TPU_PALLAS=1/0 (interpret mode is used automatically
-when the backend is not TPU).
+Dispatch: `pallas_enabled()` — on when the default backend is a TPU,
+forced on/off with KUEUE_TPU_PALLAS=1/0; off it, the kernels run in
+interpret mode (tests) or give way to the jnp reference implementations.
 """
 
 from __future__ import annotations
@@ -71,8 +82,8 @@ def _make_heads_kernel(c_pad: int):
             return jnp.minimum(acc, jnp.min(vals, axis=0))
 
         init = jnp.full((c_pad,), INT32_BIG, jnp.int32)
-        # int32 loop bounds: an int64 induction variable trips the
-        # deployment Mosaic's lowering under jax_enable_x64.
+        # int32 loop bounds, spelled out: under jax_enable_x64 a Python
+        # int would be 64-bit. (The installed compiler takes either.)
         out_ref[0, :] = jax.lax.fori_loop(jnp.int32(0), jnp.int32(n_tiles),
                                           body, init)
 
@@ -124,9 +135,9 @@ def _leaf_kernel(free_ref, used_ref, req_ref, div_ref, anyreq_ref, mask_ref,
     """free/used: int32[L_pad, S_pad]; req (0/1), div: int32[1, S_pad];
     anyreq: int32[1, 1]; mask (0/1) / out: int32[n_tiles, TILE_W].
 
-    Pure int32 arithmetic — the deployment Mosaic recurses lowering
-    bool<->int converts inside fori_loop bodies, so selects are expressed
-    as mask multiplies.
+    Pure int32 arithmetic, selects expressed as mask multiplies. (With
+    the installed compiler ``jnp.where`` lowers here as well, as long as
+    every operand is typed int32 — see the module docstring.)
     """
     from jax.experimental import pallas as pl
 

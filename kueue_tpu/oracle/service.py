@@ -29,6 +29,7 @@ same jit cache when local).
 
 from __future__ import annotations
 
+import json
 import os
 import socket
 import threading
@@ -260,9 +261,17 @@ def main(argv=None) -> None:
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
     jax.config.update("jax_enable_x64", True)
+    from kueue_tpu.utils.startup import (
+        configure_compile_cache,
+        device_stamp,
+    )
+    # Start the backend now: a service that cannot have its device fails
+    # here, not at the first request, and says which device it holds.
+    device = device_stamp()
+    configure_compile_cache()
     server = OracleServer(args.host, args.port, fault_after=fault_after)
     print(f"oracle service listening on {server.address[0]}:"
-          f"{server.address[1]}", flush=True)
+          f"{server.address[1]} device={json.dumps(device)}", flush=True)
     server.serve_forever()
 
 

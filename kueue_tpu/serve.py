@@ -107,6 +107,23 @@ def _attach_overload(eng, args) -> None:
     attach_ladder(eng)
 
 
+def attach_oracle(eng, spec: str) -> None:
+    """``--oracle``: "local" (in-process), "off", or the "host:port" of
+    an oracle service. With an oracle the process compiles device
+    programs, so the persistent compile cache goes on — a restarted
+    server then recompiles no bucket — once the platform is settled,
+    which for a remote oracle happens inside attach_oracle."""
+    if spec == "off":
+        return
+    if spec == "local":
+        eng.attach_oracle()
+    else:
+        host, _, port = spec.rpartition(":")
+        eng.attach_oracle(remote_address=(host or "127.0.0.1", int(port)))
+    from kueue_tpu.utils.startup import configure_compile_cache
+    configure_compile_cache()
+
+
 def main(argv=None) -> None:
     import argparse
 
@@ -229,11 +246,7 @@ def main(argv=None) -> None:
         Checkpointer(eng, interval=args.checkpoint_interval,
                      keep=args.checkpoint_keep,
                      min_free_bytes=args.min_free_bytes)
-    if args.oracle == "local":
-        eng.attach_oracle()
-    elif args.oracle != "off":
-        host, _, port = args.oracle.rpartition(":")
-        eng.attach_oracle(remote_address=(host or "127.0.0.1", int(port)))
+    attach_oracle(eng, args.oracle)
     _attach_overload(eng, args)
 
     recorder = None
@@ -445,12 +458,7 @@ def _main_ha(args) -> None:
         # SLO engine (drives the shedder's refill factor), tracer,
         # flight recorder, and the fault plan (which needs engine.ha —
         # already set by the promotion protocol).
-        if args.oracle == "local":
-            eng.attach_oracle()
-        elif args.oracle != "off":
-            host, _, port = args.oracle.rpartition(":")
-            eng.attach_oracle(
-                remote_address=(host or "127.0.0.1", int(port)))
+        attach_oracle(eng, args.oracle)
         from kueue_tpu.obs.slo import attach_slo
         attach_slo(eng)
         if shedder is not None:

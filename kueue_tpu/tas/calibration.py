@@ -120,16 +120,13 @@ def device_placement_wins(snap) -> bool:
     launch beats the host descent for this forest's shape on the
     current backend. False with no record — callers keep the host
     path, matching the old DEVICE_TAS_MIN_DOMAINS default."""
+    import jax
+
     if not snap.level_keys:
         return False
     nl = len(snap.level_keys)
     leaves = len(snap.domains_per_level[nl - 1])
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - jax always present in-tree
-        return False
-    entry = lookup(backend, nl, leaves)
+    entry = lookup(jax.default_backend(), nl, leaves)
     if entry is None:
         return False
     return entry["device_place_ms"] < entry["host_place_ms"]

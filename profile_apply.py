@@ -13,19 +13,18 @@ import pstats
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_enable_x64", True)
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-
 
 def main():
+    # A host-time profiler (cProfile): pinned to the CPU backend.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    from kueue_tpu.utils.startup import configure_compile_cache
+
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_enable_x64", True)
+    configure_compile_cache()
+
     n_workloads = int(os.environ.get("PROF_WORKLOADS", "50000"))
     n_cohorts = int(os.environ.get("PROF_COHORTS", "200"))
     n_cycles = int(os.environ.get("PROF_CYCLES", "4"))

@@ -1,0 +1,124 @@
+"""The chip's compiler, asked without the chip: the programs of the main
+path must keep compiling for a v5e at the shapes chip_smoke.py runs them
+with. Nothing executes here — a compile that passes is not a chip run —
+but what the TPU compiler refuses (a misaligned slice, too much VMEM, an
+op Mosaic cannot lower) it refuses here, at no chip time.
+
+The topology is described inside the module-scoped fixture, never at
+import and never in conftest.py: describing it loads libtpu, which one
+process at a time may hold, and every xdist worker imports this file.
+Keep these tests in this one file, and compile in the test's own
+process."""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import SingleDeviceSharding
+
+from kueue_tpu.ops import pallas_kernels as pk
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    env = pytest.MonkeyPatch()
+    env.setenv("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises
+        env.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A TPU executable written to the persistent cache cannot be read
+    # back without a chip (the next run would warn and recompile), so
+    # the cache is off around these compiles.
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+    jax.clear_caches()  # no chip-only trace may outlive this module
+    env.undo()
+
+
+@pytest.fixture
+def for_chip(monkeypatch):
+    """What the kernels' dispatch would see on the chip. The code asks
+    jax.default_backend(), which is the CPU here, so the test steers
+    it — and drops traces made for the CPU, which a lowering with the
+    same shapes would otherwise reuse."""
+    monkeypatch.setenv("KUEUE_TPU_PALLAS", "1")
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    jax.clear_caches()
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("W,C", [(65_536, 1_000), (4_096, 100)])
+def test_heads_kernel_compiles(one_chip, for_chip, W, C):
+    """W: chip_smoke's flat and preempt_churn buckets."""
+    compiled = pk._heads_pallas.lower(
+        jax.ShapeDtypeStruct((W,), np.int64, sharding=one_chip),
+        jax.ShapeDtypeStruct((W,), np.int32, sharding=one_chip),
+        num_cqs=C).compile()
+    assert _mosaic_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("leaves", [640, 5_120])
+def test_leaf_kernel_compiles(one_chip, for_chip, leaves):
+    quantities = jax.ShapeDtypeStruct((leaves, 2), np.int64,
+                                      sharding=one_chip)
+    compiled = pk._leaf_pallas.lower(
+        quantities, quantities,
+        jax.ShapeDtypeStruct((2,), np.int64, sharding=one_chip),
+        jax.ShapeDtypeStruct((leaves,), np.bool_, sharding=one_chip),
+    ).compile()
+    assert _mosaic_calls(compiled) == 1
+
+
+def test_flat_cycle_program_compiles_as_the_bridge_calls_it(
+        one_chip, for_chip, monkeypatch):
+    """The BASELINE world, 50,000 pending x 1,000 ClusterQueues: one real
+    schedule_once() on the CPU shows what the bridge hands its executor
+    (pow2 bucket and all); that very signature is then compiled for the
+    chip."""
+    from kueue_tpu.bench.scenario import baseline_like
+    from kueue_tpu.oracle import batched
+
+    monkeypatch.syspath_prepend(REPO)
+    import bench
+
+    eng = bench.build_cycle_engine(
+        baseline_like(n_cohorts=200, n_workloads=50_000))
+
+    class Seen(Exception):
+        pass
+
+    def cycle_step(tensors, statics):
+        # The shapes are all this test needs of the cycle: leaving here
+        # saves compiling and running it for the CPU as well.
+        raise Seen(tensors, statics)
+
+    eng.oracle.executor.cycle_step = cycle_step
+    with pytest.raises(Seen) as seen:
+        eng.schedule_once()
+    tensors, statics = seen.value.args
+    assert tensors["pending"].shape == (65_536,)
+    assert statics["num_cqs"] == 1_000
+
+    compiled = batched.cycle_step.lower(
+        **{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+           for k, v in tensors.items()}, **statics).compile()
+    assert _mosaic_calls(compiled) == 1  # the heads kernel, in the program
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 16 * 2**30  # one v5e chip's HBM

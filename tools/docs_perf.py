@@ -117,12 +117,6 @@ def render(name: str, data: dict) -> str:
                 lines.append(f"| {key} | {m.group(1)} | {m.group(2)} |")
             else:
                 lines.append(f"| {key} | {values[key]} | |")
-    if trailer.get("tpu_recheck") and isinstance(
-            trailer["tpu_recheck"], dict) \
-            and trailer["tpu_recheck"].get("values"):
-        lines.append("")
-        lines.append("Late-round TPU re-probe recovered; TPU-stamped "
-                     f"recheck: `{trailer['tpu_recheck']}`")
     lines.append("")
     lines.append(END)
     return "\n".join(lines)
