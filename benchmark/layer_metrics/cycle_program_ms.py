@@ -1,0 +1,12 @@
+"""The cycle program on the device: device time of the `_cycle_core`
+program (XLA Modules line of the trace), mean per launch."""
+
+
+def reduce(trace, spans, counters):
+    if trace is None:
+        return None
+    names = [k for k in trace["module_s"] if "_cycle_core" in k]
+    n = sum(trace["module_n"][k] for k in names)
+    if not n:
+        return None
+    return sum(trace["module_s"][k] for k in names) * 1e3 / n
