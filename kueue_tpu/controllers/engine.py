@@ -40,6 +40,12 @@ from kueue_tpu.scheduler.cycle import (
     SchedulerCycle,
 )
 from kueue_tpu.obs import perf as _perf
+from kueue_tpu.obs.span import (
+    SpanRecorder,
+    close_phases,
+    leaf_phases,
+    phase_seconds,
+)
 from kueue_tpu.workload_info import WorkloadInfo, admission_from_assignment
 
 
@@ -153,12 +159,11 @@ class Engine:
         self.cycle.namespace_labels_of = \
             lambda ns: self.namespace_labels.get(ns)
         self.clock: float = 0.0
-        # Wall-clock source for phase timing / metrics. Purely
-        # observational (never feeds a decision); the simulator
-        # (kueue_tpu/sim) injects its virtual clock here so phase
-        # histograms stay deterministic under time compression.
-        import time as _time
-        self.wall_clock: Callable[[], float] = _time.perf_counter
+        # The span recorder (obs/span.py): one real span tree per
+        # schedule_once(), always on; last_cycle_phases is derived from
+        # it. Write-only from here down: decision code opens and closes
+        # spans and never reads them. Its clock is ``wall_clock``.
+        self.spans = SpanRecorder()
         self.events: list[EngineEvent] = []
         # Watch fan-out (client-go informer analog): called with each
         # EngineEvent as it is recorded.
@@ -179,8 +184,9 @@ class Engine:
         # First-eviction-per-workload tracking
         # (evicted_workloads_once_total, metrics.go:666).
         self._evicted_once: set[str] = set()
-        # Last cycle's phase durations (scheduler.go:291-358 logs these;
-        # the debugger/dashboard surface them here).
+        # The last deciding cycle's phase durations, seconds, derived
+        # from its span tree (obs.span.phase_seconds; scheduler.go:
+        # 291-358 logs these; the debugger/dashboard surface them here).
         self.last_cycle_phases: dict[str, float] = {}
         # Which path decided the last cycle: "sequential", "device", or
         # "hybrid" (device roots + host tail).
@@ -780,7 +786,7 @@ class Engine:
                     f"{jax.default_backend()!r} backend had already "
                     "started: attach the remote oracle before anything "
                     "touches JAX, or start with JAX_PLATFORMS=cpu.")
-            executor = RemoteExecutor(*remote_address)
+            executor = RemoteExecutor(*remote_address, spans=self.spans)
         self.oracle = OracleBridge(self, max_depth=max_depth,
                                    executor=executor)
 
@@ -801,59 +807,100 @@ class Engine:
         with device_trace(trace_dir or None):
             yield
 
+    @property
+    def wall_clock(self) -> Callable[[], float]:
+        """Wall-clock source for phase timing / metrics: the span
+        recorder's clock. Purely observational (never feeds a
+        decision); the simulator (kueue_tpu/sim) assigns its virtual
+        clock here so spans, and the phase histograms derived from
+        them, stay deterministic under time compression."""
+        return self.spans.clock
+
+    @wall_clock.setter
+    def wall_clock(self, clock: Callable[[], float]) -> None:
+        self.spans.set_clock(clock)
+
     def schedule_once(self) -> Optional[CycleResult]:
         """One schedule() cycle (scheduler.go:286), bracketed by the
         replay capture points: pre_cycle_hooks before (fault injection
         lands here), then the cycle, then the journal's crash-safe
         cycle-boundary sync, then cycle_listeners (the flight recorder's
-        decision-stream capture)."""
+        decision-stream capture). The whole of it is one span tree
+        (obs/span.py), from which last_cycle_phases is derived: set
+        before the listeners run, completed when the root closes."""
         seq = self.cycle_seq
-        for fn in tuple(self.pre_cycle_hooks):
-            fn(seq, self)
-        writable = getattr(self.journal, "writable", None)
-        if writable is not None and not writable():
-            # Disk budget exhausted (store/diskguard.py): scheduling
-            # would admit workloads the journal cannot record. Park
-            # this cycle as idle — seq still advances, listeners (the
-            # degradation ladder, the watchdog) still run, and the
-            # writable() probe re-arms the budget and resumes
-            # scheduling the moment the filesystem has headroom.
-            result = None
-        elif not self._serving_gc:
-            result = self._schedule_once_impl()
-        else:
-            try:
+        spans = self.spans
+        phases = None
+        with spans.span("schedule_once", seq=seq) as root:
+            spans.begin("pre_hooks")
+            for fn in tuple(self.pre_cycle_hooks):
+                fn(seq, self)
+            spans.end()
+            writable = getattr(self.journal, "writable", None)
+            if writable is not None and not writable():
+                # Disk budget exhausted (store/diskguard.py): scheduling
+                # would admit workloads the journal cannot record. Park
+                # this cycle as idle — seq still advances, listeners (the
+                # degradation ladder, the watchdog) still run, and the
+                # writable() probe re-arms the budget and resumes
+                # scheduling the moment the filesystem has headroom.
+                result = None
+            elif not self._serving_gc:
                 result = self._schedule_once_impl()
-            finally:
-                # Serving GC posture: automatic collection is off; sweep
-                # the young generation and re-freeze survivors after
-                # EVERY cycle — device, hybrid, and sequential-fallback
-                # alike (see apply_serving_gc_posture).
-                import gc
-                gc.collect(0)
-                gc.freeze()
-        self.cycle_seq = seq + 1
-        if result is not None and self.journal is not None:
-            # pre_sync_hooks append records that must be durably part
-            # of THIS cycle (the HA ha_digest checkpoint): they run
-            # before sync so the fsync below covers them.
-            for fn in tuple(self.pre_sync_hooks):
+            else:
                 try:
-                    fn(seq, result)
-                except Exception as e:  # noqa: BLE001 — observers must
-                    import warnings      # not unwind the scheduling loop
-                    warnings.warn(f"pre-sync hook {fn!r} raised: {e!r}")
-            # Crash-safe cycle boundary: every record this cycle wrote
-            # (admissions, evictions, requeues) reaches the platter
-            # before the decisions take further effect — a SIGKILL
-            # between cycles can never lose an applied admission.
-            self.journal.sync()
-        for fn in tuple(self.cycle_listeners):
-            try:
-                fn(seq, result)
-            except Exception as e:  # noqa: BLE001 — observers must not
-                import warnings      # unwind the scheduling loop
-                warnings.warn(f"cycle listener {fn!r} raised: {e!r}")
+                    result = self._schedule_once_impl()
+                finally:
+                    # Serving GC posture: automatic collection is off;
+                    # sweep the young generation and re-freeze survivors
+                    # after EVERY cycle — device, hybrid, and
+                    # sequential-fallback alike (see
+                    # apply_serving_gc_posture).
+                    import gc
+                    with spans.span("gc_sweep"):
+                        gc.collect(0)
+                        gc.freeze()
+            self.cycle_seq = seq + 1
+            if result is not None and self.journal is not None:
+                with spans.span("journal_sync"):
+                    # pre_sync_hooks append records that must be durably
+                    # part of THIS cycle (the HA ha_digest checkpoint):
+                    # they run before sync so the fsync below covers
+                    # them.
+                    for fn in tuple(self.pre_sync_hooks):
+                        try:
+                            fn(seq, result)
+                        except Exception as e:  # noqa: BLE001 — observers
+                            import warnings      # must not unwind the loop
+                            warnings.warn(
+                                f"pre-sync hook {fn!r} raised: {e!r}")
+                    # Crash-safe cycle boundary: every record this cycle
+                    # wrote (admissions, evictions, requeues) reaches the
+                    # platter before the decisions take further effect —
+                    # a SIGKILL between cycles can never lose an applied
+                    # admission.
+                    self.journal.sync()
+            if result is not None:
+                root.attrs["mode"] = self.last_cycle_mode
+                phases = self.last_cycle_phases = phase_seconds(root)
+            with spans.span("listeners"):
+                for fn in tuple(self.cycle_listeners):
+                    try:
+                        fn(seq, result)
+                    except Exception as e:  # noqa: BLE001 — observers must
+                        import warnings      # not unwind the scheduling loop
+                        warnings.warn(
+                            f"cycle listener {fn!r} raised: {e!r}")
+        if phases is not None:
+            close_phases(phases, root)
+            # The leaves, which add up to the whole, and the whole; no
+            # aggregate key, so a sum over the leaves counts no time
+            # twice.
+            observe = self.registry.histogram(
+                "scheduler_phase_duration_seconds").observe
+            for phase, dur in leaf_phases(phases).items():
+                observe(dur, (phase,))
+            observe(phases["schedule_once"], ("schedule_once",))
         return result
 
     def _schedule_once_impl(self) -> Optional[CycleResult]:
@@ -909,8 +956,13 @@ class Engine:
         if count_cycle:
             self.metrics.admission_cycles += 1
             self.last_cycle_mode = "sequential"
+        # snapshot / decide / apply (scheduler.go:291-358 logs these
+        # splits): leaves of the cycle's tree on the sequential path,
+        # detail under host_tail in a hybrid cycle.
+        spans = self.spans
+        spans.begin("snapshot")
         snapshot = self.cache.snapshot()
-        t_snap = self.wall_clock()
+        spans.next("decide")
         already = set(self.cache.workloads)
         try:
             result = self.cycle.schedule(heads, snapshot, now=self.clock,
@@ -920,7 +972,7 @@ class Engine:
             # live forests BEFORE the apply loop commits the assumed
             # entries through the cache (tas/snapshot.py begin_cycle).
             snapshot.close()
-        t_decide = self.wall_clock()
+        spans.next("apply")
         deferred: set = set()
         self._deferred_cohort_requeue = deferred
         try:
@@ -943,20 +995,7 @@ class Engine:
             m[cq_name] = m.get(cq_name, 0) + skips
             self.registry.counter("admission_cycle_preemption_skips").inc(
                 (cq_name,), skips)
-        # Per-phase durations (scheduler.go:291-358 logs snapshot/
-        # nominate/commit splits; the debugger shows where a slow cycle
-        # went). Gated on count_cycle: a hybrid cycle's host tail must
-        # not overwrite the bridge's encode/device/apply record.
-        if count_cycle:
-            t_apply = self.wall_clock()
-            phases = {"snapshot": t_snap - t0,
-                      "decide": t_decide - t_snap,
-                      "apply": t_apply - t_decide}
-            self.last_cycle_phases = phases
-            for phase, dur in phases.items():
-                self.registry.histogram(
-                    "scheduler_phase_duration_seconds").observe(
-                    dur, (phase,))
+        spans.end()
         if count_cycle:
             outcome = "success" if result.assumed else "inadmissible"
             self.registry.report_admission_attempt(

@@ -194,7 +194,8 @@ class MetricsRegistry:
         c("admission_cycle_preemption_skips",
           "preemptions skipped per cycle per CQ")
         h("scheduler_phase_duration_seconds",
-          "per-cycle phase durations (snapshot|decide|apply|encode|device)")
+          "per-schedule_once() durations by leaf span (obs/span.py), "
+          "which add up to phase=schedule_once")
         # workload lifecycle
         c("quota_reserved_workloads_total", "per CQ")
         h("quota_reserved_wait_time_seconds", "queued->reserved per CQ")

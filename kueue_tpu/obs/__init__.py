@@ -1,10 +1,21 @@
 """Admission tracing and decision explainability.
 
-The substrate every perf/debug story builds on: per-cycle span trees
-with structured decision rationale (obs.tracer), cheap rationale hooks
-for the decision path (obs.hooks), Chrome/Perfetto export
-(obs.perfetto), ``kueuectl explain`` (obs.explain), and device-path
-named scopes that line host spans up with XLA profiles (obs.device).
+One recorder times the engine: ``obs.span.SpanRecorder`` (``eng.spans``,
+always on) keeps a real span tree per ``schedule_once()`` — pre_hooks,
+cycle (take_speculation, host_encode > tas_place, upload, dispatch,
+device_wait, readback, verdict_decode, apply, finalize, host_tail),
+snapshot / decide / apply on the sequential path, speculate, gc_sweep,
+journal_sync, listeners — each span also a ``kueue.<name>``
+``jax.profiler.TraceAnnotation``, so a profiler capture holds the same
+tree beside the device's operations. ``Engine.last_cycle_phases`` is
+derived from it.
+
+On top of it: per-cycle trees with structured decision rationale
+(obs.tracer.CycleTracer, which adopts the recorder's spans, so the
+timestamps at ``/debug/trace`` and in a Perfetto export are the ones the
+work was timed with), cheap rationale hooks for the decision path
+(obs.hooks), Chrome/Perfetto export (obs.perfetto) and ``kueuectl
+explain`` (obs.explain).
 """
 
 from kueue_tpu.obs import hooks
@@ -16,7 +27,7 @@ from kueue_tpu.obs.perfetto import (
     write_perfetto,
 )
 from kueue_tpu.obs.slo import SLO, SLOEngine, attach_slo
-from kueue_tpu.obs.span import Span, correlation_id
+from kueue_tpu.obs.span import Span, SpanRecorder, correlation_id
 from kueue_tpu.obs.tracer import CycleTracer
 
 
@@ -36,6 +47,7 @@ __all__ = [
     "SLO",
     "SLOEngine",
     "Span",
+    "SpanRecorder",
     "attach_perf",
     "attach_slo",
     "attach_tracer",

@@ -24,7 +24,7 @@ from __future__ import annotations
 import json
 from typing import Iterable
 
-from kueue_tpu.obs.span import Span, correlation_id
+from kueue_tpu.obs.span import Span, correlation_id, leaf_phases
 
 PID = 1
 TID_CYCLES = 1
@@ -77,7 +77,9 @@ def spans_from_flight_trace(path: str) -> list[Span]:
             continue
         seq = frame["seq"]
         decisions = frame.get("decisions", [])
-        phases = frame.get("phases", {})
+        # Leaf phases only: the frame's dict also carries keys that
+        # repeat their time (obs.span.AGGREGATE_KEYS).
+        phases = leaf_phases(frame.get("phases", {}))
         total = sum(phases.values()) * 1e6
         ts = frame.get("clock", 0.0) * 1e6
         cid = frame.get("cid") or correlation_id(seq, decisions)

@@ -1,19 +1,59 @@
-"""Span model for admission tracing.
+"""Spans: the one timing mechanism of the engine, and the span model
+the admission tracer serves.
 
-A scheduling cycle becomes a tree of spans:
+**SpanRecorder** (one per engine, ``eng.spans``; always on) records a
+real span tree per ``Engine.schedule_once()``, where the work happens:
+
+    schedule_once                 controllers/engine.py — attrs seq, mode
+    ├─ pre_hooks
+    ├─ cycle                      oracle bridge: try_cycle; attrs
+    │  │                          lattice, if it launched (as speculate)
+    │  ├─ take_speculation        attrs outcome = used | discarded | none
+    │  ├─ host_encode             _encode_cycle up to the device cycle
+    │  │  └─ tas_place            (attrs heads, pending)
+    │  ├─ upload                  host arrays -> device (attrs bytes)
+    │  ├─ dispatch                cycle_step(...) returning futures
+    │  ├─ device_wait             block_until_ready on the outputs
+    │  ├─ readback                np.asarray of the outputs (attrs bytes)
+    │  ├─ verdict_decode          attrs lattice (the branch of the
+    │  │                          launch that served it), device_heads
+    │  ├─ apply · finalize
+    │  └─ host_tail               hybrid cycles only
+    ├─ snapshot · decide · apply  sequential path (no bridge; fallback)
+    ├─ speculate                  next cycle's encode + launch; same
+    │                             children as ``cycle`` up to readback;
+    │                             attrs lattice (whether its launch took
+    │                             the preemptor's branch; None where the
+    │                             verdicts cannot tell), and outcome
+    │                             once the next cycle learns it
+    └─ gc_sweep · journal_sync · listeners
+
+Every span carries name, start and duration on the recorder's clock
+(``perf_counter`` unless the simulator injects its own through
+``Engine.wall_clock``), its children, and counts taken at the same
+boundary as attrs; the root's ``seq`` is the id all of a cycle's spans
+share. Entering a span also enters ``jax.profiler.TraceAnnotation(
+"kueue.<name>")`` (a flag check while no profiler session is open), so
+a profiler capture holds the same tree on the device trace's clock with
+no switch to flip. ``phase_seconds`` turns a tree into
+``Engine.last_cycle_phases``: seconds by leaf name, and the counts its
+attrs hold (COUNT_KEYS).
+
+**CycleTracer** (obs/tracer.py, attached on demand) serves per-cycle
+trees with decisions and rationale:
 
     cycle/<seq>                      (kind="cycle")
-    ├── phase/snapshot ...           (kind="phase"; sequential path)
-    ├── phase/decide
-    │   (device cycles: encode/device/apply/finalize instead)
-    ├── phase/apply
+    ├── phase/<name> ...             (kind="phase") the recorder's tree
+    │                                for this schedule_once(), nested
+    │                                as above, true ts / dur
     ├── workload/<key>               (kind="workload") — one per decided
     │     attrs: decision, flavors, reasons, preemption, rationale ...
     └── ...
 
-Timestamps are microseconds relative to the tracer's epoch (a
-perf_counter captured at attach), matching the Chrome/Perfetto
-trace-event ``ts`` unit so export is a straight mapping.
+Timestamps are microseconds since the recorder's epoch (``epoch`` is the
+``(clock(), time.time_ns())`` pair taken together, so a tree can be laid
+on any other clock), matching the Chrome/Perfetto trace-event ``ts``
+unit so export is a straight mapping.
 
 ``correlation_id`` is the cross-artifact join key: derived purely from
 (cycle seq, canonical decisions), so the tracer, the flight recorder and
@@ -23,6 +63,9 @@ subsystems, and replaying a trace regenerates identical ids.
 
 from __future__ import annotations
 
+import sys
+import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Iterator, Optional
 
@@ -35,8 +78,8 @@ class Span:
     wall-clock overhead."""
 
     name: str
-    kind: str                      # "cycle" | "phase" | "workload"
-    ts: float                      # µs since tracer epoch
+    kind: str                      # "span" | "cycle" | "phase" | "workload"
+    ts: float                      # µs since the recorder's epoch
     dur: float                     # µs
     attrs: dict = field(default_factory=dict)
     children: list = field(default_factory=list)
@@ -64,6 +107,232 @@ class Span:
                 "ts": round(self.ts, 1), "dur": round(self.dur, 1),
                 "attrs": self.attrs,
                 "children": [c.to_dict() for c in self.children]}
+
+
+# The spans whose self time is ``unattributed``; everything directly
+# under one of them is a leaf of the identity
+#   sum(leaves) + unattributed == schedule_once.
+CONTAINERS = frozenset({"schedule_once", "cycle", "speculate"})
+
+# Keys of Engine.last_cycle_phases that repeat time the leaf keys
+# already hold: a nested span (tas_place, inside host_encode), a
+# container's wall, and the LEGACY AGGREGATES, which keep the meaning
+# the bridge's perf_counter marks gave them, mark to mark, for the
+# readers that predate the tree (benchmark encode_ms / verdict_decode_ms
+# / unused_speculation_ms, bench.py, chip_smoke.py), to retire with them:
+#   encode      = take_speculation's start to verdict_decode's start
+#                 (the time between the spans included)
+#   device      = verdict_decode (the host's verdict scan, never the
+#                 device; the name is the legacy)
+#   spec_encode = a used speculation's own encode + launch, first
+#                 child's start to last child's end, paid inside the
+#                 previous schedule_once()
+AGGREGATE_KEYS = frozenset({"tas_place", "speculate", "schedule_once",
+                            "encode", "device", "spec_encode"})
+
+# Keys of Engine.last_cycle_phases that are counts of this
+# schedule_once(), not seconds, summed from span attrs recorded at the
+# same boundary as the time, so that a reader holding a window's dicts
+# has the window's own counts:
+#   n_launches, n_lattice_launches  containers (cycle, speculate) that
+#       launched the cycle program (attr ``lattice``), and those whose
+#       launch took the fused preemptor's branch; the second is left
+#       out where a launch could not tell (``lattice`` None)
+#   n_spec_used, n_spec_discarded   take_speculation's ``outcome``
+#   n_device_cycles, n_device_heads verdict_decode spans, and the heads
+#       the device decided in them (attr ``device_heads``)
+COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches", "n_spec_used",
+                        "n_spec_discarded", "n_device_cycles",
+                        "n_device_heads"})
+
+
+class SpanRecorder:
+    """Real spans, always on: one tree per schedule_once(), the last
+    ``retain`` kept. Code that runs in sequence uses ``begin`` /
+    ``next`` / ``end``; ``with rec.span(name) as s`` closes whatever
+    was left open beneath it when the block unwinds, so an exception
+    cannot leave the stack out of step. Decision code calls this and
+    never reads it (graftlint D1/O1).
+
+    What a cycle allocates is part of the cost (the engine sweeps the
+    young generation every cycle, ``apply_serving_gc_posture``): a
+    Span, its attrs and child list, and the profiler annotation, per
+    span; the context manager is the recorder itself. Spans hold no
+    parent pointer, so a tree dropped from the ring is freed by
+    reference count alone."""
+
+    __slots__ = ("clock", "epoch", "trees", "_open", "_scopes", "_marks",
+                 "_annotation")
+
+    def __init__(self, retain: int = 8,
+                 clock: Callable[[], float] = time.perf_counter):
+        self.trees: deque = deque(maxlen=retain)  # finished roots
+        self._open: list = []     # the open spans, root first
+        self._scopes: list = []   # their profiler annotations
+        self._marks: list = []    # stack depth at each ``with``
+        # jax.profiler.TraceAnnotation, from the first root that finds
+        # jax imported (this module never imports it: no session can be
+        # open in a process that has not).
+        self._annotation = None
+        self.set_clock(clock)
+
+    def set_clock(self, clock: Callable[[], float]) -> None:
+        """Time spans on ``clock`` from here on, with a fresh epoch:
+        ``(clock(), time.time_ns())`` read together."""
+        self.clock = clock
+        self.epoch = (clock(), time.time_ns())
+
+    # -- recording --
+
+    def begin(self, name: str, **attrs) -> Span:
+        return self._push(name, attrs, self.clock())
+
+    def end(self, **attrs) -> Span:
+        return self._pop(self.clock(), attrs)
+
+    def next(self, name: str, **attrs) -> Span:
+        """End the open span and begin ``name`` at the same instant."""
+        t = self.clock()
+        self._pop(t, None)
+        return self._push(name, attrs, t)
+
+    def span(self, name: str, **attrs) -> "SpanRecorder":
+        self._push(name, attrs, self.clock())
+        return self
+
+    def __enter__(self) -> Span:
+        self._marks.append(len(self._open))
+        return self._open[-1]
+
+    def __exit__(self, *exc) -> bool:
+        depth = self._marks.pop() - 1
+        while len(self._open) > depth:
+            self._pop(self.clock(), None)
+        return False
+
+    def _push(self, name: str, attrs: dict, t: float) -> Span:
+        stack = self._open
+        annotation = self._annotation
+        if annotation is None and not stack and "jax" in sys.modules:
+            from jax.profiler import TraceAnnotation
+            annotation = self._annotation = TraceAnnotation
+        scope = None
+        if annotation is not None:
+            scope = annotation("kueue." + name)
+            scope.__enter__()
+        self._scopes.append(scope)
+        s = Span(name, "span", (t - self.epoch[0]) * 1e6, 0.0, attrs, [])
+        if stack:
+            stack[-1].children.append(s)
+        stack.append(s)
+        return s
+
+    def _pop(self, t: float, attrs: Optional[dict]) -> Span:
+        s = self._open.pop()
+        s.dur = (t - self.epoch[0]) * 1e6 - s.ts
+        if attrs:
+            s.attrs.update(attrs)
+        scope = self._scopes.pop()
+        if scope is not None:
+            scope.__exit__(None, None, None)
+        if not self._open:
+            self.trees.append(s)
+        return s
+
+    # -- reading (obs zone, tests, operator surfaces) --
+
+    def open_root(self) -> Optional[Span]:
+        """The tree being recorded, for a reader that runs inside it
+        (a cycle listener); its open spans still have ``dur`` 0."""
+        return self._open[0] if self._open else None
+
+    def is_open(self, s: Span) -> bool:
+        return any(o is s for o in self._open)
+
+    def last(self) -> Optional[Span]:
+        return self.trees[-1] if self.trees else None
+
+
+def phase_seconds(root: Span) -> dict:
+    """A schedule_once() tree as ``Engine.last_cycle_phases``: seconds,
+    one key per leaf name (its time summed over the cycle's own call
+    and a speculation's alike), ``tas_place`` (nested in host_encode),
+    ``speculate`` (that subtree's wall) and the legacy aggregates; and
+    the counts of COUNT_KEYS. ``close_phases`` adds what only the closed
+    root knows."""
+    out: dict = {}
+    launches = lattice = 0
+    boxes = [root]
+    while boxes:
+        box = boxes.pop()
+        if "lattice" in box.attrs:  # this container launched
+            launches += 1
+            if lattice is not None:
+                ran = box.attrs["lattice"]
+                lattice = None if ran is None else lattice + ran
+        for c in box.children:
+            if c.name in CONTAINERS:
+                boxes.append(c)
+                if c.name == "speculate":
+                    _add(out, "speculate", c.dur * 1e-6)
+                elif c.name == "cycle":
+                    _cycle_aggregates(c, out)
+                continue
+            _add(out, c.name, c.dur * 1e-6)
+            for s in c.children:  # tas_place, in host_encode
+                if s.name in AGGREGATE_KEYS:
+                    _add(out, s.name, s.dur * 1e-6)
+    if launches:
+        out["n_launches"] = launches
+        if lattice is not None:
+            out["n_lattice_launches"] = lattice
+    return out
+
+
+def _add(out: dict, key: str, value) -> None:
+    out[key] = out.get(key, 0) + value
+
+
+def _cycle_aggregates(cycle: Span, out: dict) -> None:
+    """What is read off the ``cycle`` subtree alone: the legacy
+    aggregates, mark to mark, and the counts its spans carry."""
+    for c in cycle.children:
+        if c.name == "take_speculation":
+            outcome = c.attrs.get("outcome")
+            if outcome in ("used", "discarded"):
+                _add(out, "n_spec_used", outcome == "used")
+                _add(out, "n_spec_discarded", outcome == "discarded")
+            if "spec_encode_s" in c.attrs:
+                out["spec_encode"] = c.attrs["spec_encode_s"]
+        elif c.name == "verdict_decode":
+            # (Else the bridge declined the cycle before a verdict.)
+            out["encode"] = (c.ts - cycle.children[0].ts) * 1e-6
+            out["device"] = c.dur * 1e-6
+            _add(out, "n_device_cycles", 1)
+            if "device_heads" in c.attrs:
+                _add(out, "n_device_heads", c.attrs["device_heads"])
+
+
+def close_phases(phases: dict, root: Span) -> None:
+    """Complete ``phase_seconds(root)``'s dict, in place, once the root
+    has closed: ``listeners`` (open while the listeners read the dict),
+    ``schedule_once`` = the root's wall, and ``unattributed`` = the
+    containers' self time, so that leaves + unattributed ==
+    schedule_once by construction."""
+    for c in root.children:
+        if c.name == "listeners":
+            phases["listeners"] = c.dur * 1e-6
+    total = root.dur * 1e-6
+    phases["unattributed"] = total - sum(leaf_phases(phases).values())
+    phases["schedule_once"] = total
+
+
+def leaf_phases(phases: dict) -> dict:
+    """``last_cycle_phases`` without the keys that repeat time and
+    without the counts: the seconds a reader may add up or lay end to
+    end."""
+    return {k: v for k, v in phases.items()
+            if k not in AGGREGATE_KEYS and k not in COUNT_KEYS}
 
 
 def correlation_id(seq: int, decisions: list) -> str:

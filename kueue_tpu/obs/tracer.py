@@ -1,13 +1,14 @@
 """CycleTracer: per-cycle span trees from the engine's capture points.
 
-Attachment is purely observational — a pre-cycle hook captures the wall
-start and arms the rationale buffer (obs.hooks), and a cycle listener
-reconstructs the span tree from artifacts the cycle already produced:
-the CycleResult entries (assignment, per-flavor rejection reasons,
-preemption targets, statuses), Engine.last_cycle_phases and
-last_cycle_mode, and the drained rationale events. Nothing here feeds
-back into a decision, which is what keeps a traced run's decision
-digest byte-identical to an untraced run (asserted by
+Attachment is purely observational — a pre-cycle hook arms the
+rationale buffer (obs.hooks), and a cycle listener builds the cycle's
+tree from artifacts the cycle already produced: the engine's own span
+tree for this schedule_once() (obs.span.SpanRecorder — real starts and
+durations, adopted as ``phase/<name>`` children), the CycleResult
+entries (assignment, per-flavor rejection reasons, preemption targets,
+statuses), last_cycle_mode, and the drained rationale events. Nothing
+here feeds back into a decision, which is what keeps a traced run's
+decision digest byte-identical to an untraced run (asserted by
 tests/test_obs_trace.py and the bench trace-overhead scenario).
 
 Both decision paths land here unchanged: the sequential core and the
@@ -22,7 +23,6 @@ to keep (``kueuectl trace export``).
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Optional
 
@@ -56,8 +56,6 @@ class CycleTracer:
         self._spans: deque[Span] = deque(maxlen=retain)
         self.cycles_traced = 0
         self.last_cid: Optional[str] = None
-        self._epoch = time.perf_counter()
-        self._t0: Optional[float] = None
         self._pre = self._pre_cycle
         self._post = self._on_cycle
         engine.pre_cycle_hooks.append(self._pre)
@@ -69,19 +67,15 @@ class CycleTracer:
     def _pre_cycle(self, seq, eng) -> None:
         # Runs un-isolated in schedule_once (fault injectors share this
         # hook list and raise on purpose) — keep it infallible.
-        self._t0 = time.perf_counter()
         hooks.CURRENT = hooks.RationaleBuffer()
 
     def _on_cycle(self, seq, result) -> None:
         buf, hooks.CURRENT = hooks.CURRENT, None
-        end = time.perf_counter()
-        t0 = self._t0 if self._t0 is not None else end
-        self._t0 = None
         if result is None:
             return  # idle: no decisions, no span tree
         if not self.capture:
             return  # shed by the degradation ladder (rung "trace")
-        root = self._build(seq, result, buf, t0, end)
+        root = self._build(seq, result, buf)
         self._spans.append(root)
         self.cycles_traced += 1
         self.last_cid = root.attrs["cid"]
@@ -89,39 +83,52 @@ class CycleTracer:
 
     # -- span-tree construction --
 
-    def _build(self, seq, result, buf, t0: float, end: float) -> Span:
+    def _build(self, seq, result, buf) -> Span:
         from kueue_tpu.replay.trace import canonical_decisions
 
         eng = self.engine
         decisions = canonical_decisions(result)
         cid = correlation_id(seq, decisions)
         mode = eng.last_cycle_mode or "sequential"
-        ts = (t0 - self._epoch) * 1e6
-        root = Span(f"cycle/{seq}", "cycle", ts, (end - t0) * 1e6, {
+        # This runs as a cycle listener, inside the schedule_once()
+        # tree it adopts: the cycle span starts where that root did and
+        # ends now, on the recorder's clock and epoch.
+        rec = eng.spans
+        live = rec.open_root()
+        now = (rec.clock() - rec.epoch[0]) * 1e6
+        ts = live.ts if live is not None else now
+        root = Span(f"cycle/{seq}", "cycle", ts, now - ts, {
             "seq": seq, "cid": cid, "mode": mode, "clock": eng.clock,
             "admitted": result.stats.admitted,
             "preempting": result.stats.preempting,
             "skipped": result.stats.skipped,
             "inadmissible": result.stats.inadmissible,
         })
-        # Phases laid end-to-end from the cycle start, in the order the
-        # decision path recorded them (snapshot/decide/apply on the host
-        # path; encode/device/apply/finalize on the device path).
-        cursor = ts
-        decide_ts = ts
-        apply_span = None
-        for phase, secs in eng.last_cycle_phases.items():
-            dur = secs * 1e6
-            ps = root.child(f"phase/{phase}", "phase", cursor, dur,
-                            seconds=round(secs, 6))
-            if phase == "apply":
-                apply_span = ps
-            if phase in ("decide", "device"):
-                decide_ts = cursor
-            cursor += dur
+        # The recorder's finished spans, as they were timed, under
+        # ``phase/<name>``; what is still open (the root, ``listeners``)
+        # has no end yet and is left out. ``decide_ts`` places the
+        # workload spans where the verdicts were reached.
+        found: dict = {}  # first span of each name, as adopted
+
+        def adopt(parent: Span, src: Span) -> None:
+            if rec.is_open(src):
+                return
+            ps = parent.child(f"phase/{src.name}", "phase", src.ts, src.dur,
+                              seconds=round(src.dur * 1e-6, 6), **src.attrs)
+            found.setdefault(src.name, ps)
+            for c in src.children:
+                adopt(ps, c)
+
+        if live is not None:
+            for c in live.children:
+                adopt(root, c)
+        apply_span = found.get("apply")
+        decided = found.get("verdict_decode") or found.get("decide")
+        decide_ts = decided.ts if decided is not None else ts
         # Apply micro-attribution (obs.perf): when the perf recorder is
         # attached, nest this cycle's apply sub-step samples as spans
-        # under phase/apply, laid end-to-end — the span tree and the
+        # under phase/apply, laid end-to-end from its start (the sample
+        # buffer keeps durations only) — the span tree and the
         # aggregated histograms speak the same vocabulary. Samples
         # aggregate per sub-phase name (a cycle admitting N workloads
         # records N diff_build scopes): one span per name keeps the

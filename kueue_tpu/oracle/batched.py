@@ -144,16 +144,18 @@ def _cycle_core(
 
     # 2. Heads: per CQ, lowest rank among active pending workloads
     # (manager.go:872 Heads / cluster_queue.go:715 Pop).
-    active = pending & ~inadmissible
-    eff_rank = jnp.where(active, rank, BIG_RANK)
-    head_rank = pk.select_heads(eff_rank, wl_cq, C, BIG_RANK)
-    w_ids = jnp.arange(W, dtype=jnp.int32)
-    is_head = active & (eff_rank == head_rank[wl_cq]) & (eff_rank < BIG_RANK)
-    # Map CQ -> head workload index (-1 none). Heads are unique per CQ
-    # because rank embeds the workload index; non-heads scatter out of
-    # bounds and are dropped.
-    head_idx = jnp.full((C,), -1, jnp.int32).at[
-        jnp.where(is_head, wl_cq, C)].max(w_ids, mode="drop")
+    with jax.named_scope("kueue.heads"):
+        active = pending & ~inadmissible
+        eff_rank = jnp.where(active, rank, BIG_RANK)
+        head_rank = pk.select_heads(eff_rank, wl_cq, C, BIG_RANK)
+        w_ids = jnp.arange(W, dtype=jnp.int32)
+        is_head = active & (eff_rank == head_rank[wl_cq]) \
+            & (eff_rank < BIG_RANK)
+        # Map CQ -> head workload index (-1 none). Heads are unique per CQ
+        # because rank embeds the workload index; non-heads scatter out of
+        # bounds and are dropped.
+        head_idx = jnp.full((C,), -1, jnp.int32).at[
+            jnp.where(is_head, wl_cq, C)].max(w_ids, mode="drop")
 
     slot_valid = head_idx >= 0
     h_safe = jnp.maximum(head_idx, 0)
@@ -164,15 +166,16 @@ def _cycle_core(
 
     # 3. Nominate all heads at once (per-podset flavor choices with
     # within-workload usage accumulation, flavorassigner.go:707).
-    h_ok = None
-    if wl_flavor_ok is not None:
-        h_ok = jnp.where(slot_valid[:, None], wl_flavor_ok[h_safe], True)
-    flavor_of_res, pmode, borrows, needs_oracle, usage_fr = \
-        aops.assign_flavors(
-            h_cq, h_req, derived, nominal, ancestors, height, group_of_res,
-            group_flavors, no_preemption, can_pwb, fung_borrow_try_next,
-            fung_pref_preempt_first, flavor_ok=h_ok,
-            depth=depth, num_resources=S)
+    with jax.named_scope("kueue.assign"):
+        h_ok = None
+        if wl_flavor_ok is not None:
+            h_ok = jnp.where(slot_valid[:, None], wl_flavor_ok[h_safe], True)
+        flavor_of_res, pmode, borrows, needs_oracle, usage_fr = \
+            aops.assign_flavors(
+                h_cq, h_req, derived, nominal, ancestors, height, group_of_res,
+                group_flavors, no_preemption, can_pwb, fung_borrow_try_next,
+                fung_pref_preempt_first, flavor_ok=h_ok,
+                depth=depth, num_resources=S)
     if slot_borrows_override is not None:
         borrows = jnp.where(slot_borrows_override >= 0,
                             slot_borrows_override, borrows)
@@ -245,20 +248,22 @@ def _cycle_core(
         V_ = min(v_cap, A_l_)  # must match the kernel's victim width
 
         def _run_targets(_):
-            out = pops.classical_targets_impl(
-                oracle_eff, h_pri, h_ts, entry_fr_d, req_fr,
-                pc_wcq_policy, pc_reclaim_policy, pc_bwc_forbidden,
-                pc_bwc_threshold, pc_cq_has_parent,
-                adm_cq, adm_pri, adm_ts, adm_qrt, adm_uid, adm_evicted,
-                adm_usage, full_usage, derived["subtree_quota"],
-                lend_limit, borrow_limit, nominal, ancestors, height,
-                local_chain, root_nodes, root_of_cq,
-                adm_rank=adm_rank, adm_by_root=adm_by_root,
-                depth=depth, v_cap=v_cap)
-            # Canonical dtypes: both cond branches must match exactly.
-            return (out[0], out[1], out[2], out[3].astype(jnp.int32),
-                    out[4].astype(jnp.int32), out[5].astype(jnp.int32),
-                    out[6].astype(jnp.int32), out[7])
+            with jax.named_scope("kueue.preempt"):
+                out = pops.classical_targets_impl(
+                    oracle_eff, h_pri, h_ts, entry_fr_d, req_fr,
+                    pc_wcq_policy, pc_reclaim_policy, pc_bwc_forbidden,
+                    pc_bwc_threshold, pc_cq_has_parent,
+                    adm_cq, adm_pri, adm_ts, adm_qrt, adm_uid, adm_evicted,
+                    adm_usage, full_usage, derived["subtree_quota"],
+                    lend_limit, borrow_limit, nominal, ancestors, height,
+                    local_chain, root_nodes, root_of_cq,
+                    adm_rank=adm_rank, adm_by_root=adm_by_root,
+                    depth=depth, v_cap=v_cap)
+                # Canonical dtypes: both cond branches must match
+                # exactly.
+                return (out[0], out[1], out[2], out[3].astype(jnp.int32),
+                        out[4].astype(jnp.int32), out[5].astype(jnp.int32),
+                        out[6].astype(jnp.int32), out[7])
 
         def _skip_targets(_):
             return (jnp.zeros((C,), bool), jnp.zeros((C,), bool),
@@ -319,41 +324,43 @@ def _cycle_core(
         # reported separately for host-root demotion.
         needs_oracle = needs_oracle & jnp.zeros((C,), bool)
         slot_oracle = slot_oracle & jnp.zeros((C,), bool)
-    if fair_mode:
-        # 4f/5f. Fair-sharing tournament ordering fused with the commit
-        # (fair_sharing_iterator.go:47): per-root DRS recomputation after
-        # every winner, on device.
-        slot_admitted, slot_round, _ = cops.commit_grouped_fair(
-            slot_valid, entry_fr_d, req_fr, kind, borrows,
-            jnp.where(slot_valid, wl_priority[h_safe], 0),
-            jnp.where(slot_valid, wl_ts[h_safe], 0.0),
-            full_usage, derived["subtree_quota"], lend_limit, borrow_limit,
-            nominal, ancestors, derived["potential"], fair_weight, parent,
-            root_members, root_nodes, local_chain, child_rank, local_depth,
-            root_parent_local, depth=depth, num_flavors=num_flavors)
-        slot_preempting = jnp.zeros((C,), bool)  # overrides: classical only
-        # Positions: tournament round within the root (rounds are the
-        # reference's pop order; roots are independent).
-        slot_position = jnp.maximum(slot_round, 0)
-        key = slot_round.astype(jnp.int64)  # replay order for usage_clean
-    else:
-        # 4. Commit order (scheduler.go:971).
-        key = cops.make_commit_order_key(
-            wl_has_qr[h_safe] & slot_valid, borrows,
-            jnp.where(slot_valid, wl_priority[h_safe], 0),
-            jnp.where(slot_valid, commit_rank[h_safe], (1 << 24) - 1))
-        order = jnp.argsort(key).astype(jnp.int32)
-        slot_committed, _ = cops.commit_grouped(
-            key, slot_valid, entry_fr_d, req_fr, kind, borrows, full_usage,
-            derived["subtree_quota"], lend_limit, borrow_limit, nominal,
-            ancestors, root_members, root_nodes, local_chain,
-            root_parent_local, slot_victim_row, slot_victim_vals,
-            slot_victim_ids, claimed0, depth=depth)
-        slot_admitted = slot_committed & (kind != cops.ENTRY_PREEMPT)
-        slot_preempting = slot_committed & (kind == cops.ENTRY_PREEMPT)
-        # Positions report the global commit order (scheduler.go:971).
-        slot_position = jnp.zeros((C,), jnp.int32).at[order].set(
-            jnp.arange(C, dtype=jnp.int32))
+    with jax.named_scope("kueue.commit"):
+        if fair_mode:
+            # 4f/5f. Fair-sharing tournament ordering fused with the commit
+            # (fair_sharing_iterator.go:47): per-root DRS recomputation after
+            # every winner, on device.
+            slot_admitted, slot_round, _ = cops.commit_grouped_fair(
+                slot_valid, entry_fr_d, req_fr, kind, borrows,
+                jnp.where(slot_valid, wl_priority[h_safe], 0),
+                jnp.where(slot_valid, wl_ts[h_safe], 0.0),
+                full_usage, derived["subtree_quota"], lend_limit, borrow_limit,
+                nominal, ancestors, derived["potential"], fair_weight, parent,
+                root_members, root_nodes, local_chain, child_rank, local_depth,
+                root_parent_local, depth=depth, num_flavors=num_flavors)
+            # Overrides are classical only.
+            slot_preempting = jnp.zeros((C,), bool)
+            # Positions: tournament round within the root (rounds are the
+            # reference's pop order; roots are independent).
+            slot_position = jnp.maximum(slot_round, 0)
+            key = slot_round.astype(jnp.int64)  # replay order for usage_clean
+        else:
+            # 4. Commit order (scheduler.go:971).
+            key = cops.make_commit_order_key(
+                wl_has_qr[h_safe] & slot_valid, borrows,
+                jnp.where(slot_valid, wl_priority[h_safe], 0),
+                jnp.where(slot_valid, commit_rank[h_safe], (1 << 24) - 1))
+            order = jnp.argsort(key).astype(jnp.int32)
+            slot_committed, _ = cops.commit_grouped(
+                key, slot_valid, entry_fr_d, req_fr, kind, borrows, full_usage,
+                derived["subtree_quota"], lend_limit, borrow_limit, nominal,
+                ancestors, root_members, root_nodes, local_chain,
+                root_parent_local, slot_victim_row, slot_victim_vals,
+                slot_victim_ids, claimed0, depth=depth)
+            slot_admitted = slot_committed & (kind != cops.ENTRY_PREEMPT)
+            slot_preempting = slot_committed & (kind == cops.ENTRY_PREEMPT)
+            # Positions report the global commit order (scheduler.go:971).
+            slot_position = jnp.zeros((C,), jnp.int32).at[order].set(
+                jnp.arange(C, dtype=jnp.int32))
     adm_target = jnp.where(slot_valid & slot_admitted, h_safe, W)
     wl_admitted = jnp.zeros((W,), bool).at[adm_target].set(True, mode="drop")
 
@@ -387,11 +394,12 @@ def _cycle_core(
     # recompute post-cycle usage from admissions only.
     committed_kind = jnp.where(slot_admitted, cops.ENTRY_FORCE,
                                cops.ENTRY_SKIP)
-    _, usage_clean = cops.commit_grouped(
-        key, slot_valid, entry_fr_d, req_fr, committed_kind, borrows,
-        full_usage, derived["subtree_quota"], lend_limit, borrow_limit,
-        nominal, ancestors, root_members, root_nodes, local_chain,
-        depth=depth)
+    with jax.named_scope("kueue.commit"):
+        _, usage_clean = cops.commit_grouped(
+            key, slot_valid, entry_fr_d, req_fr, committed_kind, borrows,
+            full_usage, derived["subtree_quota"], lend_limit, borrow_limit,
+            nominal, ancestors, root_members, root_nodes, local_chain,
+            depth=depth)
 
     any_needs_oracle = jnp.any(slot_oracle)
     return (new_pending, new_inadmissible, usage_clean, wl_admitted,

@@ -94,6 +94,56 @@ def pipeline_enabled() -> bool:
     return depth > 0
 
 
+# The cycle_step arguments _encode_cycle converts from host arrays every
+# cycle (the ``upload`` span's bytes); the rest is device-resident by
+# spec / admitted-set version.
+_PER_CYCLE_UPLOADS = (
+    "rank", "commit_rank", "wl_cq", "wl_req", "wl_priority", "wl_has_qr",
+    "wl_hash", "wl_ts", "wl_flavor_ok", "pending", "usage", "slot_maybe",
+    "slot_kind_override", "slot_borrows_override", "slot_flavor_override",
+    "slot_victim_row", "slot_victim_vals", "slot_victim_ids")
+
+
+def _lattice_ran(out, w, slot_maybe) -> Optional[bool]:
+    """Whether this launch took the fused preemptor's branch (the
+    ``lax.cond`` in batched._cycle_core), read off the verdicts it
+    returned — the cycle program has no output for its predicate, and
+    zeroes ``slot_oracle`` once the preemptor has decided every flagged
+    slot. A head drives the branch when its ClusterQueue can preempt,
+    the host's precheck (``slot_maybe``) let it through, and the
+    flavor assigner turned it over to the preemptor; that last leaves
+    one of four marks: the slot preempts, overflows ``v_cap``, parks
+    with a flavor assigned (a head that merely did not fit parks with
+    none, one that fit never parks), or has victims in the mask though
+    it was beaten to their room at commit.
+
+    The marks tell only where every ClusterQueue that could drive the
+    branch is BestEffortFIFO (a StrictFIFO head the preemptor turns
+    down does not park) and the world has one resource group (with two,
+    a head can park with a flavor assigned in the other). Elsewhere the
+    answer is None, not a guess: the ``lattice`` attr says so and the
+    phase dict leaves ``n_lattice_launches`` out (ROADMAP A2: one more
+    output of the cycle program, ``any(oracle_eff)``, retires this
+    function)."""
+    if slot_maybe is None:
+        return False  # no fused preemptor in this program
+    head = np.asarray(out[10])
+    could = (head >= 0) & slot_maybe & ~w.no_preemption
+    if not could.any():
+        return False
+    if w.group_flavors.shape[1] != 1 or not w.best_effort[could].all():
+        return None
+    flavor = np.asarray(out[6])
+    nominated = (flavor >= 0).any(axis=tuple(range(1, flavor.ndim)))
+    parked = np.asarray(out[1])[np.maximum(head, 0)]
+    marks = np.asarray(out[9]) | np.asarray(out[11]) | (parked & nominated)
+    if (could & marks).any():
+        return True
+    # Every victim set beaten to its room leaves no mark on a slot; the
+    # victim mask (zeros where the branch was skipped) still holds them.
+    return bool(np.asarray(out[12]).any())
+
+
 class _CycleExit:
     """An early-exit verdict from :meth:`OracleBridge._encode_cycle`:
     either a named fallback (``fallback_reason``) or a literal return
@@ -136,7 +186,7 @@ class OracleBridge:
         self.max_depth = max_depth
         if executor is None:
             from kueue_tpu.oracle.service import LocalExecutor
-            executor = LocalExecutor()
+            executor = LocalExecutor(engine.spans)
         # Where device programs run: in-process (LocalExecutor) or a
         # standalone oracle service over the socket boundary
         # (service.RemoteExecutor).
@@ -902,6 +952,16 @@ class OracleBridge:
         only when the engine state it encoded is bit-for-bit the state
         this cycle would encode.
         """
+        spans = self.engine.spans
+        with spans.span("cycle") as box:
+            result = self._cycle(box)
+        if result is not None:
+            with spans.span("speculate") as spec_span:
+                self._maybe_speculate(spec_span)
+        return result
+
+    def _cycle(self, box) -> Optional[CycleResult]:
+        """``box``: the open ``cycle`` span."""
         eng = self.engine
         if (self.supervisor is not None
                 and not self.supervisor.allow_cycle(eng.cycle_seq)):
@@ -922,21 +982,16 @@ class OracleBridge:
                 return self._fallback("idle-inadmissible")
             return CycleResult()
 
-        import time as _time
-
-        _t0 = _time.perf_counter()
-        enc = self._take_speculation()
+        with eng.spans.span("take_speculation") as take:
+            enc = self._take_speculation(take)
         if enc is None:
             enc = self._encode_cycle()
             if isinstance(enc, _CycleExit):
                 if enc.fallback_reason is not None:
                     return self._fallback(enc.fallback_reason)
                 return enc.value
-        _t_encode = _time.perf_counter()
-        result = self._commit_cycle(enc, _t0, _t_encode)
-        if result is not None:
-            self._maybe_speculate()
-        return result
+            box.attrs["lattice"] = enc.lattice  # it launched, itself
+        return self._commit_cycle(enc)
 
     # -- the double-buffered cycle loop (ISSUE 16) --
 
@@ -957,11 +1012,15 @@ class OracleBridge:
                 len(eng.workloads),
                 len(eng.namespace_labels))
 
-    def _take_speculation(self):
+    def _take_speculation(self, take):
         """Consume the in-flight speculative cycle if it is still
-        valid; None forces a fresh synchronous encode."""
+        valid; None forces a fresh synchronous encode. What became of
+        the speculation is stamped on ``take`` (this cycle's
+        take_speculation span) and on the ``speculate`` span that paid
+        for it, one schedule_once() back."""
         slot = self._spec
         if slot is None:
+            take.attrs["outcome"] = "none"
             return None
         self._spec = None
         head, payload = slot
@@ -973,6 +1032,8 @@ class OracleBridge:
             # pipeline on.
             raise payload
         if head != self._state_token():
+            take.attrs["outcome"] = \
+                payload.spec_span.attrs["outcome"] = "discarded"
             self.pipeline_stats["discarded"] += 1
             self._count("oracle_pipeline_total", ("discarded",))
             self._spec_miss += 1
@@ -982,20 +1043,28 @@ class OracleBridge:
         for fn, a in payload.deferred:
             fn(*a)
         payload.deferred = ()
+        take.attrs["outcome"] = payload.spec_span.attrs["outcome"] = "used"
+        # The legacy ``spec_encode`` key (obs.span.AGGREGATE_KEYS): what
+        # that speculation's encode + launch cost, mark to mark.
+        first, last = (payload.spec_span.children[0],
+                       payload.spec_span.children[-1])
+        take.attrs["spec_encode_s"] = \
+            (last.ts + last.dur - first.ts) * 1e-6
         self.pipeline_stats["used"] += 1
         self._count("oracle_pipeline_total", ("used",))
         self._spec_miss = 0
         self._spec_backoff = 0
         return payload
 
-    def _maybe_speculate(self) -> None:
-        """Encode cycle N+1 and dispatch its device program NOW — while
-        the host finishes this schedule_once (journal fsync, listener
-        fanout, span build) the device is already solving the next
-        cycle. Counter/stat side effects of the speculative encode are
-        deferred and committed only when the speculation is USED, so a
-        discarded one leaves every diagnostic exactly as the serial
-        loop would have."""
+    def _maybe_speculate(self, spec_span) -> None:
+        """Encode cycle N+1 and run its device program NOW, inside this
+        schedule_once() (``spec_span`` is its open ``speculate`` span):
+        the executor returns with the verdicts read back, so nothing
+        overlaps the host yet (ROADMAP A2). Counter/stat side effects of
+        the speculative encode are deferred and committed only when the
+        speculation is USED, so a discarded one leaves every diagnostic
+        exactly as the serial loop would have (its ``lattice`` attr
+        stands either way: the launch was made)."""
         eng = self.engine
         if not pipeline_enabled():
             self._spec = None
@@ -1021,7 +1090,10 @@ class OracleBridge:
         if isinstance(enc, _CycleExit):
             self._spec = None
             return
-        enc.speculative = True
+        # The open ``speculate`` span: the next cycle stamps the outcome
+        # on it.
+        enc.spec_span = spec_span
+        spec_span.attrs["lattice"] = enc.lattice
         self._spec = (self._state_token(), enc)
         self.pipeline_stats["speculated"] += 1
 
@@ -1044,19 +1116,17 @@ class OracleBridge:
         """The encode phase: world + row tensors, head selection with
         hold-back, per-root host/device partitioning, batched TAS
         nomination, sim-augmented multi-flavor nomination, and the
-        (async) device dispatch. Returns the in-flight cycle for
-        _commit_cycle, or a _CycleExit.
+        executor call. Returns the solved cycle for _commit_cycle, or a
+        _CycleExit.
 
         ``defer_stats`` (speculative mode) buffers every counter/stat
         side effect into ``enc.deferred`` instead of committing it, so
         discarding a speculation cannot skew diagnostics."""
         import jax.numpy as jnp
-        import time as _time
-
-        from kueue_tpu.obs.device import PhaseAnnotator
 
         eng = self.engine
-        _t_start = _time.perf_counter()
+        spans = eng.spans
+        host = spans.begin("host_encode")
         deferred: list = []
         if defer_stats:
             def emit(fn, *a):
@@ -1064,14 +1134,6 @@ class OracleBridge:
         else:
             def emit(fn, *a):
                 fn(*a)
-        # Named profiler scopes mirroring the phase marks: a JAX
-        # profiler capture shows kueue_tpu.oracle.{encode,device,apply,
-        # finalize} lined up with the host span tree (no-op unless a
-        # cycle tracer is active). Sequential phase()/close() calls
-        # because the cycle times phases with perf_counter marks, not
-        # nested blocks; every early return below must close().
-        _ann = PhaseAnnotator()
-        _ann.phase("encode")
         now = eng.clock
         # Incremental encoding: the queue manager's row cache carries the
         # pending world as live tensors; a cycle pays only for rows that
@@ -1120,7 +1182,7 @@ class OracleBridge:
             active[held] = False
         else:
             # Pathological hold churn: give up on the fast path.
-            _ann.close()
+            spans.end()
             return _CycleExit(fallback_reason="held-head-churn")
 
         head_eligible = np.zeros(C, bool)
@@ -1177,7 +1239,7 @@ class OracleBridge:
         from kueue_tpu.tas import batched as _tb
         tas_plan = None
         tas_cq = None
-        _t_tas0 = _time.perf_counter()
+        spans.begin("tas_place")
         if _tb.enabled():
             tas_cq = self._cq_tas_mask(w)
             # The serving rows keep topology heads device-eligible on
@@ -1218,7 +1280,7 @@ class OracleBridge:
                     m[closed] = True
                     demote(m, "tas-forest-shared")
                 emit(self._commit_tas_stats, tas_plan)
-        _t_tas = _time.perf_counter() - _t_tas0
+        spans.end()
         cq_on_device = ~host_root[root_of_cq]
 
         # Multi-flavor groups on preemption-enabled CQs: the flavor
@@ -1294,10 +1356,13 @@ class OracleBridge:
         device_w = active & wl.eligible & (wl.cq >= 0) \
             & cq_on_device[cq_safe_idx]
         if not device_w.any():
-            _ann.close()
+            spans.end()
             return _CycleExit(fallback_reason="all-host")
+        host.attrs["heads"] = int(np.count_nonzero(has_head))
+        host.attrs["pending"] = int(np.count_nonzero(device_w))
 
         # --- device cycle ---
+        upload = spans.next("upload")
         # World-structure arrays are device-resident across cycles
         # (re-uploaded only on spec changes); per-cycle uploads are just
         # the row tensors + usage.
@@ -1362,6 +1427,7 @@ class OracleBridge:
         # the cycle program — one launch instead of three.
         fused = (not eng.cycle.enable_fair_sharing
                  and bool(np.any(~w.no_preemption)))
+        slot_maybe = None
         if fused:
             if pcfg is None:
                 pcfg = self._cq_policy_cfg(w)
@@ -1380,20 +1446,25 @@ class OracleBridge:
                 pc_cq_has_parent=pcfg["j"]["cq_has_parent"],
                 root_of_cq=pcfg["j"]["root_of_cq"],
                 adm_rank=ap["adm_rank"],
-                adm_by_root=ap["adm_by_root"],
-                slot_maybe=jnp.asarray(self._slot_maybe(
-                    w, pcfg, adm, self._head_pri(wl, head_wid))))
-        _ann.phase("device")
+                adm_by_root=ap["adm_by_root"])
+            slot_maybe = self._slot_maybe(
+                w, pcfg, adm, self._head_pri(wl, head_wid))
+            pre_kwargs["slot_maybe"] = jnp.asarray(slot_maybe)
         _inputs = dict(pending=pending, inadmissible=inadmissible,
                        usage=usage, **args, **pre_kwargs)
+        # What this cycle handed over; the world and the admitted set
+        # are device-resident by version and not counted.
+        upload.attrs["bytes"] = sum(
+            _inputs[k].nbytes for k in _PER_CYCLE_UPLOADS if k in _inputs)
+        spans.end()
         emit(_obs_perf.device_call, "cycle_step", _inputs, statics)
-        # JAX dispatch is asynchronous: the call returns device futures
-        # without blocking, so a speculative encode leaves the kernel
-        # solving while the host finishes the previous cycle's
-        # bookkeeping. _commit_cycle blocks on the results.
+        # The executor blocks until the verdicts are on the host
+        # (service._run_cycle_step records dispatch / device_wait /
+        # readback): a speculative launch is waited for here, inside
+        # the schedule_once() that launched it.
         out = self._exec_call("cycle_step", self.executor.cycle_step,
                               _inputs, statics)
-        _ann.close()
+        lattice = _lattice_ran(out, w, slot_maybe)
 
         from types import SimpleNamespace
         return SimpleNamespace(
@@ -1402,22 +1473,20 @@ class OracleBridge:
             cq_on_device=cq_on_device, host_root=host_root,
             root_of_cq=root_of_cq, has_head=has_head,
             tas_plan=tas_plan, fused=fused, admitted=admitted,
-            preempt_targets=preempt_targets,
-            encode_s=_time.perf_counter() - _t_start, t_tas=_t_tas,
-            deferred=deferred, speculative=False)
+            preempt_targets=preempt_targets, lattice=lattice,
+            deferred=deferred, spec_span=None)
 
-    def _commit_cycle(self, enc, _t0: float,
-                      _t_encode: float) -> Optional[CycleResult]:
-        """Block on the in-flight device verdicts and commit the cycle:
-        TAS commit-order recheck, columnar apply, finalize, host tail.
-        ``enc`` comes from _encode_cycle — fresh this cycle or used
-        from the speculation slot (byte-identical either way)."""
-        import time as _time
-
-        from kueue_tpu.obs.device import PhaseAnnotator
+    def _commit_cycle(self, enc) -> Optional[CycleResult]:
+        """Commit the cycle from the verdicts the executor read back:
+        verdict decode, TAS commit-order recheck, columnar apply,
+        finalize, host tail. ``enc`` comes from _encode_cycle — fresh
+        this cycle or used from the speculation slot (byte-identical
+        either way)."""
         from kueue_tpu.tas import batched as _tb
 
         eng = self.engine
+        spans = eng.spans
+        decode = spans.begin("verdict_decode", lattice=enc.lattice)
         w, wl, out = enc.w, enc.wl, enc.out
         pending_infos = enc.pending_infos
         now, W, C = enc.now, enc.W, enc.C
@@ -1428,7 +1497,6 @@ class OracleBridge:
         tas_plan, fused = enc.tas_plan, enc.fused
         admitted = enc.admitted
         preempt_targets = enc.preempt_targets
-        _t_tas = enc.t_tas
 
         def demote(cq_mask: np.ndarray, reason: str) -> None:
             roots = np.unique(root_of_cq[cq_mask])
@@ -1437,8 +1505,6 @@ class OracleBridge:
                 self._host_root(reason, int(new.size))
                 host_root[new] = True
 
-        _ann = PhaseAnnotator()
-        _ann.phase("device")
         if _obs_perf.ACTIVE is not None:
             _obs_perf.device_result("cycle_step", out)
         (new_pending, new_inadmissible, usage2, wl_admitted, slot_admitted,
@@ -1489,6 +1555,8 @@ class OracleBridge:
                 cq_on_device = ~host_root[root_of_cq]
 
         self.cycles_on_device += 1
+        decode.attrs["device_heads"] = int(
+            np.count_nonzero(has_head & cq_on_device))
         # Replay capture point: a cheap fingerprint of the raw device
         # verdicts (before host decode/apply), recorded into traces so a
         # decision-stream divergence can be attributed to the kernel
@@ -1499,8 +1567,7 @@ class OracleBridge:
                      slot_preempting, victim_mask):
             _vd = _zlib.crc32(np.ascontiguousarray(_arr).tobytes(), _vd)
         self.last_verdict_digest = _vd
-        _t_device = _time.perf_counter()
-        _ann.phase("apply")
+        spans.next("apply")
 
         # Commit-order re-check for planned TAS admits: serialize them
         # through the overlay (tas/batched.commit_plan); a nominated
@@ -1555,30 +1622,13 @@ class OracleBridge:
                 "workload")
             result.entries.append(e)
             result.stats.skipped += 1
-        _t_apply = _time.perf_counter()
-        _ann.phase("finalize")
+        # apply: decode + cache assume, what the reference's cycle
+        # blocks on. finalize: status + metric + journal writes — the
+        # reference's ASYNC status PATCH (scheduler.go:870), still
+        # inside this cycle's wall time.
+        spans.next("finalize")
         finalize()
-        # North-star phase accounting: encode (snapshot + tensorize) /
-        # device (solve incl. transfer) / apply (decode + cache assume,
-        # what the reference's cycle blocks on) / finalize (status +
-        # metric + journal writes — the reference's ASYNC status PATCH,
-        # scheduler.go:870; still inside this cycle's wall time).
-        _t_final = _time.perf_counter()
-        _ann.close()
-        phases = {"encode": _t_encode - _t0, "device": _t_device - _t_encode,
-                  "apply": _t_apply - _t_device,
-                  "finalize": _t_final - _t_apply,
-                  "tas_place": _t_tas}
-        if enc.speculative:
-            # Honest attribution for pipelined cycles: "encode" above is
-            # just the token validation; the real encode+dispatch cost
-            # was paid inside the PREVIOUS cycle's wall time and is
-            # reported here under its own key.
-            phases["spec_encode"] = enc.encode_s
-        eng.last_cycle_phases = phases
-        for phase, dur in phases.items():
-            eng.registry.histogram(
-                "scheduler_phase_duration_seconds").observe(dur, (phase,))
+        spans.end()
 
         # --- host tail: sequential cycle over the host roots ---
         eng.last_cycle_mode = "device"
@@ -1586,6 +1636,7 @@ class OracleBridge:
         if host_cqs.size:
             self.cycles_hybrid += 1
             eng.last_cycle_mode = "hybrid"
+            spans.begin("host_tail")
             heads = []
             for ci in host_cqs:
                 pcq = eng.queues.cluster_queues.get(w.cq_names[ci])
@@ -1607,6 +1658,7 @@ class OracleBridge:
                 for k, v in hst.preemption_skips.items():
                     st.preemption_skips[k] = \
                         st.preemption_skips.get(k, 0) + v
+            spans.end()
         self._count("oracle_cycles_total", (eng.last_cycle_mode,))
         return result
 

@@ -37,6 +37,7 @@ from typing import Optional
 
 import numpy as np
 
+from kueue_tpu.obs.span import SpanRecorder
 from kueue_tpu.oracle import wire
 
 
@@ -49,7 +50,10 @@ _CYCLE_STATICS = ("depth", "num_resources", "num_cqs", "fair_mode",
                   "num_flavors")
 
 
-def _run_cycle_step(tensors: dict, statics: dict):
+def _run_cycle_step(tensors: dict, statics: dict, spans: SpanRecorder):
+    """One launch of the cycle program, verdicts read back to the host.
+    ``spans`` (the engine's recorder, or a served connection's own)
+    gets the call split where it blocks."""
     import jax
     import jax.numpy as jnp
 
@@ -59,10 +63,23 @@ def _run_cycle_step(tensors: dict, statics: dict):
     # cache) pass through untouched: jnp.asarray on a committed jax
     # array still pays an eager weak-type strip per call — ~2ms/cycle
     # of pure dispatch at tas_large scale.
-    kwargs = {k: v if isinstance(v, jax.Array) else jnp.asarray(v)
-              for k, v in tensors.items()}
-    out = B.cycle_step(**kwargs, **statics)
-    return [np.asarray(o) for o in out]
+    # The spans run on from one another; the ``with`` closes whichever
+    # is open when the block ends or unwinds.
+    with spans.span("upload") as upload:
+        kwargs = {k: v if isinstance(v, jax.Array) else jnp.asarray(v)
+                  for k, v in tensors.items()}
+        upload.attrs["bytes"] = sum(
+            kwargs[k].nbytes for k, v in tensors.items()
+            if kwargs[k] is not v)
+        spans.next("dispatch")
+        out = B.cycle_step(**kwargs, **statics)
+        spans.next("device_wait")
+        jax.block_until_ready(out)
+        readback = spans.next("readback")
+        host = [np.asarray(o) for o in out]
+        readback.attrs["bytes"] = sum(o.nbytes for o in host)
+        del out  # the device's copies are released inside the span
+    return host
 
 
 def _run_classical_targets(tensors: dict, statics: dict, derived=None):
@@ -95,10 +112,15 @@ def _run_classical_targets(tensors: dict, statics: dict, derived=None):
 
 class LocalExecutor:
     """In-process execution (the default): the engine and the oracle
-    share one JAX runtime and jit cache."""
+    share one JAX runtime and jit cache. ``spans``: the engine's
+    recorder, where cycle_step records upload / dispatch / device_wait
+    / readback."""
+
+    def __init__(self, spans: SpanRecorder):
+        self.spans = spans
 
     def cycle_step(self, tensors: dict, statics: dict):
-        return _run_cycle_step(tensors, statics)
+        return _run_cycle_step(tensors, statics, self.spans)
 
     def classical_targets(self, tensors: dict, statics: dict,
                           derived=None):
@@ -109,10 +131,15 @@ class RemoteExecutor:
     """Client side of the serving boundary: one persistent connection,
     reconnect-per-error, RemoteOracleError on transport failure."""
 
-    def __init__(self, host: str, port: int, timeout: float = 60.0):
+    def __init__(self, host: str, port: int, spans: SpanRecorder,
+                 timeout: float = 60.0):
         self.host = host
         self.port = port
         self.timeout = timeout
+        # As LocalExecutor.spans. Over the wire a cycle_step is upload
+        # (serialize), device_wait (send, the service's solve, receive)
+        # and readback (deserialize); there is no dispatch to tell.
+        self.spans = spans
         self._sock: Optional[socket.socket] = None
         self._lock = threading.Lock()
 
@@ -125,12 +152,12 @@ class RemoteExecutor:
                 raise RemoteOracleError(str(e)) from e
         return self._sock
 
-    def _call(self, op: str, tensors: dict, meta: dict):
+    def _roundtrip(self, payload: bytes) -> bytes:
         with self._lock:
             try:
                 sock = self._connect()
-                wire.send_msg(sock, wire.pack(op, tensors, meta))
-                body = wire.recv_msg(sock)
+                wire.send_msg(sock, payload)
+                return wire.recv_msg(sock)
             except (OSError, ConnectionError) as e:
                 if self._sock is not None:
                     try:
@@ -139,6 +166,9 @@ class RemoteExecutor:
                         pass
                     self._sock = None
                 raise RemoteOracleError(str(e)) from e
+
+    @staticmethod
+    def _unpack(body: bytes) -> list:
         rop, out_tensors, out_meta = wire.unpack(body)
         if rop == "error":
             raise RemoteOracleError(out_meta.get("message", "remote error"))
@@ -146,14 +176,20 @@ class RemoteExecutor:
         return [out_tensors[f"out{i}"] for i in range(n)]
 
     def cycle_step(self, tensors: dict, statics: dict):
-        tensors = {k: np.asarray(v) for k, v in tensors.items()}
-        return self._call("cycle_step", tensors, statics)
+        spans = self.spans
+        with spans.span("upload") as upload:
+            payload = wire.pack("cycle_step", tensors, statics)
+            upload.attrs["bytes"] = len(payload)
+        with spans.span("device_wait"):
+            body = self._roundtrip(payload)
+        with spans.span("readback", bytes=len(body)):
+            return self._unpack(body)
 
     def classical_targets(self, tensors: dict, statics: dict,
                           derived=None):
         # The service re-derives quota state server-side.
-        tensors = {k: np.asarray(v) for k, v in tensors.items()}
-        return self._call("classical_targets", tensors, statics)
+        return self._unpack(self._roundtrip(
+            wire.pack("classical_targets", tensors, statics)))
 
     def close(self) -> None:
         with self._lock:
@@ -201,6 +237,9 @@ class OracleServer:
             t.start()
 
     def _serve_conn(self, conn: socket.socket) -> None:
+        # This connection's own recorder (one thread serves it): its
+        # spans reach a profile of the service as kueue.* events.
+        spans = SpanRecorder(retain=1)
         with conn:
             while True:
                 try:
@@ -212,7 +251,7 @@ class OracleServer:
                     if op == "ping":
                         reply = wire.pack("pong", {}, {"n": 0})
                     elif op == "cycle_step":
-                        outs = _run_cycle_step(tensors, meta)
+                        outs = _run_cycle_step(tensors, meta, spans)
                         reply = wire.pack(
                             "ok", {f"out{i}": o
                                    for i, o in enumerate(outs)},

@@ -27,6 +27,7 @@ import json
 from dataclasses import dataclass, field
 from typing import Optional
 
+from kueue_tpu.obs.span import leaf_phases
 from kueue_tpu.replay.recorder import apply_input
 from kueue_tpu.replay.trace import (
     TraceReader,
@@ -180,7 +181,7 @@ def replay_trace(path: str, mode: str = "host",
             eng.clock = frame["clock"]
             result = eng.schedule_once()
             got[name] = canonical_decisions(result)
-            for p, dur in eng.last_cycle_phases.items():
+            for p, dur in leaf_phases(eng.last_cycle_phases).items():
                 key = p if name == "primary" else f"{name}:{p}"
                 report.phases_replayed[key] = \
                     report.phases_replayed.get(key, 0.0) + dur
@@ -198,7 +199,7 @@ def replay_trace(path: str, mode: str = "host",
         report.cycles += 1
         report.admitted += len(want[0]) if want else 0
         report.preempting += len(want[1]) if want else 0
-        for p, dur in frame.get("phases", {}).items():
+        for p, dur in leaf_phases(frame.get("phases", {})).items():
             report.phases_recorded[p] = \
                 report.phases_recorded.get(p, 0.0) + dur
         if stop_after_cycles is not None \

@@ -166,7 +166,11 @@ def test_phase_timing_recorded():
     eng = make_engine()
     submit(eng, "w", 500)
     eng.schedule_once()
-    assert set(eng.last_cycle_phases) == {"snapshot", "decide", "apply"}
+    # The sequential path's leaves, what brackets them in
+    # schedule_once(), and the two totals (obs.span.phase_seconds).
+    assert set(eng.last_cycle_phases) == {
+        "pre_hooks", "snapshot", "decide", "apply", "listeners",
+        "unattributed", "schedule_once"}
     assert all(v >= 0 for v in eng.last_cycle_phases.values())
     h = eng.registry.histogram("scheduler_phase_duration_seconds")
     assert h.totals[("decide",)] == 1

@@ -107,11 +107,33 @@ def test_engine_against_remote_oracle(oracle_proc):
     assert state_remote == state_seq
 
 
+def test_remote_cycle_records_the_wire_spans(oracle_proc):
+    """Over the wire the executor's part of the cycle's span tree is
+    upload (serialize), device_wait (send, solve, receive) and readback
+    (deserialize), in the engine's own tree; there is no dispatch."""
+    _, addr = oracle_proc
+    eng = build_engine(seed=3)
+    eng.attach_oracle(remote_address=addr)
+    assert eng.oracle.executor.spans is eng.spans
+    eng.schedule_once()
+    (cyc,) = [c for c in eng.spans.last().children if c.name == "cycle"]
+    names = [c.name for c in cyc.children]
+    assert names[:6] == ["take_speculation", "host_encode", "upload",
+                         "upload", "device_wait", "readback"]
+    assert "dispatch" not in names
+    wire_spans = {c.name: c for c in cyc.children[3:6]}
+    assert wire_spans["upload"].attrs["bytes"] > 0
+    assert wire_spans["readback"].attrs["bytes"] > 0
+    assert {"upload", "device_wait", "readback"} <= set(
+        eng.last_cycle_phases)
+
+
 def test_remote_roundtrip_tensor_integrity(oracle_proc):
     """cycle_step over the wire equals cycle_step in-process."""
     from kueue_tpu.bench.scenario import baseline_like
     from kueue_tpu.cache.snapshot import build_snapshot
     from kueue_tpu.oracle.batched import BatchedDrainSolver
+    from kueue_tpu.obs.span import SpanRecorder
     from kueue_tpu.oracle.service import LocalExecutor, RemoteExecutor
 
     _, addr = oracle_proc
@@ -130,8 +152,9 @@ def test_remote_roundtrip_tensor_integrity(oracle_proc):
     statics = dict(depth=w.depth, num_resources=w.num_resources,
                    num_cqs=w.num_cqs, fair_mode=False,
                    num_flavors=max(w.num_flavors, 1))
-    local = LocalExecutor().cycle_step(dict(tensors), dict(statics))
-    rex = RemoteExecutor(*addr)
+    spans = SpanRecorder()  # an executor records into its engine's
+    local = LocalExecutor(spans).cycle_step(dict(tensors), dict(statics))
+    rex = RemoteExecutor(*addr, spans=spans)
     remote = rex.cycle_step(dict(tensors), dict(statics))
     rex.close()
     assert len(local) == len(remote)
