@@ -25,7 +25,10 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     │                             attrs lattice (whether its launch took
     │                             the preemptor's branch; None where the
     │                             verdicts cannot tell), and outcome
-    │                             once the next cycle learns it
+    │                             once the next cycle learns it; or
+    │                             gate = closed and no children, where
+    │                             the last gap was mutated and nothing
+    │                             was launched
     └─ gc_sweep · journal_sync · listeners
 
 Every span carries name, start and duration on the recorder's clock
@@ -139,11 +142,13 @@ AGGREGATE_KEYS = frozenset({"tas_place", "speculate", "schedule_once",
 #       launch took the fused preemptor's branch; the second is left
 #       out where a launch could not tell (``lattice`` None)
 #   n_spec_used, n_spec_discarded   take_speculation's ``outcome``
+#   n_spec_skipped                  ``speculate`` spans the gate closed
+#       (attr ``gate``): nothing encoded, nothing launched
 #   n_device_cycles, n_device_heads verdict_decode spans, and the heads
 #       the device decided in them (attr ``device_heads``)
 COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches", "n_spec_used",
-                        "n_spec_discarded", "n_device_cycles",
-                        "n_device_heads"})
+                        "n_spec_discarded", "n_spec_skipped",
+                        "n_device_cycles", "n_device_heads"})
 
 
 class SpanRecorder:
@@ -275,6 +280,8 @@ def phase_seconds(root: Span) -> dict:
                 boxes.append(c)
                 if c.name == "speculate":
                     _add(out, "speculate", c.dur * 1e-6)
+                    if c.attrs.get("gate") == "closed":
+                        _add(out, "n_spec_skipped", 1)
                 elif c.name == "cycle":
                     _cycle_aggregates(c, out)
                 continue

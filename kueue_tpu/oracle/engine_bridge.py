@@ -226,24 +226,28 @@ class OracleBridge:
             "encode_s": 0.0, "place_s": 0.0, "decode_s": 0.0}
         self.tas_heads_per_launch: dict[int, int] = {}
         # Double-buffered cycle loop (ISSUE 16): the speculatively
-        # encoded + device-dispatched next cycle, as (state_token, enc),
-        # or ("error", exc) when the speculative dispatch failed — the
-        # error surfaces at the next try_cycle, exactly where the
-        # serial loop would have hit it. None = nothing in flight.
+        # encoded + device-dispatched next cycle (stamped with the
+        # state token in _gap_token, below), or the exception when the
+        # speculative dispatch failed — the error surfaces at the next
+        # try_cycle, exactly where the serial loop would have hit it.
+        # None = nothing in flight.
         self._spec = None
         self.pipeline_stats: dict[str, int] = {
             "speculated": 0, "used": 0, "discarded": 0, "skipped": 0}
-        # Discard backoff: a world whose state token flips every cycle
-        # (heavy churn between schedule_once calls) discards every
-        # speculation, turning the pipeline into pure encode waste.
-        # After two consecutive discards, probe only every other cycle
-        # (skip one, speculate, repeat); any USED speculation resets to
-        # every-cycle speculation. Halves the worst-case waste while
-        # re-engaging the pipeline within two cycles of the world going
-        # quiet. Digest-neutral by construction — speculation only
-        # moves work earlier, never changes a decision.
-        self._spec_miss = 0
-        self._spec_backoff = 0
+        # The speculation gate: speculate only after a quiet gap. A
+        # client that speaks between two schedule_once() calls moves the
+        # state token, and every speculation across such a gap is
+        # thrown away. Whether a speculation WOULD have been used costs
+        # nothing to learn: _maybe_speculate leaves the token it stamps
+        # (or would have stamped) in _gap_token, and the next device
+        # cycle's _take_speculation compares it with the token it finds
+        # — the comparison that decides used / discarded, made whether
+        # or not a speculation exists. None = a gap the bridge did not
+        # observe (fresh bridge, fallback, idle or breaker cycle), which
+        # counts as quiet. Digest-neutral by construction — speculation
+        # only moves work earlier, never changes a decision.
+        self._gap_token: Optional[tuple] = None
+        self._gap_quiet = True
 
     def world_is_fast_path_safe(self) -> bool:
         eng = self.engine
@@ -277,6 +281,10 @@ class OracleBridge:
         return True
 
     def _fallback(self, reason: str) -> None:
+        """Hand the whole cycle to the sequential path. A speculation in
+        flight is dropped, and the gap around a cycle the bridge sat
+        out is one it did not observe (the gate, _take_speculation)."""
+        self._spec = self._gap_token = None
         self.fallback_reasons[reason] = \
             self.fallback_reasons.get(reason, 0) + 1
         self._count("oracle_fallback_total", (reason,))
@@ -947,7 +955,11 @@ class OracleBridge:
         cycle's encode + device dispatch may have been SPECULATED at
         the end of the previous one (_maybe_speculate);
         _take_speculation validates the state token and either consumes
-        the in-flight cycle or falls through to a fresh encode.
+        the in-flight cycle or falls through to a fresh encode. The
+        same comparison gates the next speculation: one is made only
+        if the gap before this cycle left the token where it was, so a
+        loop whose client speaks before every cycle launches once a
+        cycle, and a drain loop speculates after every one.
         Decisions are byte-identical either way: a speculation is used
         only when the engine state it encoded is bit-for-bit the state
         this cycle would encode.
@@ -967,10 +979,8 @@ class OracleBridge:
                 and not self.supervisor.allow_cycle(eng.cycle_seq)):
             # Breaker open: the device path is known-bad, skip straight
             # to the host path without paying retries or timeouts.
-            self._spec = None
             return self._fallback("breaker-open")
         if not self.world_is_fast_path_safe():
-            self._spec = None
             return self._fallback("world")
 
         if not any(pcq.items for pcq in
@@ -1017,28 +1027,29 @@ class OracleBridge:
         valid; None forces a fresh synchronous encode. What became of
         the speculation is stamped on ``take`` (this cycle's
         take_speculation span) and on the ``speculate`` span that paid
-        for it, one schedule_once() back."""
-        slot = self._spec
-        if slot is None:
+        for it, one schedule_once() back. Every device cycle passes
+        here, so this is also where the gap since the last stamp is
+        read for the gate: quiet (token unmoved, or not observed) or
+        mutated."""
+        token = self._state_token()
+        opened, self._gap_token = self._gap_token, None
+        self._gap_quiet = opened is None or opened == token
+        payload, self._spec = self._spec, None
+        if payload is None:
             take.attrs["outcome"] = "none"
             return None
-        self._spec = None
-        head, payload = slot
-        if head == "error":
+        if isinstance(payload, Exception):
             # The speculative dispatch failed. Surface the error HERE —
             # the point where the serial loop would have raised it —
             # so the engine's RemoteOracleError fallback (and every
             # chaos-injected oracle fault) behaves identically with the
             # pipeline on.
             raise payload
-        if head != self._state_token():
+        if opened != token:
             take.attrs["outcome"] = \
                 payload.spec_span.attrs["outcome"] = "discarded"
             self.pipeline_stats["discarded"] += 1
             self._count("oracle_pipeline_total", ("discarded",))
-            self._spec_miss += 1
-            if self._spec_miss >= 2:
-                self._spec_backoff = 1
             return None
         for fn, a in payload.deferred:
             fn(*a)
@@ -1052,49 +1063,47 @@ class OracleBridge:
             (last.ts + last.dur - first.ts) * 1e-6
         self.pipeline_stats["used"] += 1
         self._count("oracle_pipeline_total", ("used",))
-        self._spec_miss = 0
-        self._spec_backoff = 0
         return payload
 
     def _maybe_speculate(self, spec_span) -> None:
         """Encode cycle N+1 and run its device program NOW, inside this
-        schedule_once() (``spec_span`` is its open ``speculate`` span):
-        the executor returns with the verdicts read back, so nothing
-        overlaps the host yet (ROADMAP A2). Counter/stat side effects of
-        the speculative encode are deferred and committed only when the
-        speculation is USED, so a discarded one leaves every diagnostic
-        exactly as the serial loop would have (its ``lattice`` attr
-        stands either way: the launch was made)."""
+        schedule_once() (``spec_span`` is its open ``speculate`` span)
+        — if the last gap the bridge observed was quiet (the gate,
+        _take_speculation). The executor returns with the verdicts read
+        back, so nothing overlaps the host yet (ROADMAP A2), and across
+        a gap in which the client speaks the whole launch is thrown
+        away: after a mutated gap only the token is stamped, for the
+        next cycle to read the next gap by; the span then carries
+        ``gate = "closed"`` and has no children. Counter/stat side
+        effects of the speculative encode are deferred and committed
+        only when the speculation is USED, so a discarded one leaves
+        every diagnostic exactly as the serial loop would have (its
+        ``lattice`` attr stands either way: the launch was made)."""
         eng = self.engine
+        self._spec = self._gap_token = None
         if not pipeline_enabled():
-            self._spec = None
-            return
-        if self._spec_backoff > 0:
-            # Discard backoff in force: this world has been invalidating
-            # speculations faster than it can use them — sit out the
-            # window rather than burn another encode that will be
-            # thrown away.
-            self._spec_backoff -= 1
-            self.pipeline_stats["skipped"] += 1
-            self._spec = None
             return
         if not any(pcq.items for pcq in
                    eng.queues.cluster_queues.values()):
-            self._spec = None
+            return
+        if not self._gap_quiet:
+            spec_span.attrs["gate"] = "closed"
+            self.pipeline_stats["skipped"] += 1
+            self._gap_token = self._state_token()
             return
         try:
             enc = self._encode_cycle(defer_stats=True)
         except Exception as e:
-            self._spec = ("error", e)
+            self._spec = e
             return
         if isinstance(enc, _CycleExit):
-            self._spec = None
             return
         # The open ``speculate`` span: the next cycle stamps the outcome
         # on it.
         enc.spec_span = spec_span
         spec_span.attrs["lattice"] = enc.lattice
-        self._spec = (self._state_token(), enc)
+        self._gap_token = self._state_token()
+        self._spec = enc
         self.pipeline_stats["speculated"] += 1
 
     def _commit_tas_stats(self, tas_plan) -> None:

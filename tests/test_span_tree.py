@@ -34,6 +34,7 @@ from kueue_tpu.obs.span import (  # noqa: E402
     COUNT_KEYS,
     SpanRecorder,
     leaf_phases,
+    phase_seconds,
 )
 
 LAUNCH = ["upload", "upload", "dispatch", "device_wait", "readback"]
@@ -294,11 +295,21 @@ def test_discarded_speculation_is_stamped_and_counted_in_its_cycle():
     ph = eng.last_cycle_phases
     assert "spec_encode" not in ph
     assert (ph["n_spec_used"], ph["n_spec_discarded"]) == (0, 1)
-    # Every cycle_step call is a launch, thrown away or not: the
-    # cycle's own and its speculation's.
-    assert ph["n_launches"] == 2 * ph["n_device_cycles"] == 2
+    # Every cycle_step call is a launch, thrown away or not: the first
+    # schedule_once() made two, the cycle's own and its speculation's.
+    assert phase_seconds(first)["n_launches"] == 2
+    # The gap was mutated, so the gate is closed (ISSUE 28): the second
+    # launches once, and its speculate span says why it is empty.
+    skipped = child(second, "speculate")
+    assert skipped.attrs == {"gate": "closed"} and not skipped.children
+    assert "gate" not in spec.attrs and "n_spec_skipped" not in \
+        phase_seconds(first)
+    assert ph["n_launches"] == ph["n_device_cycles"] == 1
     assert ph["n_lattice_launches"] == 0
-    assert stats["speculated"] == 2
+    assert ph["n_spec_skipped"] == 1
+    assert (stats["speculated"], stats["skipped"]) == (1, 1)
+    assert_nested(second)
+    assert_adds_up(ph)
 
 
 # -- the phase dict ----------------------------------------------------
@@ -607,7 +618,9 @@ def test_profiler_capture_holds_the_tree(tmp_path):
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         submit(eng, "x", 100)
-        eng.schedule_once()  # the speculation is stale: a fresh encode
+        eng.schedule_once()  # the speculation is stale: a fresh encode,
+        #                      and the gate closes: an empty speculate
+        eng.schedule_once()  # a quiet gap: fresh encode, gate open again
         eng.schedule_once()  # this one's is used
     finally:
         jax.profiler.stop_trace()
@@ -634,15 +647,20 @@ def test_profiler_capture_holds_the_tree(tmp_path):
         assert host[2] <= dispatch[1] and dispatch[2] <= wait[1] \
             and wait[2] <= readback[1]
 
-    fresh, served = sorted(e for e in events
-                           if e[0] == "kueue.schedule_once")
-    for root in (fresh, served):
+    fresh, reopened, served = sorted(e for e in events
+                                     if e[0] == "kueue.schedule_once")
+    for root in (fresh, reopened, served):
         (cyc,) = inside("kueue.cycle", root)
         (spec,) = inside("kueue.speculate", root)
         assert cyc[2] <= spec[1]
         assert len(inside("kueue.take_speculation", cyc)) == 1
         assert len(inside("kueue.verdict_decode", cyc)) == 1
         assert not inside("kueue.verdict_decode", spec)
-        launched(spec)
+        if root is fresh:  # gate closed: nothing encoded or launched
+            assert not inside("kueue.host_encode", spec)
+            assert not inside("kueue.dispatch", spec)
+        else:
+            launched(spec)
     launched(inside("kueue.cycle", fresh)[0])
+    launched(inside("kueue.cycle", reopened)[0])
     assert not inside("kueue.dispatch", inside("kueue.cycle", served)[0])
