@@ -3,7 +3,8 @@
 
     python3 benchmark/control.py --workload <cell> --seed <n> --seconds <s>
 
-One run of run.py's whole path with the plain reference computed one
+One run of run.py's whole path with the cell's own plain reference (the
+module its world file names, or run.DEFAULT_MODULES') computed one
 precision below what the configuration states: every time it reads —
 the clock, creation and reservation times — in float32 instead of
 float64. At Unix time float32 cannot tell two workloads of one day
@@ -20,7 +21,6 @@ import argparse
 import struct
 import sys
 
-import plain
 import run
 
 
@@ -43,7 +43,8 @@ def main(argv=None) -> int:
     device = run.find_device(cell["chips"], rehearsal=args.tiny)
     result = run.run_cell(
         cell, args.seed, args.seconds, False, device,
-        make_reference=lambda w: plain.Plain(w, stamp=float32),
+        make_reference=lambda w: cell["modules"]["reference"].Plain(
+            w, stamp=float32),
         rehearsal=device["platform"] != "tpu")
     return 0 if not result["correct"] else 1
 

@@ -50,7 +50,9 @@ def differing(got: list, want: list, cohort_of: dict) -> list:
 def compare(world: dict, events: list, verdicts: list, make_reference,
             end_state: dict) -> dict:
     """Every cycle of the run against the reference. ``end_state`` is
-    the program's sut.Program.state() after the run's last cycle."""
+    the program's sut.Program.state() after the run's last cycle. The
+    reference's own verdicts go back under ``verdicts``: a world file's
+    added minimums are counted over them (run.load_kind)."""
     t0 = time.perf_counter()
     ref = make_reference(world)
     want = replay(ref, events)
@@ -62,4 +64,5 @@ def compare(world: dict, events: list, verdicts: list, make_reference,
             "evictions_compared": sum(
                 len(vs) for v in want for _h, vs in v["preempting"]),
             "end_state_differs": int(ref.state() != end_state),
+            "verdicts": want,
             "reference_s": time.perf_counter() - t0}

@@ -6,8 +6,8 @@
 One loop drives every cell, from the client's side, in the one process
 that holds the chip:
 
-    build the world from its file, relabelled by the seed (worldgen.py)
-    the system under test over it (sut.py): Engine + attach_oracle(local)
+    build the world from its file, relabelled by the seed (world builder)
+    the system under test over it (adapter): Engine + attach_oracle(local)
     for k in 0 .. :                       # `warmup_cycles` first: set-up
         finish and submit what the traffic file says (trafficgen.py)
         schedule_once()                   # the clock stops when the
@@ -16,15 +16,18 @@ that holds the chip:
         stop when --seconds are over
 
 Then, with the window closed, the peak memory read and the program's
-state freed, the plain reference (plain.py) decides every cycle of the
+state freed, the cell's plain reference decides every cycle of the
 run again from the events the loop wrote down, and every verdict and the
 end state are compared (reference.py).
 
-A cell is data: its world benchmark/worlds/<config>.json, its traffic
+A cell is files: its world benchmark/worlds/<config>.json, its traffic
 benchmark/traffic/<mix>.json, and for each per-layer metric one reader
 benchmark/layer_metrics/<name>.py, all found by the names in
-BENCHMARK.json. A new cell or metric is new files and new entries, and
-no edit here (benchmark/README.md).
+BENCHMARK.json; and the four modules that state its kind of deployment
+— world builder, adapter, reference, invariants — which the world file
+names under `modules` (DEFAULT_MODULES where it names none). A new
+cell, metric or kind of deployment is new files and new entries, and no
+edit here (benchmark/README.md).
 
 It exits non-zero before building anything unless JAX reports a TPU
 with the chips the cell asks for. `JAX_PLATFORMS=cpu ... --tiny` is the
@@ -44,6 +47,7 @@ import contextlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -53,13 +57,22 @@ for _p in (HERE, ROOT):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-import invariants  # noqa: E402
-import plain  # noqa: E402
 import reference  # noqa: E402
 import trafficgen as traffic_mod  # noqa: E402
-import worldgen  # noqa: E402
 
 TRACE_DIR = os.path.join(HERE, ".trace")  # git-ignored, emptied per run
+
+# The modules that state a kind of deployment, by role (README.md), for
+# a world file that names no other under `modules`. These four are
+# named here and nowhere else in run.py or control.py: every use goes
+# through what load_cell resolved.
+DEFAULT_MODULES = {"world_builder": "worldgen", "adapter": "sut",
+                   "reference": "plain", "invariants": "invariants"}
+# What every run has to have compared, whatever its world: a world
+# file's `compared_at_least` adds to these and raises them, never less.
+BASE_MINIMUMS = {"admissions_compared": 1, "evictions_compared": 1}
+MODULE_NAME = re.compile(r"^[A-Za-z_][A-Za-z0-9_]*$")
+
 
 def log(obj) -> None:
     print(json.dumps(obj) if not isinstance(obj, str) else obj,
@@ -71,9 +84,83 @@ def read_json(path: str) -> dict:
         return json.load(f)
 
 
+def read_config(name: str, tiny: bool = False) -> dict:
+    """benchmark/worlds/<name>.json; with ``tiny`` the file's `tiny`
+    sizes (the CPU tests' and rehearsals') laid over the real ones."""
+    cfg = read_json(os.path.join(HERE, "worlds", name + ".json"))
+    if tiny:
+        cfg.update(cfg.get("tiny", {}))
+    cfg["name"] = name
+    return cfg
+
+
+def module_from_file(name: str, path: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_module(name, asked_by: str):
+    """benchmark/<name>.py, under its own name in sys.modules, so that a
+    kind's modules can import one another. A name with no file is an
+    error that names the file, never a fall back to a default."""
+    path = os.path.join(HERE, f"{name}.py")
+    if not MODULE_NAME.match(str(name)) or not os.path.isfile(path):
+        raise SystemExit(f"{asked_by} names the module {name!r}: there "
+                         f"is no file {path}")
+    loaded = sys.modules.get(name)
+    if loaded is None or os.path.abspath(
+            getattr(loaded, "__file__", None) or "") != path:
+        loaded = sys.modules[name] = module_from_file(name, path)
+    return loaded
+
+
+def load_kind(cfg: dict) -> tuple:
+    """(modules, at_least) of a world file. The module of each role of
+    DEFAULT_MODULES: the file's own where its `modules` names one. And
+    what a run of it has to have compared, name -> (minimum, count):
+    BASE_MINIMUMS, raised where its `compared_at_least` says more and
+    counted by the comparison itself (count None); then the names that
+    key adds, each counted by count_<name>(world, verdicts) of its
+    reference module."""
+    asked_by = os.path.join(HERE, "worlds", cfg["name"] + ".json")
+    named = cfg.get("modules", {})
+    if set(named) - set(DEFAULT_MODULES):
+        raise SystemExit(
+            f"{asked_by}: `modules` has the roles "
+            f"{sorted(DEFAULT_MODULES)}, not "
+            f"{sorted(set(named) - set(DEFAULT_MODULES))}")
+    modules = {role: load_module(named.get(role, default), asked_by)
+               for role, default in DEFAULT_MODULES.items()}
+    at_least = {name: (least, None)
+                for name, least in BASE_MINIMUMS.items()}
+    for name, least in cfg.get("compared_at_least", {}).items():
+        if isinstance(least, bool) or not isinstance(least, int) \
+                or least < 1:
+            raise SystemExit(
+                f"{asked_by}: `compared_at_least` holds {name} to "
+                f"{least!r}; a minimum is a whole number, 1 or more — "
+                "a world file adds to what a run has to have compared "
+                "and takes nothing away")
+        if name in BASE_MINIMUMS:
+            at_least[name] = (max(least, BASE_MINIMUMS[name]), None)
+            continue
+        count = getattr(modules["reference"], "count_" + name, None)
+        if count is None:
+            raise SystemExit(
+                f"{asked_by}: `compared_at_least` names {name}, and "
+                f"{modules['reference'].__file__} has no function "
+                f"count_{name}(world, verdicts)")
+        at_least[name] = (least, count)
+    return modules, at_least
+
+
 def load_cell(name: str, tiny: bool = False) -> dict:
     """The cell's entry in BENCHMARK.json with its world and its
-    traffic mix read in, and the metrics it has to report."""
+    traffic mix read in, the modules its world file names and what a
+    run of it has to have compared (load_kind), and the metrics it has
+    to report."""
     bench = read_json(os.path.join(ROOT, "BENCHMARK.json"))
     cells = {c["name"]: c for c in bench["workloads"]}
     if name not in cells:
@@ -87,8 +174,9 @@ def load_cell(name: str, tiny: bool = False) -> dict:
 
     cell["end_to_end"] = mine(bench["end_to_end"])
     cell["per_layer"] = mine(bench["per_layer"])
-    cell["world"] = worldgen.read_config(cell["config"], tiny)
+    cell["world"] = read_config(cell["config"], tiny)
     cell["mix"] = traffic_mod.read_mix(cell["traffic"], tiny)
+    cell["modules"], cell["at_least"] = load_kind(cell["world"])
     return cell
 
 
@@ -100,11 +188,9 @@ def load_reader(name: str):
     if readers not in sys.path:
         sys.path.insert(0, readers)  # the readers share _common.py
     path = os.path.join(readers, name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "layer_metrics_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.reduce
+    return module_from_file(
+        "layer_metrics_" + name.replace(".", "_").replace("-", "_"),
+        path).reduce
 
 
 def find_device(chips: int, rehearsal: bool) -> dict:
@@ -187,10 +273,16 @@ def device_peak_bytes(stats: dict) -> int:
                + stats.get("peak_bytes_reserved", 0))
 
 
-def phase_means(cycles: list) -> dict:
+def phase_means(cycles: list) -> tuple:
+    """(ms, counts): the mean per cycle of every key of the program's
+    phases — its spans in milliseconds, and its counts (the `n_*` keys,
+    obs.span.COUNT_KEYS) as counts."""
     keys = sorted({k for c in cycles for k in c["phases"]})
-    return {k: round(sum(c["phases"].get(k, 0.0) for c in cycles) * 1e3
-                     / len(cycles), 3) for k in keys}
+    mean = {k: sum(c["phases"].get(k, 0.0) for c in cycles) / len(cycles)
+            for k in keys}
+    return ({k: round(mean[k] * 1e3, 3) for k in keys
+             if not k.startswith("n_")},
+            {k: round(mean[k], 3) for k in keys if k.startswith("n_")})
 
 
 class Loop:
@@ -243,25 +335,26 @@ class Loop:
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
              device: dict, make_program=None, make_reference=None,
              rehearsal: bool = False, max_cycles: int | None = None,
-             out=sys.stdout) -> dict:
+             out=None) -> dict:
     """Everything after the look for a chip: set-up, warm-up, the
     window, the metrics, and — once the window has closed, the peak has
     been read and the program's state is freed — the comparison with
     the reference. ``make_program(world)`` builds the system under test
     (the tests pass broken ones) and ``make_reference(world)`` the
-    reference (the control passes one in a lower precision)."""
+    reference (the control passes one in a lower precision); left out,
+    they are the cell's own adapter and reference."""
     import jax
 
-    import sut
-
-    make_program = make_program or (lambda w: sut.Program(w, "local"))
-    make_reference = make_reference or plain.Plain
+    kind = cell["modules"]
+    make_program = make_program or (
+        lambda w: kind["adapter"].Program(w, "local"))
+    make_reference = make_reference or kind["reference"].Plain
     mix, cfg = cell["mix"], cell["world"]
     stages = {"imports": time.perf_counter() - T0}
     clog = CompileLog()
 
     t = time.perf_counter()
-    world = worldgen.build_world(cfg, seed)
+    world = kind["world_builder"].build_world(cfg, seed)
     stages["records"] = time.perf_counter() - t
     t = time.perf_counter()
     program = make_program(world)
@@ -332,16 +425,19 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     # -- the comparison with the reference --------------------------
     cmp_ = reference.compare(world, loop.events, loop.verdicts,
                              make_reference, end_state)
-    breaches = invariants.check(world, loop.events, loop.verdicts)
+    breaches = kind["invariants"].check(world, loop.events, loop.verdicts)
     checks["guarantees_broken"] = (len(breaches), 0)
     checks["cycles_differing"] = (cmp_["cycles_differing"], 0)
     checks["end_state_differs"] = (cmp_["end_state_differs"], 0)
     # What has to have been compared: every cycle, and in them the
-    # layers the cell's `why` names — admissions and evictions both.
+    # layers the cell's `why` names — admissions and evictions both, in
+    # every world; and what the world file adds of its own, counted by
+    # its reference module over the reference's verdicts.
     at_least = {"cycles_compared": (cmp_["cycles_compared"],
-                                    len(loop.events)),
-                "admissions_compared": (cmp_["admissions_compared"], 1),
-                "evictions_compared": (cmp_["evictions_compared"], 1)}
+                                    len(loop.events))}
+    for name, (least, count) in cell["at_least"].items():
+        at_least[name] = (count(world, cmp_["verdicts"]) if count
+                          else cmp_[name], least)
     correct = all(v <= lim for v, lim in checks.values()) \
         and all(v >= lim for v, lim in at_least.values())
 
@@ -362,7 +458,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
              "traced_cycles": n_trace}
     info = {"sizes": sizes1, "cfg": cfg, "device_kind": device["kind"],
             "pipeline": counters["pipeline"],
-            "buckets": worldgen.device_bytes(cfg)}
+            "buckets": kind["world_builder"].device_bytes(cfg)}
     if trace:
         import trace_reduce
 
@@ -387,6 +483,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         **{k: {"value": v, "limit_min": lim}
            for k, (v, lim) in at_least.items()})
 
+    phase_ms, phase_counts = phase_means(cycles)
     log({"run": {
         "cell": cell["name"], "seed": seed, "cycles": n,
         "warmup_cycles": mix["warmup_cycles"], "admitted": admitted,
@@ -402,7 +499,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
             for sig in signatures),
         "compiled_in_window": compiled, "counters": counters,
         "memory_stats": stats,
-        "phase_ms_mean": phase_means(cycles),
+        "phase_ms_mean": phase_ms, "phase_count_mean": phase_counts,
         "executor_call_ms_mean": (sum(calls) * 1e3 / len(calls)
                                   if calls else None),
         "cycle_ms": [round(x) for x in cycle_ms],
@@ -420,7 +517,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         + (f"{v['limit']}" if "limit" in v else f">= {v['limit_min']}")
         + ")"
         for k, v in result["compared"].items()))
-    print(json.dumps(result), file=out, flush=True)
+    print(json.dumps(result), file=out or sys.stdout, flush=True)
     return result
 
 

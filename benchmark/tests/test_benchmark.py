@@ -9,8 +9,9 @@ sequential core does under three stanzas; the control (the reference in
 float32 time) and each fault a cell can have come out as `correct`
 false; the bucket-stays-put assertion fires; every seed runs one
 scenario under other labels; the trace reduction gives the numbers
-worked out by hand from a small recorded chip trace; BENCHMARK.json
-keeps to the contract's shapes.
+worked out by hand from a small recorded chip trace. What needs no
+engine is in test_contract.py, and the seam between the harness and a
+kind of deployment's own modules in test_seam.py.
 """
 
 from __future__ import annotations
@@ -18,7 +19,6 @@ from __future__ import annotations
 import io
 import json
 import os
-import re
 import sys
 
 import pytest
@@ -250,7 +250,7 @@ def test_second_cycle_program_fails_the_run():
 
 
 def test_every_seed_runs_one_scenario_under_other_labels():
-    cfg = worldgen.read_config(CELLS[0].split(".")[0], tiny=True)
+    cfg = run.read_config(CELLS[0].split(".")[0], tiny=True)
 
     def census(seed):
         w = worldgen.build_world(cfg, seed)
@@ -370,52 +370,3 @@ def test_unknown_device_kind_is_an_error():
 
     with pytest.raises(SystemExit):
         rooflines.peaks_for("TPU v9 imaginary")
-
-
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-
-
-def test_benchmark_json_keeps_to_the_contract():
-    b = run.read_json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert set(b) == {"command", "paths", "run_seconds", "configs",
-                      "workloads", "end_to_end", "per_layer"}
-    assert 1 <= b["run_seconds"] <= 51
-    configs = {c["name"]: c for c in b["configs"]}
-    for c in b["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert NAME.match(c["name"]) and len(c["source"]) <= 200
-        assert os.path.isfile(os.path.join(ROOT, c["file"]))
-        assert read_source(c["file"]) == c["source"]
-    assert len({c["file"] for c in b["configs"]}) == len(configs)
-    cells = set()
-    for w in b["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
-        assert w["config"] in configs and w["chips"] in (1, 4)
-        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
-        assert os.path.isfile(os.path.join(
-            BENCH, "traffic", w["traffic"] + ".json"))
-        cells.add(w["name"])
-    assert {w["config"] for w in b["workloads"]} == set(configs)
-    e2e = {m["name"]: m for m in b["end_to_end"]}
-    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.1
-    for m in b["end_to_end"]:
-        assert set(m) <= {"name", "unit", "better", "bound", "source",
-                          "workloads"}
-        assert 0.01 <= m["bound"] <= 0.1
-        assert m["source"] in ("host_clock", "device_trace")
-    for m in b["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert NAME.match(m["name"]) and m["moves"] in e2e
-        assert m["better"] in ("lower", "higher")
-        assert set(m.get("workloads", [])) <= cells
-        assert os.path.isfile(os.path.join(
-            BENCH, "layer_metrics", m["name"] + ".py"))
-    names = [m["name"] for m in b["end_to_end"] + b["per_layer"]]
-    assert len(names) == len(set(names))
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 65536
-
-
-def read_source(path):
-    return run.read_json(os.path.join(ROOT, path))["source"]

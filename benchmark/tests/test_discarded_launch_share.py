@@ -45,6 +45,9 @@ GATED = [cycle(1, skipped=1)] * 29
 WINDOWS = {
     "backoff: 9 of 25": (BACKOFF, 36.0),
     "gated: launches, no speculation": (GATED, 0.0),
+    "gated: 0 used and 0 discarded, counted": (
+        [{"phases": dict(c["phases"], n_spec_used=0, n_spec_discarded=0)}
+         for c in GATED], 0.0),
     "drain: every speculation used": (
         [{"phases": {"n_launches": 1, "n_spec_used": 1,
                      "n_spec_discarded": 0}}] * 5, 0.0),
@@ -70,17 +73,6 @@ def test_share_of_launches_thrown_away(window):
     assert want is None or isinstance(got, float)
 
 
-def test_discarded_pct_reads_nothing_on_0_of_0():
-    """Behind the gate the window learns of no speculation at all: the
-    accepted ratio has nothing under it and says nothing, never 0."""
-    reader = run.load_reader("speculation_discarded_pct")
-    assert reader(None, {"cycles": GATED}, {}) is None
-    zeros = [{"phases": dict(c["phases"], n_spec_used=0,
-                             n_spec_discarded=0)} for c in GATED]
-    assert reader(None, {"cycles": zeros}, {}) is None
-    assert reader(None, {"cycles": BACKOFF}, {}) == pytest.approx(100.0)
-
-
 def test_speculation_ms_of_an_empty_span_is_a_number():
     assert run.load_reader("speculation_ms")(
         None, {"cycles": GATED}, {}) == pytest.approx(0.0)
@@ -89,13 +81,12 @@ def test_speculation_ms_of_an_empty_span_is_a_number():
 def test_the_metric_is_declared_last_with_its_reader():
     bench = run.read_json(os.path.join(ROOT, "BENCHMARK.json"))
     names = [m["name"] for m in bench["per_layer"]]
-    assert names.index(NAME) == 20 and len(set(names)) == len(names)
-    assert bench["per_layer"][20] == {
+    assert names.index(NAME) == 19 and len(set(names)) == len(names)
+    assert bench["per_layer"][19] == {
         "name": NAME, "unit": "%", "better": "lower",
         "source": "program_span", "layer": "speculation",
         "moves": "cycle_mean_ms", "workloads": [CELL]}
-    assert names[15:20] == [
-        "speculation_ms", "speculation_discarded_pct",
-        "preemptor_launch_share_pct", "heads_per_cycle",
+    assert names[15:19] == [
+        "speculation_ms", "preemptor_launch_share_pct", "heads_per_cycle",
         "schedule_once_unattributed_ms"]
     assert os.path.isfile(os.path.join(BENCH, "layer_metrics", NAME + ".py"))

@@ -1,4 +1,5 @@
-"""One builder for every world: it reads the numbers of
+"""The world builder of the flat one-flavor kind, and of every world file
+that names no other (run.DEFAULT_MODULES): it is handed the numbers of
 benchmark/worlds/<config>.json and the seed, and returns plain records —
 nothing of the program is imported here, so the system under test
 (sut.py) and the reference (plain.py) are handed the same data.
@@ -20,23 +21,7 @@ Records (tuples, cheap at tens of thousands of objects):
 
 from __future__ import annotations
 
-import json
-import os
 import random
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def read_config(name: str, tiny: bool = False) -> dict:
-    """benchmark/worlds/<name>.json; with ``tiny`` the file's `tiny`
-    sizes (the CPU tests' and rehearsals') laid over the real ones."""
-    with open(os.path.join(HERE, "worlds", name + ".json"),
-              encoding="utf-8") as f:
-        cfg = json.load(f)
-    if tiny:
-        cfg.update(cfg.get("tiny", {}))
-    cfg["name"] = name
-    return cfg
 
 
 def pow2_bucket(n: int, floor: int) -> int:
