@@ -11,6 +11,17 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     │  ├─ take_speculation        attrs outcome = used | discarded | none
     │  ├─ host_encode             _encode_cycle up to the device cycle
     │  │  └─ tas_place            (attrs heads, pending)
+    │  ├─ sim_nomination          multi-flavor groups on preempting CQs
+    │  │  │                       only; host_encode runs on after it;
+    │  │  │                       attrs heads, rows, launches, overflow
+    │  │  ├─ flavor_grid          ops/assign.flavor_grid + readback
+    │  │  ├─ sim_rows             one row a Preempt-gated cell
+    │  │  ├─ sim_launch           the sim program, one block of rows
+    │  │  │                       a launch (attrs rows, rows_padded,
+    │  │  │                       launches; bytes moved, upload_s,
+    │  │  │                       device_wait_s, readback_s)
+    │  │  ├─ fungibility_fold     the flavor walk, array code
+    │  │  └─ sim_targets          the cycle program's slot overrides
     │  ├─ upload                  host arrays -> device (attrs bytes)
     │  ├─ dispatch                cycle_step(...) returning futures
     │  ├─ device_wait             block_until_ready on the outputs
@@ -115,7 +126,8 @@ class Span:
 # The spans whose self time is ``unattributed``; everything directly
 # under one of them is a leaf of the identity
 #   sum(leaves) + unattributed == schedule_once.
-CONTAINERS = frozenset({"schedule_once", "cycle", "speculate"})
+CONTAINERS = frozenset({"schedule_once", "cycle", "speculate",
+                        "sim_nomination"})
 
 # Keys of Engine.last_cycle_phases that repeat time the leaf keys
 # already hold: a nested span (tas_place, inside host_encode), a
@@ -131,7 +143,8 @@ CONTAINERS = frozenset({"schedule_once", "cycle", "speculate"})
 #                 child's start to last child's end, paid inside the
 #                 previous schedule_once()
 AGGREGATE_KEYS = frozenset({"tas_place", "speculate", "schedule_once",
-                            "encode", "device", "spec_encode"})
+                            "encode", "device", "spec_encode",
+                            "sim_nomination"})
 
 # Keys of Engine.last_cycle_phases that are counts of this
 # schedule_once(), not seconds, summed from span attrs recorded at the
@@ -146,9 +159,16 @@ AGGREGATE_KEYS = frozenset({"tas_place", "speculate", "schedule_once",
 #       (attr ``gate``): nothing encoded, nothing launched
 #   n_device_cycles, n_device_heads verdict_decode spans, and the heads
 #       the device decided in them (attr ``device_heads``)
+#   n_sim_heads, n_sim_rows, n_sim_launches, n_sim_overflow
+#       ``sim_nomination`` spans' attrs: the heads whose flavor choice
+#       needed preemption simulations, the (head, flavor, resource)
+#       cells simulated, the sim program's launches, and the heads the
+#       sim program handed to the host (more candidates than it scans)
 COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches", "n_spec_used",
                         "n_spec_discarded", "n_spec_skipped",
-                        "n_device_cycles", "n_device_heads"})
+                        "n_device_cycles", "n_device_heads",
+                        "n_sim_heads", "n_sim_rows", "n_sim_launches",
+                        "n_sim_overflow"})
 
 
 class SpanRecorder:
@@ -204,6 +224,14 @@ class SpanRecorder:
     def span(self, name: str, **attrs) -> "SpanRecorder":
         self._push(name, attrs, self.clock())
         return self
+
+    def add(self, **amounts) -> None:
+        """Add ``amounts`` to the innermost open span's attrs of the
+        same names (code that runs inside a leaf and has bytes or
+        seconds to report, without a span of its own)."""
+        attrs = self._open[-1].attrs
+        for key, more in amounts.items():
+            attrs[key] = attrs.get(key, 0) + more
 
     def __enter__(self) -> Span:
         self._marks.append(len(self._open))
@@ -284,6 +312,10 @@ def phase_seconds(root: Span) -> dict:
                         _add(out, "n_spec_skipped", 1)
                 elif c.name == "cycle":
                     _cycle_aggregates(c, out)
+                elif c.name == "sim_nomination":
+                    _add(out, "sim_nomination", c.dur * 1e-6)
+                    for attr in ("heads", "rows", "launches", "overflow"):
+                        _add(out, "n_sim_" + attr, c.attrs.get(attr, 0))
                 continue
             _add(out, c.name, c.dur * 1e-6)
             for s in c.children:  # tas_place, in host_encode

@@ -106,8 +106,8 @@ def flavor_grid(
     for the sim-augmented nomination: cells flagged ``sim`` need a
     preemption simulation (preemption_oracle.go:41) before the
     fungibility lattice can pick the flavor; the bridge runs those sims
-    with ops/preempt.classical_targets and folds the lattice host-side
-    with the exact scheduler/flavorassigner code.
+    with the sim program (ops/preempt.sim_targets) and folds the lattice
+    as array code (engine_bridge._fold_fungibility).
 
     Returns (pmode int32[C, G, F, S] in {NO_FIT, NO_CANDIDATES, FIT},
     borrow int32[C, G, F, S] pre-sim, sim bool[C, G, F, S],

@@ -259,8 +259,8 @@ def commit_grouped(
     cycle on TPU.
 
     slot_victim_* carry device-selected preemption victims for
-    ENTRY_PREEMPT slots (ops/preempt.classical_targets output): the fit
-    check runs with the victims removed along their own chains, removals
+    ENTRY_PREEMPT slots (ops/preempt.classical_targets_impl output): the
+    fit check runs with the victims removed along their own chains, removals
     persist on success, and victim overlap between entries applies the
     one-admission-per-cohort rule (scheduler.go:432).
 

@@ -621,11 +621,23 @@ def classical_targets_impl(
 
 
 @partial(jax.jit, static_argnames=("depth", "v_cap"))
-def classical_targets(*args, depth: int, v_cap: int, slot_cq=None,
-                      adm_rank=None, adm_by_root=None):
-    """Jitted standalone form (the oracle-service op): drops the packed
-    per-slot victim lists that only the fused cycle kernel consumes."""
-    return classical_targets_impl(*args, slot_cq=slot_cq,
-                                  adm_rank=adm_rank,
-                                  adm_by_root=adm_by_root, depth=depth,
-                                  v_cap=v_cap)[:6]
+def sim_targets(*args, slot_cq, adm_rank, adm_by_root, depth: int,
+                v_cap: int):
+    """The sim program (preemption_oracle.go:41 SimulatePreemption, one
+    row per (head, flavor, resource) cell): the classical preemptor over
+    a block of rows, returning only what the fungibility fold reads —
+    found bool[B], overflow bool[B], borrow_after int32[B], and whether
+    any victim sits in the row's own ClusterQueue (Preempt, else
+    Reclaim). The victim sets stay on the device: the fold does not
+    need them, and the final target selection is the cycle program's."""
+    adm_cq = args[10]
+    out = classical_targets_impl(*args, slot_cq=slot_cq,
+                                 adm_rank=adm_rank,
+                                 adm_by_root=adm_by_root, depth=depth,
+                                 v_cap=v_cap)
+    found, overflow, borrow_after, v_ids, taken = (
+        out[0], out[1], out[5], out[6], out[7])
+    same = jnp.any(taken & (v_ids >= 0)
+                   & (adm_cq[jnp.maximum(v_ids, 0)] == slot_cq[:, None]),
+                   axis=1)
+    return found, overflow, borrow_after, same

@@ -81,21 +81,24 @@ def _cycle_core(
     fair_weight=None,  # float64[N]
     child_rank=None,  # int64[N] fair-tournament child-order tiebreak
     local_depth=None,  # int32[Rn, K] fair-tournament level structure
-    slot_kind_override=None,  # int32[C] ENTRY_* (-1 = use computed kind);
-    #   set to ENTRY_PREEMPT/ENTRY_RESERVE by the bridge after device
-    #   preemption target selection (ops/preempt.classical_targets)
-    slot_borrows_override=None,  # int32[C] post-preemption borrow level
-    #   (-1 = keep): the commit iterator orders preempting entries by the
-    #   borrow level WITH their victims removed (preemption_oracle.go:41)
+    slot_kind_override=None,  # int32[C] ENTRY_* (-1 = use computed kind),
+    #   from the bridge's sim-augmented nomination (multi-flavor groups
+    #   on preemption-enabled CQs): ENTRY_FIT where the fungibility fold
+    #   chose a flavor that fits; ENTRY_PREEMPT where the chosen flavor's
+    #   mode is Preempt — the victims are then selected HERE, by the
+    #   fused preemptor below, on the overridden flavor (preemption.go:129
+    #   GetTargets runs for any Preempt-mode nomination)
+    slot_borrows_override=None,  # int32[C] the assignment's borrow level
+    #   (-1 = keep): the worst over the head's resources of what each
+    #   cell said — a simulated cell's is the borrow WITH its own victims
+    #   removed (preemption_oracle.go:41) — which the commit iterator
+    #   orders by (scheduler.go:971); it stands whatever the final
+    #   target selection frees
     slot_flavor_override=None,  # int32[C, S] flavor per resource (-1 =
     #   keep computed): set by the bridge's sim-augmented nomination when
     #   the fungibility lattice needed preemption simulations to pick the
     #   flavor (multi-flavor groups, flavorassigner.go:1127)
     root_parent_local=None,  # int32[Rn, K] (victim-removal bubbling)
-    slot_victim_row=None,  # int32[C, V] victim CQ local positions
-    slot_victim_vals=None,  # int64[C, V, R] victim usage rows
-    slot_victim_ids=None,  # int32[C, V] admitted ids (overlap rule)
-    claimed0=None,  # bool[A] initially-claimed victims
     # --- fused classical preemption (round 2): when the admitted
     # tensors + policy config are provided, preempt-flagged slots get
     # their victim sets selected INSIDE this program
@@ -176,9 +179,6 @@ def _cycle_core(
                 group_flavors, no_preemption, can_pwb, fung_borrow_try_next,
                 fung_pref_preempt_first, flavor_ok=h_ok,
                 depth=depth, num_resources=S)
-    if slot_borrows_override is not None:
-        borrows = jnp.where(slot_borrows_override >= 0,
-                            slot_borrows_override, borrows)
     if slot_flavor_override is not None:
         # Sim-nomination overrides are single-podset by construction
         # (the bridge demotes multi-podset sim heads): apply at podset 0
@@ -223,8 +223,18 @@ def _cycle_core(
     overridden = jnp.zeros((C,), bool)
     if slot_kind_override is not None:
         overridden = slot_valid & (slot_kind_override >= 0)
-        kind = jnp.where(overridden, slot_kind_override, kind)
-        needs_oracle = needs_oracle & ~overridden
+        # A Preempt-mode override is an oracle slot on the overridden
+        # flavor; its nomination mode is the bridge's, not the pre-sim
+        # pass's (parking below reads it).
+        want_targets = overridden & (slot_kind_override
+                                     == cops.ENTRY_PREEMPT)
+        kind = jnp.where(overridden & ~want_targets, slot_kind_override,
+                         jnp.where(want_targets, cops.ENTRY_SKIP, kind))
+        needs_oracle = (needs_oracle & ~overridden) | want_targets
+        pmode = jnp.where(
+            want_targets, aops.P_NO_CANDIDATES,
+            jnp.where(overridden & (slot_kind_override == cops.ENTRY_FIT),
+                      aops.P_FIT, pmode))
     slot_oracle = needs_oracle & slot_valid
     # Commit against the freshly-aggregated full usage (cohort rows are
     # derived from CQ rows; the raw carry may predate aggregation).
@@ -236,6 +246,7 @@ def _cycle_core(
     victim_mask = jnp.zeros((C, 0), bool)
     victim_variant = jnp.zeros((C, 0), jnp.int32)
     fused_preempt = jnp.zeros((C,), bool)
+    slot_victim_row = slot_victim_vals = slot_victim_ids = claimed0 = None
     if adm_cq is not None and not fair_mode:
         from kueue_tpu.ops import preempt as pops
 
@@ -309,21 +320,18 @@ def _cycle_core(
                 [f_vals, jnp.zeros((C, pad, R), f_vals.dtype)], axis=1)
             f_ids = jnp.concatenate(
                 [f_ids, jnp.full((C, pad), -1, f_ids.dtype)], axis=1)
-        if slot_victim_row is None:
-            slot_victim_row, slot_victim_vals, slot_victim_ids = \
-                f_row, f_vals, f_ids
-        else:
-            m = pfound[:, None]
-            slot_victim_row = jnp.where(m, f_row, slot_victim_row)
-            slot_victim_vals = jnp.where(m[:, :, None], f_vals,
-                                         slot_victim_vals)
-            slot_victim_ids = jnp.where(m, f_ids, slot_victim_ids)
-        if claimed0 is None:
-            claimed0 = jnp.zeros((adm_cq.shape[0],), bool)
+        slot_victim_row, slot_victim_vals, slot_victim_ids = \
+            f_row, f_vals, f_ids
+        claimed0 = jnp.zeros((adm_cq.shape[0],), bool)
         # Every flagged slot is decided in-program; overflow slots are
         # reported separately for host-root demotion.
         needs_oracle = needs_oracle & jnp.zeros((C,), bool)
         slot_oracle = slot_oracle & jnp.zeros((C,), bool)
+    if slot_borrows_override is not None:
+        # The bridge's borrow stands over the nomination pass's and the
+        # fused preemptor's alike.
+        borrows = jnp.where(slot_borrows_override >= 0,
+                            slot_borrows_override, borrows)
     with jax.named_scope("kueue.commit"):
         if fair_mode:
             # 4f/5f. Fair-sharing tournament ordering fused with the commit

@@ -20,10 +20,12 @@ member ClusterQueues needs the host this cycle:
 
 Multi-flavor resource groups on preemption-enabled ClusterQueues run
 the sim-augmented nomination: the pre-oracle flavor grid
-(ops/assign.flavor_grid) plus per-cell preemption simulations
-(ops/preempt.classical_targets standing in for
-preemption_oracle.go:41), folded through the exact host fungibility
-lattice, then committed via kernel overrides.
+(ops/assign.flavor_grid) plus per-cell preemption simulations (the sim
+program, ops/preempt.sim_targets, standing in for
+preemption_oracle.go:41: one fixed block of rows a world), folded
+through the fungibility lattice as array code (_fold_fungibility), then
+committed via the cycle program's slot overrides; a Preempt-mode head's
+victims are the cycle program's fused preemptor's, on the chosen flavor.
 Host roots are handed to the engine's sequential path in the same
 schedule_once() call (engine._sequential_cycle); because roots never
 share quota, device-then-host commit order is cycle-equivalent to the
@@ -100,8 +102,76 @@ def pipeline_enabled() -> bool:
 _PER_CYCLE_UPLOADS = (
     "rank", "commit_rank", "wl_cq", "wl_req", "wl_priority", "wl_has_qr",
     "wl_hash", "wl_ts", "wl_flavor_ok", "pending", "usage", "slot_maybe",
-    "slot_kind_override", "slot_borrows_override", "slot_flavor_override",
-    "slot_victim_row", "slot_victim_vals", "slot_victim_ids")
+    "slot_kind_override", "slot_borrows_override", "slot_flavor_override")
+
+
+# The sim program's per-row inputs and what pads a block's unused rows
+# (slot_need False: a padded row simulates nothing).
+_SIM_ROW_FILLS = {"slot_cq": 0, "slot_need": False, "slot_pri": 0,
+                  "slot_ts": 0.0, "slot_fr": -1, "slot_req": 0}
+
+
+def _fold_fungibility(pm, br, in_group, group_flavors, borrow_try_next,
+                      preempt_try_next, pref_preempt_first):
+    """findFlavorForPodSets (flavorassigner.go:932) for every slot at
+    once, on the granular modes of its cells after the simulations:
+    ``pm`` / ``br`` int[C, G, F, S] (PMode, borrow), ``in_group``
+    bool[C, G, S] (the slot requests this resource of this group).
+
+    A flavor's representative mode is the worst of its resources'
+    (isPreferred); the group's flavors are walked in order, the walk
+    stops at the first that need not try the next (shouldTryNextFlavor),
+    else the best seen wins. Returns per slot (flavor int32[C, S] per
+    resource, -1 none; mode int[C], the worst PMode over the chosen
+    flavors' resources, NO_FIT where a group found none; borrow
+    int[C], the assignment's borrow level — the MAX over those
+    resources' own, Assignment.append: a Fit resource that borrows
+    keeps the entry behind the non-borrowing ones even where the
+    simulated resource's victims would end its queue's borrowing)."""
+    from kueue_tpu.scheduler.flavorassigner import PMode
+
+    C, G, F, S = pm.shape
+    big = np.int64(1) << 20
+    bottom = -big * big
+    pm, br = pm.astype(np.int64), br.astype(np.int64)
+    key = np.where(pref_preempt_first[:, None, None, None],
+                   -br * big + pm, pm * big - br)
+    key = np.where(pm == int(PMode.NO_FIT), bottom, key)
+    mask = in_group[:, :, None, :]
+    worst = np.where(mask, key, np.iinfo(np.int64).max).argmin(
+        axis=3)[..., None]
+    rep_key = np.take_along_axis(key, worst, 3)[..., 0]  # [C, G, F]
+    rep_pm = np.take_along_axis(pm, worst, 3)[..., 0]
+    rep_br = np.take_along_axis(br, worst, 3)[..., 0]
+    active = in_group.any(axis=2)  # [C, G]
+    valid = (group_flavors >= 0) & active[:, :, None]
+    try_next = (
+        (rep_pm <= int(PMode.NO_CANDIDATES))
+        | (((rep_pm == int(PMode.PREEMPT)) | (rep_pm == int(PMode.RECLAIM)))
+           & preempt_try_next[:, None, None])
+        | ((rep_br > 0) & borrow_try_next[:, None, None]))
+    best_key = np.full((C, G), bottom, np.int64)
+    best_f = np.full((C, G), -1, np.int64)
+    stopped = np.zeros((C, G), bool)
+    for f in range(F):
+        consider = valid[:, :, f] & ~stopped
+        stop = consider & ~try_next[:, :, f]
+        take = stop | (consider & (rep_key[:, :, f] > best_key))
+        best_key = np.where(take, rep_key[:, :, f], best_key)
+        best_f = np.where(take, f, best_f)
+        stopped |= stop
+    at = np.maximum(best_f, 0)[:, :, None, None]
+    pm_at = np.take_along_axis(pm, at, 2)[:, :, 0, :]  # [C, G, S]
+    br_at = np.take_along_axis(br, at, 2)[:, :, 0, :]
+    found = (best_f >= 0)[:, :, None] & in_group
+    missing = (active & (best_f < 0)).any(axis=1)
+    mode = np.where(in_group, pm_at, int(PMode.FIT)).min(axis=(1, 2))
+    mode = np.where(missing, int(PMode.NO_FIT), mode)
+    borrow = np.where(found, br_at, 0).max(axis=(1, 2))
+    flavor = np.take_along_axis(group_flavors, np.maximum(best_f, 0)[
+        :, :, None], 2)[:, :, 0]  # [C, G]
+    choice = np.where(found, flavor[:, :, None], -1).max(axis=1)
+    return choice.astype(np.int32), mode, borrow
 
 
 def _lattice_ran(out, w, slot_maybe) -> Optional[bool]:
@@ -443,8 +513,8 @@ class OracleBridge:
 
     def _cq_policy_cfg(self, w):
         """Per-CQ preemption-policy encoding for the device classical
-        preemptor (ops/preempt.classical_targets), which covers the full
-        classical policy surface. Memoized by spec version."""
+        preemptor (ops/preempt.classical_targets_impl), which covers the
+        full classical policy surface. Memoized by spec version."""
         from kueue_tpu.api.types import (
             BorrowWithinCohortPolicy,
             PreemptionPolicy,
@@ -615,311 +685,184 @@ class OracleBridge:
         self._maybe_memo = (adm, pcfg, np.array(head_pri), maybe)
         return maybe
 
-    def _classical_call(self, w, adm, pcfg, usage, slot_need, slot_pri,
-                        slot_ts, slot_fr, slot_req, v_cap=32,
-                        derived=None, slot_cq=None):
-        """One batched classical_targets launch via the executor;
-        returns numpy (found, overflow, mask, variant, borrow_after).
-        Pass ``derived`` when the caller already ran quota.derive_world
-        for this usage (in-process execution reuses it). ``slot_cq``
-        decouples rows from CQ ids (batched sim cells)."""
-        C = slot_need.shape[0]
-        live = adm.live if adm.live is not None else adm.num_admitted
-        if live == 0:
-            return (np.zeros(C, bool), np.zeros(C, bool),
-                    np.zeros((C, 0), bool), np.zeros((C, 0), np.int32),
-                    np.zeros(C, np.int32))
-        ap = self._adm_padded(adm, w)
-        adm_cq = ap["adm_cq"]
-        adm_pri = ap["adm_pri"]
-        adm_ts = ap["adm_ts"]
-        adm_qrt = ap["adm_qrt"]
-        adm_uid = ap["adm_uid"]
-        adm_ev = ap["adm_ev"]
-        adm_usage = ap["adm_usage"]
-        tensors = dict(
-            slot_need=slot_need, slot_pri=slot_pri, slot_ts=slot_ts,
-            slot_fr=slot_fr, slot_req=slot_req,
-            wcq_policy=pcfg["wcq_policy"],
-            reclaim_policy=pcfg["reclaim_policy"],
-            bwc_forbidden=pcfg["bwc_forbidden"],
-            bwc_threshold=pcfg["bwc_threshold"],
-            cq_has_parent=pcfg["cq_has_parent"],
-            adm_cq=adm_cq, adm_pri=adm_pri, adm_ts=adm_ts,
-            adm_qrt=adm_qrt, adm_uid=adm_uid, adm_ev=adm_ev,
-            adm_rank=ap["adm_rank"],
-            adm_by_root=ap["adm_by_root"],
-            adm_usage=adm_usage, usage=usage, nominal=w.nominal,
-            lend_limit=w.lend_limit, borrow_limit=w.borrow_limit,
-            parent=w.parent, ancestors=w.ancestors, height=w.height,
-            local_chain=w.local_chain, root_nodes=w.root_nodes,
-            root_of_cq=w.root_of_cq)
-        if slot_cq is not None:
-            tensors["slot_cq"] = slot_cq
-        out = self._exec_call(
-            "classical_targets", self.executor.classical_targets,
-            tensors, {"depth": w.depth, "v_cap": v_cap}, derived=derived)
-        found, overflow, mask, _n, variant, borrow_after = out
-        return (np.array(found), np.array(overflow), np.array(mask),
-                np.array(variant), np.array(borrow_after))
+    @staticmethod
+    def _sim_block(w) -> int:
+        """Rows of the sim program's one launch shape for this world:
+        one a ClusterQueue, to the next power of two. A cycle has one
+        head a queue and few of a head's (flavor, resource) cells need
+        a simulation, so most cycles are one launch; a cycle with more
+        rows loops the block (_sim_launch). The program compiles once
+        (in warm-up) and its device bytes are bounded by the world —
+        its temporaries are a (block row x padded running workload of
+        the fullest cohort root) lattice — not by the cycle."""
+        from kueue_tpu.tensor.schema import pow2_bucket
 
-    def _sim_nomination(self, w, wls, usage, head_idx, sim_slots,
-                        adm, admitted, pcfg, v_cap=32):
+        return pow2_bucket(w.num_cqs, 8)
+
+    def _sim_launch(self, w, adm, pcfg, usage, derived, rows: dict,
+                    block: int, v_cap: int):
+        """The simulation rows through the sim program (the executor's
+        sim_targets), ``block`` rows a launch and as many launches as
+        the rows need, the last padded: numpy (found, overflow,
+        borrow_after, same_cq) per row. An in-process executor adds to
+        the open ``sim_launch`` span what the launches moved between
+        host and device (bytes) and where their wall time went
+        (upload_s, device_wait_s, readback_s)."""
+        from kueue_tpu.tensor.schema import pad_axis0
+
+        n = rows["slot_cq"].shape[0]
+        ap = self._adm_padded(adm, w)
+        # The world's structure and the policy config are device-resident
+        # by spec version, the admitted set by its own: a launch uploads
+        # its rows and nothing else.
+        dev, pj = self._device_world_args(w), pcfg["j"]
+        tensors = dict(
+            wcq_policy=pj["wcq_policy"],
+            reclaim_policy=pj["reclaim_policy"],
+            bwc_forbidden=pj["bwc_forbidden"],
+            bwc_threshold=pj["bwc_threshold"],
+            cq_has_parent=pj["cq_has_parent"],
+            root_of_cq=pj["root_of_cq"],
+            adm_cq=ap["adm_cq"], adm_pri=ap["adm_pri"],
+            adm_ts=ap["adm_ts"], adm_qrt=ap["adm_qrt"],
+            adm_uid=ap["adm_uid"], adm_ev=ap["adm_ev"],
+            adm_rank=ap["adm_rank"], adm_by_root=ap["adm_by_root"],
+            adm_usage=ap["adm_usage"], usage=usage,
+            **{k: dev[k] for k in (
+                "nominal", "lend_limit", "borrow_limit", "parent",
+                "ancestors", "height", "local_chain", "root_nodes")})
+        parts = []
+        for lo in range(0, n, block):
+            for key, fill in _SIM_ROW_FILLS.items():
+                tensors[key] = pad_axis0(rows[key][lo:lo + block], block,
+                                         fill)
+            got = self._exec_call(
+                "sim_targets", self.executor.sim_targets, tensors,
+                {"depth": w.depth, "v_cap": v_cap}, derived=derived)
+            parts.append([o[:n - lo] for o in got])
+        return [np.concatenate(col) for col in zip(*parts)]
+
+    def _sim_nomination(self, box, w, wls, usage, head_idx, sim_slots,
+                        adm, pcfg, v_cap=32):
         """Sim-augmented nomination for heads whose flavor choice depends
         on preemption simulations (multi-flavor groups on
         preemption-enabled CQs): run the pre-oracle flavor grid on
-        device, simulate each Preempt-gated (group, flavor, resource)
-        cell with the device classical preemptor
-        (preemption_oracle.go:41 SimulatePreemption), fold the
-        fungibility lattice host-side with the exact
-        scheduler/flavorassigner semantics, and return slot overrides
-        for the cycle kernel.
+        device (span ``flavor_grid``), turn each Preempt-gated (head,
+        group, flavor, resource) cell into a row (``sim_rows``),
+        simulate the rows with the device classical preemptor
+        (``sim_launch``; preemption_oracle.go:41 SimulatePreemption),
+        fold the fungibility lattice as array code with the
+        scheduler/flavorassigner semantics (``fungibility_fold``;
+        findFlavorForPodSets), and hand the cycle program its slot
+        overrides (``sim_targets``): a head whose chosen flavor's mode
+        is Preempt gets its victims from the cycle program's fused
+        preemptor, on that flavor. ``box`` is the open ``sim_nomination``
+        span, whose attrs carry the cycle's counts.
 
-        Returns (override, borrows_override, flavor_override, victims
-        (row, vals, ids) or None, targets_by_slot, demote_cq bool[C])."""
+        Returns (override, borrows_override, flavor_override,
+        demote_cq bool[C])."""
         import jax.numpy as jnp
 
         from kueue_tpu.ops import assign as aops
         from kueue_tpu.ops import commit as cops
         from kueue_tpu.ops import quota as qops
-        from kueue_tpu.scheduler.flavorassigner import (
-            BEST,
-            WORST,
-            GranularMode,
-            PMode,
-            is_preferred,
-            should_try_next_flavor,
-        )
+        from kueue_tpu.scheduler.flavorassigner import PMode
 
+        spans = self.engine.spans
         C, S = w.num_cqs, w.num_resources
-        G, F = w.group_flavors.shape[1], w.group_flavors.shape[2]
-        demote_cq = np.zeros(C, bool)
-        override = np.full(C, -1, np.int32)
-        borrows_override = np.full(C, -1, np.int32)
-        flavor_override = np.full((C, S), -1, np.int32)
-        targets_by_slot: dict[int, list] = {}
+        dev = self._device_world_args(w)
 
+        grid = spans.begin("flavor_grid")
         slots = np.nonzero(sim_slots)[0]
-        h = head_idx[slots]
-        h_cq = np.zeros(C, np.int32)
-        h_req = np.zeros((C, S), np.int64)
-        h_cq[slots] = slots  # head CQ == slot for valid heads
         # Sim heads are single-podset by construction (try_cycle demotes
         # multi-podset heads on sim-needing CQs): podset 0 carries the
-        # whole request.
-        h_req[slots] = wls.requests[h, 0]
-
+        # whole request. Head CQ == slot for valid heads.
+        h_cq = np.where(sim_slots, np.arange(C), 0).astype(np.int32)
+        h_req = np.zeros((C, S), np.int64)
+        h_req[slots] = wls.requests[head_idx[slots], 0]
         derived = qops.derive_world(
-            jnp.asarray(w.nominal), jnp.asarray(w.lend_limit),
-            jnp.asarray(w.borrow_limit), usage, jnp.asarray(w.parent),
-            depth=w.depth)
-        g_pmode, g_borrow, g_sim, _g_in = aops.flavor_grid(
+            dev["nominal"], dev["lend_limit"], dev["borrow_limit"], usage,
+            dev["parent"], depth=w.depth)
+        g_pmode, g_borrow, g_sim, in_group = aops.flavor_grid(
             jnp.asarray(h_cq), jnp.asarray(h_req), derived,
-            jnp.asarray(w.nominal), jnp.asarray(w.ancestors),
-            jnp.asarray(w.height), jnp.asarray(w.group_of_res),
-            jnp.asarray(w.group_flavors), jnp.asarray(w.no_preemption),
-            jnp.asarray(w.can_preempt_while_borrowing),
+            dev["nominal"], dev["ancestors"], dev["height"],
+            dev["group_of_res"], dev["group_flavors"],
+            dev["no_preemption"], dev["can_pwb"],
             depth=w.depth, num_resources=S)
-        g_pmode = np.asarray(g_pmode)
-        g_borrow = np.asarray(g_borrow)
-        g_sim = np.array(g_sim)  # writable copy
-        g_sim[~sim_slots] = False
+        pm = np.array(g_pmode)  # writable copies: the fold's lattice
+        br = np.array(g_borrow)
+        g_sim = np.asarray(g_sim) & sim_slots[:, None, None, None]
+        in_group = np.asarray(in_group) & sim_slots[:, None, None]
+        box.attrs["heads"] = grid.attrs["heads"] = int(slots.size)
 
-        # Batched per-cell sims: ALL (slot, group, flavor, resource)
-        # cells in ONE classical_targets launch — each cell is its own
-        # row with slot_cq pointing at the head's CQ (the per-cell
-        # launches dominated mixed-world cycle time). Cells whose slot
-        # provably has no candidates (_slot_maybe) skip the kernel and
-        # resolve to found=False, matching SimulatePreemption's no-
-        # candidates outcome.
-        from kueue_tpu.tensor.schema import pow2_bucket
-
+        # One row per cell to simulate. Cells whose slot provably has no
+        # candidates (_slot_maybe) skip the kernel and resolve to
+        # found=False, SimulatePreemption's no-candidates outcome.
+        spans.next("sim_rows")
         head_pri = self._head_pri(wls, head_idx)
-        head_ts = self._head_ts(wls, head_idx)
         maybe = self._slot_maybe(w, pcfg, adm, head_pri)
-        cells = list(zip(*np.nonzero(np.any(g_sim, axis=0))))
-        # cell -> (found bool[C], victims dict ci -> np.int idx array,
-        # borrow int32[C])
-        sim_out: dict[tuple, tuple] = {
-            cell: (np.zeros(C, bool), {}, np.zeros(C, np.int32))
-            for cell in cells}
-        row_ci: list[int] = []
-        row_cell: list[tuple] = []
-        for cell in cells:
-            g, f, s = cell
-            for ci in np.nonzero(g_sim[:, g, f, s] & maybe)[0]:
-                row_ci.append(int(ci))
-                row_cell.append(cell)
         adm_live = adm.live if adm.live is not None else adm.num_admitted
-        if row_ci and adm_live:
-            n_rows = len(row_ci)
-            Rw = pow2_bucket(n_rows, 8)
-            r_cq = np.zeros(Rw, np.int32)
-            r_need = np.zeros(Rw, bool)
-            r_pri = np.zeros(Rw, np.int64)
-            r_ts = np.zeros(Rw, np.float64)
-            r_fr = np.full((Rw, S), -1, np.int32)
-            r_req = np.zeros((Rw, S), np.int64)
-            for r, (ci, (g, f, s)) in enumerate(zip(row_ci, row_cell)):
-                r_cq[r] = ci
-                r_need[r] = True
-                r_pri[r] = head_pri[ci]
-                r_ts[r] = head_ts[ci]
-                r_fr[r, s] = w.group_flavors[ci, g, f] * S + s
-                r_req[r, s] = h_req[ci, s]
-            found_r, overflow_r, mask_r, _variant_r, borrow_r = \
-                self._classical_call(
-                    w, adm, pcfg, usage, r_need, r_pri, r_ts,
-                    r_fr, r_req, v_cap=v_cap, derived=derived,
-                    slot_cq=r_cq)
-            for r, (ci, cell) in enumerate(zip(row_ci, row_cell)):
-                f_arr, victims, b_arr = sim_out[cell]
-                if overflow_r[r]:
-                    demote_cq[ci] = True
-                if found_r[r]:
-                    f_arr[ci] = True
-                    victims[ci] = np.nonzero(mask_r[r])[0]
-                    b_arr[ci] = borrow_r[r]
+        ci, g, f, s_ = np.nonzero(
+            g_sim & maybe[:, None, None, None]) if adm_live else (
+            np.zeros(0, np.int64),) * 4
+        n_rows = int(ci.size)
+        r = np.arange(n_rows)
+        slot_fr = np.full((n_rows, S), -1, np.int32)
+        slot_fr[r, s_] = w.group_flavors[ci, g, f] * S + s_
+        slot_req = np.zeros((n_rows, S), np.int64)
+        slot_req[r, s_] = h_req[ci, s_]
+        rows = dict(slot_cq=ci.astype(np.int32),
+                    slot_need=np.ones(n_rows, bool),
+                    slot_pri=head_pri[ci],
+                    slot_ts=self._head_ts(wls, head_idx)[ci],
+                    slot_fr=slot_fr, slot_req=slot_req)
 
-        # Host-side fungibility fold (findFlavorForPodSets semantics)
-        # on the device-computed granular modes.
-        rep_of_slot = np.full(C, -1, np.int64)
-        for ci in slots:
-            if demote_cq[ci]:
-                continue
-            spec = self.engine.cache.cluster_queues[w.cq_names[ci]]
-            fung = spec.flavor_fungibility
-            req = h_req[ci]
-            choice = np.full(S, -1, np.int32)
-            rep_overall = int(PMode.FIT)
-            overall_borrow = 0
-            ok = True
-            for g in range(G):
-                res_ids = [s for s in range(S)
-                           if w.group_of_res[ci, s] == g and req[s] > 0]
-                if not res_ids:
-                    continue
-                best_mode = WORST
-                best_fl = -1
-                for f in range(F):
-                    fl = int(w.group_flavors[ci, g, f])
-                    if fl < 0:
-                        continue
-                    rep = BEST
-                    for s in res_ids:
-                        pm = int(g_pmode[ci, g, f, s])
-                        br = int(g_borrow[ci, g, f, s])
-                        if g_sim[ci, g, f, s]:
-                            found, victims, borrow_after = \
-                                sim_out[(g, f, s)]
-                            if found[ci]:
-                                vs = victims[ci]
-                                same = any(adm.cq[v] == ci for v in vs)
-                                pm = int(PMode.PREEMPT if same
-                                         else PMode.RECLAIM)
-                                br = int(borrow_after[ci])
-                            else:
-                                pm = int(PMode.NO_CANDIDATES)
-                        mode = GranularMode(PMode(pm), br)
-                        if is_preferred(rep, mode, fung):
-                            rep = mode
-                        if rep.pmode == PMode.NO_FIT:
-                            break
-                    if not should_try_next_flavor(rep, fung):
-                        best_mode, best_fl = rep, fl
-                        break
-                    if is_preferred(rep, best_mode, fung):
-                        best_mode, best_fl = rep, fl
-                if best_fl < 0 or best_mode.pmode == PMode.NO_FIT:
-                    ok = False
-                    break
-                for s in res_ids:
-                    choice[s] = best_fl
-                rep_overall = min(rep_overall, int(best_mode.pmode))
-                overall_borrow = max(overall_borrow, best_mode.borrow)
-            if not ok:
-                continue  # NO_FIT: the plain assign pass parks identically
-            flavor_override[ci] = choice
-            rep_of_slot[ci] = rep_overall
-            borrows_override[ci] = overall_borrow
-            if rep_overall == int(PMode.FIT):
-                override[ci] = cops.ENTRY_FIT
+        launch = spans.next("sim_launch")
+        demote_cq = np.zeros(C, bool)
+        block = self._sim_block(w)
+        launches = -(-n_rows // block)
+        if n_rows:
+            found, overflow, borrow_after, same = self._sim_launch(
+                w, adm, pcfg, usage, derived, rows, block, v_cap)
+            demote_cq[ci[overflow]] = True
+        box.attrs.update(rows=n_rows, launches=launches,
+                         overflow=int(np.count_nonzero(demote_cq)))
+        launch.attrs.update(rows=n_rows, rows_padded=launches * block,
+                            launches=launches)
 
-        # Final target selection for every preempt-mode representative
-        # (NO_CANDIDATES included — the scheduler runs GetTargets for any
-        # RepresentativeMode()==Preempt entry, preemption.go:129), with
-        # the chosen assignment's full flavor-resource set.
-        pre_slots = np.nonzero(
-            (rep_of_slot >= int(PMode.NO_CANDIDATES))
-            & (rep_of_slot < int(PMode.FIT)))[0]
-        victims = None
-        if pre_slots.size:
-            need = np.zeros(C, bool)
-            need[pre_slots] = True
-            # Precheck-masked slots resolve to the kernel's found=False
-            # outcome without running it; skip the launch entirely when
-            # no slot could have candidates.
-            kernel_need = need & maybe
-            if kernel_need.any():
-                slot_fr = np.where(
-                    flavor_override >= 0,
-                    flavor_override.astype(np.int64) * S
-                    + np.arange(S)[None, :], -1).astype(np.int32)
-                slot_fr[~kernel_need] = -1
-                slot_req = np.where(kernel_need[:, None], h_req, 0)
-                found, overflow, mask, variant, borrow_after = \
-                    self._classical_call(
-                        w, adm, pcfg, usage, kernel_need,
-                        np.where(sim_slots, head_pri, 0),
-                        np.where(sim_slots, head_ts, 0.0),
-                        slot_fr, slot_req, v_cap=v_cap, derived=derived)
-                demote_cq |= overflow & kernel_need
-            else:
-                found = np.zeros(C, bool)
-                mask = np.zeros((C, 0), bool)
-                variant = np.zeros((C, 0), np.int32)
-                borrow_after = np.zeros(C, np.int32)
-            V = v_cap
-            R = max(w.num_flavors, 1) * max(S, 1)
-            victim_row = np.full((C, V), -1, np.int32)
-            victim_vals = np.zeros((C, V, R), np.int64)
-            victim_ids = np.full((C, V), -1, np.int32)
-            variant_reason = self._variant_reason()
-            for ci in pre_slots:
-                if demote_cq[ci]:
-                    continue
-                if found[ci]:
-                    override[ci] = cops.ENTRY_PREEMPT
-                    borrows_override[ci] = borrow_after[ci]
-                    self._fill_victims(
-                        ci, np.nonzero(mask[ci])[0][:V], variant[ci],
-                        admitted, adm, w, victim_row, victim_vals,
-                        victim_ids, targets_by_slot, variant_reason)
-                else:
-                    override[ci] = (cops.ENTRY_SKIP
-                                    if w.can_always_reclaim[ci]
-                                    else cops.ENTRY_RESERVE)
-            victims = (victim_row, victim_vals, victim_ids)
-        return (override, borrows_override, flavor_override, victims,
-                targets_by_slot, demote_cq)
+        # The fungibility fold (findFlavorForPodSets) over every head at
+        # once. A simulated cell is Preempt or Reclaim with the borrow
+        # after its victims, else NoCandidates with the borrow it had.
+        spans.next("fungibility_fold")
+        pm[g_sim] = int(PMode.NO_CANDIDATES)
+        if n_rows:
+            hit = (ci[found], g[found], f[found], s_[found])
+            pm[hit] = np.where(same[found], int(PMode.PREEMPT),
+                               int(PMode.RECLAIM))
+            br[hit] = borrow_after[found]
+        choice, mode, borrow = _fold_fungibility(
+            pm, br, in_group, w.group_flavors, w.fung_borrow_try_next,
+            w.fung_preempt_try_next, w.fung_pref_preempt_first)
 
-    @staticmethod
-    def _fill_victims(ci, vs, variant_row, admitted, adm, w, victim_row,
-                      victim_vals, victim_ids, targets_by_slot,
-                      variant_reason):
-        """Pack one slot's chosen victims into the kernel's victim arrays
-        and the host-side target list (shared by the sim-nomination and
-        the flagged-slot preemption pass)."""
-        from kueue_tpu.scheduler.preemption import IN_CLUSTER_QUEUE
-
-        targets_by_slot[int(ci)] = [
-            (admitted[v],
-             variant_reason.get(int(variant_row[v]), IN_CLUSTER_QUEUE))
-            for v in vs]
-        for j, v in enumerate(vs):
-            victim_row[ci, j] = w.local_chain[adm.cq[v], 0]
-            victim_vals[ci, j] = adm.usage[v]
-            victim_ids[ci, j] = v
+        # What the cycle program is handed (NO_FIT heads get nothing:
+        # its own assign pass parks them identically). Fit commits on
+        # the chosen flavor; any Preempt-mode nomination — NoCandidates
+        # included, preemption.go:129 runs GetTargets for it — has its
+        # victims selected by the cycle program's fused preemptor.
+        spans.next("sim_targets")
+        # (A positive request no group covers is NoFit whatever the
+        # groups say, flavorassigner.go:939.)
+        uncovered = ((h_req > 0) & (w.group_of_res < 0)).any(axis=1)
+        decided = sim_slots & ~demote_cq & ~uncovered \
+            & (mode > int(PMode.NO_FIT))
+        override = np.where(
+            decided, np.where(mode == int(PMode.FIT), cops.ENTRY_FIT,
+                              cops.ENTRY_PREEMPT), -1).astype(np.int32)
+        borrows_override = np.where(decided, borrow, -1).astype(np.int32)
+        flavor_override = np.where(decided[:, None], choice,
+                                   -1).astype(np.int32)
+        spans.end()
+        return override, borrows_override, flavor_override, demote_cq
 
     @staticmethod
     def _head_pri(wls, head_idx):
@@ -1343,24 +1286,19 @@ class OracleBridge:
             else:
                 pcfg = self._cq_policy_cfg(w)
                 admitted, adm = self._encode_admitted(w)
-                (p_override, p_borrows, p_flavor, p_victims, p_targets,
-                 demote_cq) = self._sim_nomination(
-                    w, wl, jnp.asarray(w.usage), head_wid,
-                    sim_cq, adm, admitted, pcfg)
+                # A container beside host_encode, which runs on after
+                # it: its leaves are the nomination's own.
+                box = spans.next("sim_nomination")
+                pre = self._sim_nomination(
+                    box, w, wl, jnp.asarray(w.usage), head_wid, sim_cq,
+                    adm, pcfg)
+                spans.next("host_encode")
+                demote_cq = pre[3]
                 if demote_cq.any():
                     demote(demote_cq, "sim-overflow")
                     cq_on_device = ~host_root[root_of_cq]
-                off = ~cq_on_device
-                p_override[off] = -1
-                p_borrows[off] = -1
-                p_flavor[off] = -1
-                if p_victims is not None:
-                    p_victims[0][off] = -1
-                    p_victims[2][off] = -1
-                p_targets = {ci: t for ci, t in p_targets.items()
-                             if cq_on_device[ci]}
-                pre = (p_override, p_borrows, p_flavor, p_victims,
-                       p_targets)
+                    for arr in pre[:3]:
+                        arr[~cq_on_device] = -1
 
         device_w = active & wl.eligible & (wl.cq >= 0) \
             & cq_on_device[cq_safe_idx]
@@ -1413,22 +1351,11 @@ class OracleBridge:
                        fair_mode=eng.cycle.enable_fair_sharing,
                        num_flavors=max(w.num_flavors, 1))
         pre_kwargs = {}
-        preempt_targets: dict[int, list] = {}
         if pre is not None:
-            p_override, p_borrows, p_flavor, p_victims, p_targets = pre
-            preempt_targets.update(p_targets)
             pre_kwargs = dict(
-                slot_kind_override=jnp.asarray(p_override),
-                slot_borrows_override=jnp.asarray(p_borrows),
-                slot_flavor_override=jnp.asarray(p_flavor))
-            if p_victims is not None:
-                from kueue_tpu.tensor.schema import pow2_bucket
-                a_pad = pow2_bucket(adm.num_admitted, 8)
-                pre_kwargs.update(
-                    slot_victim_row=jnp.asarray(p_victims[0]),
-                    slot_victim_vals=jnp.asarray(p_victims[1]),
-                    slot_victim_ids=jnp.asarray(p_victims[2]),
-                    claimed0=jnp.zeros(a_pad, bool))
+                slot_kind_override=jnp.asarray(pre[0]),
+                slot_borrows_override=jnp.asarray(pre[1]),
+                slot_flavor_override=jnp.asarray(pre[2]))
 
         # Fused classical preemption: with any preemption-enabled CQ in
         # a classical world, ship the admitted tensors + policy config so
@@ -1482,7 +1409,7 @@ class OracleBridge:
             cq_on_device=cq_on_device, host_root=host_root,
             root_of_cq=root_of_cq, has_head=has_head,
             tas_plan=tas_plan, fused=fused, admitted=admitted,
-            preempt_targets=preempt_targets, lattice=lattice,
+            lattice=lattice,
             deferred=deferred, spec_span=None)
 
     def _commit_cycle(self, enc) -> Optional[CycleResult]:
@@ -1505,7 +1432,7 @@ class OracleBridge:
         has_head = enc.has_head
         tas_plan, fused = enc.tas_plan, enc.fused
         admitted = enc.admitted
-        preempt_targets = enc.preempt_targets
+        preempt_targets: dict[int, list] = {}
 
         def demote(cq_mask: np.ndarray, reason: str) -> None:
             roots = np.unique(root_of_cq[cq_mask])
@@ -1543,8 +1470,6 @@ class OracleBridge:
                 variant_reason = self._variant_reason()
                 from kueue_tpu.scheduler.preemption import IN_CLUSTER_QUEUE
                 for ci in np.nonzero((sp | found_any) & cq_on_device)[0]:
-                    if int(ci) in preempt_targets:
-                        continue  # sim-nomination slot (host-built)
                     preempt_targets[int(ci)] = [
                         (admitted[v],
                          variant_reason.get(int(vvar[ci, v]),

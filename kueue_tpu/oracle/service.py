@@ -7,8 +7,9 @@ exposed over an AdmissionCheck-style RPC API") as it actually ships:
   * OracleServer — a standalone process (``python -m
     kueue_tpu.oracle.service --port N``) hosting the two device programs
     the hybrid cycle needs: the batched cycle step
-    (oracle/batched.cycle_step) and the classical preemption targets
-    kernel (ops/preempt.classical_targets). It is stateless: every
+    (oracle/batched.cycle_step) and the sim program, the classical
+    preemptor over a block of simulation rows
+    (ops/preempt.sim_targets). It is stateless: every
     request carries the full dense snapshot (tensor/schema.py), every
     response the verdicts — the reference's "the API server is the
     durable store; the scheduler assumes and patches"
@@ -21,10 +22,10 @@ exposed over an AdmissionCheck-style RPC API") as it actually ships:
     which the bridge turns into a sequential-path fallback for the
     cycle (the BestEffortFIFO fallback contract).
 
-Scope: cycle_step and classical_targets cross the boundary (the hot
-decision programs); the sim-augmented nomination grid and TAS placement
-currently run in the engine process (they share the device through the
-same jit cache when local).
+Scope: cycle_step and sim_targets cross the boundary (the hot
+decision programs); the sim-augmented nomination's flavor grid and TAS
+placement currently run in the engine process (they share the device
+through the same jit cache when local).
 """
 
 from __future__ import annotations
@@ -82,20 +83,30 @@ def _run_cycle_step(tensors: dict, statics: dict, spans: SpanRecorder):
     return host
 
 
-def _run_classical_targets(tensors: dict, statics: dict, derived=None):
+def _run_sim_targets(tensors: dict, statics: dict, derived=None,
+                     spans: Optional[SpanRecorder] = None):
+    """One launch of the sim program (ops/preempt.sim_targets) over one
+    block of rows; the four per-row answers read back to the host.
+    ``spans`` (the engine's recorder, inside the bridge's ``sim_launch``
+    span) is told the bytes the call moved (host arrays up, answers
+    back) and the seconds it spent where it blocks: attrs bytes,
+    upload_s, device_wait_s, readback_s."""
     import jax
     import jax.numpy as jnp
 
     from kueue_tpu.ops import preempt as pops
     from kueue_tpu.ops import quota as qops
 
+    clock = spans.clock if spans is not None else (lambda: 0.0)
+    t0 = clock()
     t = {k: v if isinstance(v, jax.Array) else jnp.asarray(v)
          for k, v in tensors.items()}
     if derived is None:
         derived = qops.derive_world(
             t["nominal"], t["lend_limit"], t["borrow_limit"], t["usage"],
             t["parent"], depth=statics["depth"])
-    out = pops.classical_targets(
+    t1 = clock()
+    out = pops.sim_targets(
         t["slot_need"], t["slot_pri"], t["slot_ts"], t["slot_fr"],
         t["slot_req"], t["wcq_policy"], t["reclaim_policy"],
         t["bwc_forbidden"], t["bwc_threshold"], t["cq_has_parent"],
@@ -104,10 +115,19 @@ def _run_classical_targets(tensors: dict, statics: dict, derived=None):
         derived["subtree_quota"], t["lend_limit"], t["borrow_limit"],
         t["nominal"], t["ancestors"], t["height"], t["local_chain"],
         t["root_nodes"], t["root_of_cq"],
-        slot_cq=t.get("slot_cq"), adm_rank=t.get("adm_rank"),
-        adm_by_root=t.get("adm_by_root"),
+        slot_cq=t["slot_cq"], adm_rank=t["adm_rank"],
+        adm_by_root=t["adm_by_root"],
         depth=statics["depth"], v_cap=statics["v_cap"])
-    return [np.asarray(o) for o in out]
+    jax.block_until_ready(out)
+    t2 = clock()
+    host = [np.asarray(o) for o in out]
+    if spans is not None:
+        spans.add(
+            bytes=sum(t[k].nbytes for k, v in tensors.items()
+                      if t[k] is not v) + sum(o.nbytes for o in host),
+            upload_s=t1 - t0, device_wait_s=t2 - t1,
+            readback_s=clock() - t2)
+    return host
 
 
 class LocalExecutor:
@@ -122,9 +142,9 @@ class LocalExecutor:
     def cycle_step(self, tensors: dict, statics: dict):
         return _run_cycle_step(tensors, statics, self.spans)
 
-    def classical_targets(self, tensors: dict, statics: dict,
-                          derived=None):
-        return _run_classical_targets(tensors, statics, derived=derived)
+    def sim_targets(self, tensors: dict, statics: dict, derived=None):
+        return _run_sim_targets(tensors, statics, derived=derived,
+                                spans=self.spans)
 
 
 class RemoteExecutor:
@@ -185,11 +205,10 @@ class RemoteExecutor:
         with spans.span("readback", bytes=len(body)):
             return self._unpack(body)
 
-    def classical_targets(self, tensors: dict, statics: dict,
-                          derived=None):
+    def sim_targets(self, tensors: dict, statics: dict, derived=None):
         # The service re-derives quota state server-side.
         return self._unpack(self._roundtrip(
-            wire.pack("classical_targets", tensors, statics)))
+            wire.pack("sim_targets", tensors, statics)))
 
     def close(self) -> None:
         with self._lock:
@@ -256,8 +275,8 @@ class OracleServer:
                             "ok", {f"out{i}": o
                                    for i, o in enumerate(outs)},
                             {"n": len(outs)})
-                    elif op == "classical_targets":
-                        outs = _run_classical_targets(tensors, meta)
+                    elif op == "sim_targets":
+                        outs = _run_sim_targets(tensors, meta)
                         reply = wire.pack(
                             "ok", {f"out{i}": o
                                    for i, o in enumerate(outs)},
@@ -271,7 +290,7 @@ class OracleServer:
                     wire.send_msg(conn, reply)
                 except (ConnectionError, OSError):
                     return
-                if op in ("cycle_step", "classical_targets"):
+                if op in ("cycle_step", "sim_targets"):
                     self._count_and_maybe_crash()
 
 

@@ -7,7 +7,7 @@ decided, never WHAT it decides — both paths are proven
 byte-identical):
 
   * **retry with backoff + jitter** — a transport-level executor call
-    (cycle_step / classical_targets) that raises RemoteOracleError is
+    (cycle_step / sim_targets) that raises RemoteOracleError is
     retried up to ``max_attempts`` times, sleeping
     ``jitter · min(cap, base·2^attempt)`` between attempts. The jitter
     fraction is DETERMINISTIC (a CRC over the call site and attempt

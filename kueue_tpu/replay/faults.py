@@ -225,10 +225,9 @@ class _ExecutorFaultProxy:
         self._gate()
         return self.inner.cycle_step(tensors, statics)
 
-    def classical_targets(self, tensors, statics, derived=None):
+    def sim_targets(self, tensors, statics, derived=None):
         self._gate()
-        return self.inner.classical_targets(tensors, statics,
-                                            derived=derived)
+        return self.inner.sim_targets(tensors, statics, derived=derived)
 
     def close(self) -> None:
         if hasattr(self.inner, "close"):

@@ -8,6 +8,7 @@ import random
 import pytest
 
 jax = pytest.importorskip("jax")
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
@@ -33,6 +34,9 @@ from kueue_tpu.tensor.schema import (  # noqa: E402
     encode_admitted,
     encode_snapshot,
 )
+
+_classical_targets = jax.jit(pops.classical_targets_impl,
+                             static_argnames=("depth", "v_cap"))
 
 _POLICY_CODE = {
     PreemptionPolicy.NEVER: pops.POLICY_NEVER,
@@ -160,7 +164,7 @@ def device_targets(eng, wl_info, assignment, now, v_cap=16):
         jnp.asarray(world.borrow_limit), jnp.asarray(usage),
         jnp.asarray(world.parent), depth=world.depth)
 
-    found, overflow, mask, n, variant, _borrow = pops.classical_targets(
+    found, overflow, mask, _n, variant = _classical_targets(
         jnp.asarray(slot_need), jnp.asarray(slot_pri),
         jnp.asarray(slot_ts), jnp.asarray(slot_fr),
         jnp.asarray(slot_req), jnp.asarray(wcq_policy),
@@ -175,7 +179,7 @@ def device_targets(eng, wl_info, assignment, now, v_cap=16):
         jnp.asarray(world.ancestors), jnp.asarray(world.height),
         jnp.asarray(world.local_chain),
         jnp.asarray(world.root_nodes), jnp.asarray(world.root_of_cq),
-        depth=world.depth, v_cap=v_cap)
+        depth=world.depth, v_cap=v_cap)[:5]
     found = bool(np.asarray(found)[ci])
     mask = np.asarray(mask)[ci]
     variant = np.asarray(variant)[ci]
