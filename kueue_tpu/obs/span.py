@@ -34,8 +34,8 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     ├─ speculate                  next cycle's encode + launch; same
     │                             children as ``cycle`` up to readback;
     │                             attrs lattice (whether its launch took
-    │                             the preemptor's branch; None where the
-    │                             verdicts cannot tell), and outcome
+    │                             the preemptor's branch: the cycle
+    │                             program's own output), and outcome
     │                             once the next cycle learns it; or
     │                             gate = closed and no children, where
     │                             the last gap was mutated and nothing
@@ -152,8 +152,7 @@ AGGREGATE_KEYS = frozenset({"tas_place", "speculate", "schedule_once",
 # has the window's own counts:
 #   n_launches, n_lattice_launches  containers (cycle, speculate) that
 #       launched the cycle program (attr ``lattice``), and those whose
-#       launch took the fused preemptor's branch; the second is left
-#       out where a launch could not tell (``lattice`` None)
+#       launch took the fused preemptor's branch
 #   n_spec_used, n_spec_discarded   take_speculation's ``outcome``
 #   n_spec_skipped                  ``speculate`` spans the gate closed
 #       (attr ``gate``): nothing encoded, nothing launched
@@ -300,9 +299,7 @@ def phase_seconds(root: Span) -> dict:
         box = boxes.pop()
         if "lattice" in box.attrs:  # this container launched
             launches += 1
-            if lattice is not None:
-                ran = box.attrs["lattice"]
-                lattice = None if ran is None else lattice + ran
+            lattice += box.attrs["lattice"]
         for c in box.children:
             if c.name in CONTAINERS:
                 boxes.append(c)
@@ -323,8 +320,7 @@ def phase_seconds(root: Span) -> dict:
                     _add(out, s.name, s.dur * 1e-6)
     if launches:
         out["n_launches"] = launches
-        if lattice is not None:
-            out["n_lattice_launches"] = lattice
+        out["n_lattice_launches"] = lattice
     return out
 
 

@@ -271,14 +271,18 @@ def classical_targets_impl(
     fail to fit with more candidates available report overflow=True and
     must fall back to the host preemptor.
 
-    Returns per slot:
-      found bool[C], overflow bool[C],
-      target_mask bool[C, A], n_targets int32[C],
-      variant int32[C, A] (candidate variants, for preemption reasons),
+    Returns per slot, the victims packed to V = min(v_cap, A_l) columns
+    (the scanned candidates, in candidate order):
+      found bool[C], overflow bool[C], n_targets int32[C],
       borrow_after int32[C] — the assignment borrow level with the
         victims removed (preemption_oracle.go:41 SimulatePreemption →
         FindHeightOfLowestSubtreeThatFits), which is what the commit
-        iterator orders preempting entries by (scheduler.go:971).
+        iterator orders preempting entries by (scheduler.go:971),
+      v_ids int32[C, V] — GLOBAL admitted ids of the scanned candidates
+        (-1 on an ``adm_by_root`` pad row),
+      taken bool[C, V] — which of them are the targets,
+      v_variant int32[C, V] — each one's candidate variant (V_*, for
+        the preemption reason; 0 on a pad row).
     """
     C, S = slot_req.shape
     A = adm_cq.shape[0]
@@ -427,10 +431,11 @@ def classical_targets_impl(
         rwob = (bwc_forbidden[c] | (l_pri >= p_pri)
                 | (l_pri > bwc_threshold[c]))
         variant = jnp.where(
-            same_cq, V_WITHIN_CQ,
-            jnp.where(adv_at_lca, V_HIERARCHICAL_RECLAIM,
-                      jnp.where(rwob, V_RECLAIM_WITHOUT_BORROWING,
-                                V_RECLAIM_WHILE_BORROWING)))
+            same_cq, jnp.int32(V_WITHIN_CQ),
+            jnp.where(adv_at_lca, jnp.int32(V_HIERARCHICAL_RECLAIM),
+                      jnp.where(rwob,
+                                jnp.int32(V_RECLAIM_WITHOUT_BORROWING),
+                                jnp.int32(V_RECLAIM_WHILE_BORROWING))))
 
         # Static within-nominal pruning (collectCandidatesInSubtree +
         # candidateIsValid at cycle start): every node on the candidate's
@@ -599,20 +604,13 @@ def classical_targets_impl(
             jnp.where(use2, borrow_after_height(u2), 0)).astype(jnp.int32)
 
         if g_rows is None:
-            g_v_ids = v_ids
-            variant_g = variant
+            g_v_ids, g_variant = v_ids, v_variant
         else:
-            # Map local victim positions / variants back to GLOBAL ids.
+            # Map local victim positions back to GLOBAL ids.
             g_v_ids = jnp.where(l_ok[v_ids], g_rows[v_ids], -1)
-            variant_g = jnp.zeros((A,), variant.dtype).at[
-                jnp.where(l_ok, jnp.maximum(g_rows, 0), A)].set(
-                jnp.where(l_ok, variant, 0), mode="drop")
-        target_mask = jnp.zeros((A,), bool).at[
-            jnp.where(taken & (g_v_ids >= 0), g_v_ids, A)].set(
-            True, mode="drop")
-        return (found, overflow, target_mask,
-                jnp.sum(taken.astype(jnp.int32)), variant_g, borrow_after,
-                g_v_ids, taken)
+            g_variant = jnp.where(l_ok[v_ids], v_variant, 0)
+        return (found, overflow, jnp.sum(taken.astype(jnp.int32)),
+                borrow_after, g_v_ids, taken, g_variant)
 
     if slot_cq is None:
         slot_cq = jnp.arange(C, dtype=jnp.int32)
@@ -635,8 +633,7 @@ def sim_targets(*args, slot_cq, adm_rank, adm_by_root, depth: int,
                                  adm_rank=adm_rank,
                                  adm_by_root=adm_by_root, depth=depth,
                                  v_cap=v_cap)
-    found, overflow, borrow_after, v_ids, taken = (
-        out[0], out[1], out[5], out[6], out[7])
+    found, overflow, _n, borrow_after, v_ids, taken, _variant = out
     same = jnp.any(taken & (v_ids >= 0)
                    & (adm_cq[jnp.maximum(v_ids, 0)] == slot_cq[:, None]),
                    axis=1)

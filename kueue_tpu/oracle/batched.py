@@ -242,9 +242,13 @@ def _cycle_core(
     full_usage = derived["usage"]
 
     # --- fused classical preemption target selection ---
+    # The victims stay packed, [C, v_cap], from the selection to the
+    # host: ids (-1 where a column holds no target) and each one's
+    # candidate variant. [C, 0] where this program has no preemptor.
     slot_overflow = jnp.zeros((C,), bool)
-    victim_mask = jnp.zeros((C, 0), bool)
+    victim_ids = jnp.zeros((C, 0), jnp.int32)
     victim_variant = jnp.zeros((C, 0), jnp.int32)
+    lattice_ran = jnp.asarray(False)
     fused_preempt = jnp.zeros((C,), bool)
     slot_victim_row = slot_victim_vals = slot_victim_ids = claimed0 = None
     if adm_cq is not None and not fair_mode:
@@ -254,13 +258,14 @@ def _cycle_core(
         h_ts = jnp.where(slot_valid, wl_ts[h_safe], 0.0)
         oracle_eff = (slot_oracle if slot_maybe is None
                       else slot_oracle & slot_maybe)
-        A_ = adm_cq.shape[0]
-        A_l_ = adm_by_root.shape[1] if adm_by_root is not None else A_
-        V_ = min(v_cap, A_l_)  # must match the kernel's victim width
+        A_l_ = (adm_by_root.shape[1] if adm_by_root is not None
+                else adm_cq.shape[0])
+        V = min(v_cap, A_l_)  # must match the kernel's victim width
 
         def _run_targets(_):
             with jax.named_scope("kueue.preempt"):
-                out = pops.classical_targets_impl(
+                (found, overflow, _n, borrow, v_ids, taken,
+                 v_variant) = pops.classical_targets_impl(
                     oracle_eff, h_pri, h_ts, entry_fr_d, req_fr,
                     pc_wcq_policy, pc_reclaim_policy, pc_bwc_forbidden,
                     pc_bwc_threshold, pc_cq_has_parent,
@@ -272,21 +277,21 @@ def _cycle_core(
                     depth=depth, v_cap=v_cap)
                 # Canonical dtypes: both cond branches must match
                 # exactly.
-                return (out[0], out[1], out[2], out[3].astype(jnp.int32),
-                        out[4].astype(jnp.int32), out[5].astype(jnp.int32),
-                        out[6].astype(jnp.int32), out[7])
+                return (found, overflow, borrow.astype(jnp.int32),
+                        v_ids.astype(jnp.int32), taken,
+                        v_variant.astype(jnp.int32))
 
         def _skip_targets(_):
             return (jnp.zeros((C,), bool), jnp.zeros((C,), bool),
-                    jnp.zeros((C, A_), bool), jnp.zeros((C,), jnp.int32),
-                    jnp.zeros((C, A_), jnp.int32),
                     jnp.zeros((C,), jnp.int32),
-                    jnp.zeros((C, V_), jnp.int32),
-                    jnp.zeros((C, V_), bool))
+                    jnp.zeros((C, V), jnp.int32),
+                    jnp.zeros((C, V), bool),
+                    jnp.zeros((C, V), jnp.int32))
 
-        (pfound, poverflow, victim_mask, _pn, victim_variant, pborrow,
-         pv_ids, ptaken) = jax.lax.cond(
-            jnp.any(oracle_eff), _run_targets, _skip_targets, None)
+        lattice_ran = jnp.any(oracle_eff)
+        (pfound, poverflow, pborrow, pv_ids, ptaken,
+         pvariant) = jax.lax.cond(
+            lattice_ran, _run_targets, _skip_targets, None)
         pfound = pfound & oracle_eff
         fused_preempt = pfound
         slot_overflow = poverflow & oracle_eff
@@ -302,26 +307,27 @@ def _cycle_core(
                                           cops.ENTRY_RESERVE),
                                 kind)))
         borrows = jnp.where(pfound, pborrow, borrows)
-        # Pack per-slot victims to v_cap columns for the commit kernel.
-        V = pv_ids.shape[1]
+        # The commit kernel and the host read the same packed columns.
         R = adm_usage.shape[1]
+        is_target = ptaken & pfound[:, None]
         pv_safe = jnp.maximum(pv_ids, 0)
         f_row = jnp.where(
-            ptaken & pfound[:, None],
-            local_chain[jnp.maximum(adm_cq[pv_safe], 0), 0], -1)
-        f_vals = jnp.where((ptaken & pfound[:, None])[:, :, None],
-                           adm_usage[pv_safe], 0)
-        f_ids = jnp.where(ptaken & pfound[:, None], pv_safe, -1)
+            is_target, local_chain[jnp.maximum(adm_cq[pv_safe], 0), 0], -1)
+        f_vals = jnp.where(is_target[:, :, None], adm_usage[pv_safe], 0)
+        victim_ids = jnp.where(is_target, pv_safe, -1)
+        victim_variant = jnp.where(is_target, pvariant, 0)
         if V < v_cap:
             pad = v_cap - V
             f_row = jnp.concatenate(
                 [f_row, jnp.full((C, pad), -1, f_row.dtype)], axis=1)
             f_vals = jnp.concatenate(
                 [f_vals, jnp.zeros((C, pad, R), f_vals.dtype)], axis=1)
-            f_ids = jnp.concatenate(
-                [f_ids, jnp.full((C, pad), -1, f_ids.dtype)], axis=1)
+            victim_ids = jnp.concatenate(
+                [victim_ids, jnp.full((C, pad), -1, jnp.int32)], axis=1)
+            victim_variant = jnp.concatenate(
+                [victim_variant, jnp.zeros((C, pad), jnp.int32)], axis=1)
         slot_victim_row, slot_victim_vals, slot_victim_ids = \
-            f_row, f_vals, f_ids
+            f_row, f_vals, victim_ids
         claimed0 = jnp.zeros((adm_cq.shape[0],), bool)
         # Every flagged slot is decided in-program; overflow slots are
         # reported separately for host-root demotion.
@@ -413,7 +419,7 @@ def _cycle_core(
     return (new_pending, new_inadmissible, usage_clean, wl_admitted,
             slot_admitted, slot_position, flavor_of_res, any_needs_oracle,
             slot_oracle, slot_preempting, head_idx, slot_overflow,
-            victim_mask, victim_variant)
+            victim_ids, victim_variant, lattice_ran)
 
 
 cycle_step = partial(jax.jit,
@@ -476,8 +482,8 @@ def drain_loop(
          wl_flavor, oracle_flag) = state
         (pending, inadmissible, usage, wl_admitted, _slot_admitted,
          slot_position, flavor_of_res, any_oracle, _slot_oracle,
-         _slot_preempting, _head_idx, _slot_overflow, _vmask,
-         _vvariant) = step(pending, inadmissible, usage)
+         _slot_preempting, _head_idx, _slot_overflow, _victim_ids,
+         _victim_variant, _lattice_ran) = step(pending, inadmissible, usage)
         admit_cycle = jnp.where(wl_admitted, cycle, admit_cycle)
         admit_pos = jnp.where(wl_admitted, slot_position[wl_cq], admit_pos)
         wl_flavor = jnp.where(wl_admitted[:, None, None],

@@ -174,46 +174,6 @@ def _fold_fungibility(pm, br, in_group, group_flavors, borrow_try_next,
     return choice.astype(np.int32), mode, borrow
 
 
-def _lattice_ran(out, w, slot_maybe) -> Optional[bool]:
-    """Whether this launch took the fused preemptor's branch (the
-    ``lax.cond`` in batched._cycle_core), read off the verdicts it
-    returned — the cycle program has no output for its predicate, and
-    zeroes ``slot_oracle`` once the preemptor has decided every flagged
-    slot. A head drives the branch when its ClusterQueue can preempt,
-    the host's precheck (``slot_maybe``) let it through, and the
-    flavor assigner turned it over to the preemptor; that last leaves
-    one of four marks: the slot preempts, overflows ``v_cap``, parks
-    with a flavor assigned (a head that merely did not fit parks with
-    none, one that fit never parks), or has victims in the mask though
-    it was beaten to their room at commit.
-
-    The marks tell only where every ClusterQueue that could drive the
-    branch is BestEffortFIFO (a StrictFIFO head the preemptor turns
-    down does not park) and the world has one resource group (with two,
-    a head can park with a flavor assigned in the other). Elsewhere the
-    answer is None, not a guess: the ``lattice`` attr says so and the
-    phase dict leaves ``n_lattice_launches`` out (ROADMAP A2: one more
-    output of the cycle program, ``any(oracle_eff)``, retires this
-    function)."""
-    if slot_maybe is None:
-        return False  # no fused preemptor in this program
-    head = np.asarray(out[10])
-    could = (head >= 0) & slot_maybe & ~w.no_preemption
-    if not could.any():
-        return False
-    if w.group_flavors.shape[1] != 1 or not w.best_effort[could].all():
-        return None
-    flavor = np.asarray(out[6])
-    nominated = (flavor >= 0).any(axis=tuple(range(1, flavor.ndim)))
-    parked = np.asarray(out[1])[np.maximum(head, 0)]
-    marks = np.asarray(out[9]) | np.asarray(out[11]) | (parked & nominated)
-    if (could & marks).any():
-        return True
-    # Every victim set beaten to its room leaves no mark on a slot; the
-    # victim mask (zeros where the branch was skipped) still holds them.
-    return bool(np.asarray(out[12]).any())
-
-
 class _CycleExit:
     """An early-exit verdict from :meth:`OracleBridge._encode_cycle`:
     either a named fallback (``fallback_reason``) or a literal return
@@ -1400,7 +1360,9 @@ class OracleBridge:
         # the schedule_once() that launched it.
         out = self._exec_call("cycle_step", self.executor.cycle_step,
                               _inputs, statics)
-        lattice = _lattice_ran(out, w, slot_maybe)
+        # Whether this launch took the fused preemptor's branch: the
+        # program's own predicate (batched._cycle_core's lax.cond).
+        lattice = bool(out[14])
 
         from types import SimpleNamespace
         return SimpleNamespace(
@@ -1445,8 +1407,16 @@ class OracleBridge:
             _obs_perf.device_result("cycle_step", out)
         (new_pending, new_inadmissible, usage2, wl_admitted, slot_admitted,
          slot_position, flavor_of_res, any_oracle, slot_oracle,
-         slot_preempting, head_idx, slot_overflow, victim_mask,
-         victim_variant) = out
+         slot_preempting, head_idx, slot_overflow, victim_ids,
+         victim_variant, _lattice) = out
+        # The victims come packed, [C, v_cap] admitted ids (-1 where a
+        # column holds no target; [C, 0] with no fused preemptor), each
+        # with its variant. A slot's targets are listed by ascending
+        # admitted index: events, journal lines and the eviction order
+        # follow that list.
+        vids = np.asarray(victim_ids)
+        by_id = np.argsort(vids, axis=1)
+        vids = np.take_along_axis(vids, by_id, axis=1)
 
         if fused:
             overflow = np.asarray(slot_overflow) & cq_on_device
@@ -1458,23 +1428,21 @@ class OracleBridge:
             # Host-side Target lists for the preempting slots, from the
             # in-program victim selection.
             sp = np.asarray(slot_preempting)
-            vmask = np.asarray(victim_mask)
             # Slots with a selected victim set: committed ones become
             # PREEMPTING entries; uncommitted ones (capacity claimed by
             # an earlier entry) are the reference's skipped preemptions
             # and are counted by _apply.
-            found_any = (vmask.any(axis=1) if vmask.size
-                         else np.zeros(C, bool))
+            found_any = (vids >= 0).any(axis=1)
             if (sp | found_any).any():
-                vvar = np.asarray(victim_variant)
+                vvar = np.take_along_axis(np.asarray(victim_variant),
+                                          by_id, axis=1)
                 variant_reason = self._variant_reason()
                 from kueue_tpu.scheduler.preemption import IN_CLUSTER_QUEUE
                 for ci in np.nonzero((sp | found_any) & cq_on_device)[0]:
                     preempt_targets[int(ci)] = [
                         (admitted[v],
-                         variant_reason.get(int(vvar[ci, v]),
-                                            IN_CLUSTER_QUEUE))
-                        for v in np.nonzero(vmask[ci])[0]]
+                         variant_reason.get(int(var), IN_CLUSTER_QUEUE))
+                        for v, var in zip(vids[ci], vvar[ci]) if v >= 0]
         if bool(any_oracle):
             flagged = np.asarray(slot_oracle)
             if eng.cycle.enable_fair_sharing:
@@ -1498,7 +1466,7 @@ class OracleBridge:
         import zlib as _zlib
         _vd = 0
         for _arr in (wl_admitted, slot_admitted, slot_position,
-                     slot_preempting, victim_mask):
+                     slot_preempting, vids):
             _vd = _zlib.crc32(np.ascontiguousarray(_arr).tobytes(), _vd)
         self.last_verdict_digest = _vd
         spans.next("apply")

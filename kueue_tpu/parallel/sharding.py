@@ -71,13 +71,14 @@ def sharded_cycle_step(mesh: Mesh, depth: int, num_resources: int,
     local_depth, root_parent_local."""
     sh = _shardings(mesh)
     in_shardings = tuple(sh[n] for n in list(_PREFIX) + list(_TAIL))
-    # 14 outputs (batched._cycle_core): ... plus slot_overflow [C],
-    # victim_mask [C, 0], victim_variant [C, 0] (empty when the fused
-    # preemption tensors are not provided, as here).
+    # 15 outputs (batched._cycle_core): ... plus slot_overflow [C],
+    # victim_ids [C, 0], victim_variant [C, 0] (the packed victims:
+    # empty when the fused preemption tensors are not provided, as
+    # here) and the scalar lattice_ran.
     out_shardings = (
         sh["wl"], sh["wl"], sh["r2"], sh["wl"], sh["r"], sh["r"],
         sh["r3"], sh["r"], sh["r"], sh["r"], sh["r"], sh["r"],
-        sh["r2"], sh["r2"])
+        sh["r2"], sh["r2"], sh["r"])
 
     def fn(pending, inadmissible, usage, rank, commit_rank, wl_cq,
            wl_req, wl_priority, wl_has_qr, wl_hash, nominal,

@@ -1,7 +1,10 @@
-"""Device classical preemptor (ops/preempt.classical_targets) vs the host
-Preemptor: target sets must match exactly on randomized hierarchical
+"""Device classical preemptor (ops/preempt.classical_targets_impl) vs the
+host Preemptor: target sets must match exactly on randomized hierarchical
 worlds — cross-CQ reclaim, borrowWithinCohort, nested cohorts, priority
-thresholds (VERDICT round-1 item #3)."""
+thresholds (VERDICT round-1 item #3). The device's answer is packed: the
+scanned candidates' ids, which of them are taken, and each one's variant
+(ISSUE 31); and the cycle program hands the same columns on, padded to
+v_cap."""
 
 import random
 
@@ -115,7 +118,24 @@ def host_targets(eng, wl_info, now):
     return assignment, sorted((t.workload.key, t.reason) for t in targets)
 
 
-def device_targets(eng, wl_info, assignment, now, v_cap=16):
+def by_root_layout(world, adm):
+    """The bridge's form (engine_bridge._adm_padded): admitted ids
+    grouped by cohort root, -1 pad, and the precomputed ordering rank."""
+    A, Rn = adm.num_admitted, world.root_members.shape[0]
+    root_of = world.root_of_cq[adm.cq]
+    A_l = max(8, int(np.bincount(root_of, minlength=Rn).max()))
+    adm_by_root = np.full((Rn, A_l), -1, np.int32)
+    for r in range(Rn):
+        ids = np.nonzero(root_of == r)[0]
+        adm_by_root[r, :ids.size] = ids
+    rank = np.empty(A, np.int64)
+    rank[np.lexsort((adm.uid_rank, -adm.qr_time, adm.priority))] = \
+        np.arange(A)
+    return dict(adm_by_root=jnp.asarray(adm_by_root),
+                adm_rank=jnp.asarray(rank))
+
+
+def device_targets(eng, wl_info, assignment, now, v_cap=16, layout="flat"):
     snapshot = eng.cache.snapshot()
     world = encode_snapshot(snapshot, max_depth=4)
     admitted = [info for cqs in snapshot.cluster_queues.values()
@@ -164,7 +184,8 @@ def device_targets(eng, wl_info, assignment, now, v_cap=16):
         jnp.asarray(world.borrow_limit), jnp.asarray(usage),
         jnp.asarray(world.parent), depth=world.depth)
 
-    found, overflow, mask, _n, variant = _classical_targets(
+    grouped = by_root_layout(world, adm) if layout == "by_root" else {}
+    found, overflow, n, _borrow, v_ids, taken, variant = _classical_targets(
         jnp.asarray(slot_need), jnp.asarray(slot_pri),
         jnp.asarray(slot_ts), jnp.asarray(slot_fr),
         jnp.asarray(slot_req), jnp.asarray(wcq_policy),
@@ -179,17 +200,29 @@ def device_targets(eng, wl_info, assignment, now, v_cap=16):
         jnp.asarray(world.ancestors), jnp.asarray(world.height),
         jnp.asarray(world.local_chain),
         jnp.asarray(world.root_nodes), jnp.asarray(world.root_of_cq),
-        depth=world.depth, v_cap=v_cap)[:5]
+        depth=world.depth, v_cap=v_cap, **grouped)
+    # Packed: V = min(v_cap, the candidate axis) columns a slot.
+    A_l = (grouped["adm_by_root"].shape[1] if grouped
+           else adm.num_admitted)
+    assert v_ids.shape == taken.shape == variant.shape == (
+        C, min(v_cap, A_l))
+    assert variant.dtype == jnp.int32
+    # Slots that did not ask take nobody.
+    assert not np.asarray(taken)[np.arange(C) != ci].any()
     found = bool(np.asarray(found)[ci])
-    mask = np.asarray(mask)[ci]
+    v_ids, taken = np.asarray(v_ids)[ci], np.asarray(taken)[ci]
     variant = np.asarray(variant)[ci]
-    targets = sorted((adm.keys[i], _VARIANT_REASON[int(variant[i])])
-                     for i in np.nonzero(mask)[0])
+    assert int(np.asarray(n)[ci]) == int(taken.sum())
+    assert (v_ids[taken] >= 0).all()
+    assert len(set(v_ids[taken])) == int(taken.sum())
+    targets = sorted((adm.keys[i], _VARIANT_REASON[int(var)])
+                     for i, var in zip(v_ids[taken], variant[taken]))
     return found, targets, bool(np.asarray(overflow)[ci])
 
 
+@pytest.mark.parametrize("layout", ["flat", "by_root"])
 @pytest.mark.parametrize("seed", range(12))
-def test_classical_targets_match_host(seed):
+def test_classical_targets_match_host(seed, layout):
     rng = random.Random(31 * seed + 5)
     eng, n_cqs = build_engine(rng)
     now = eng.clock + 1.0
@@ -208,8 +241,88 @@ def test_classical_targets_match_host(seed):
     from kueue_tpu.scheduler.flavorassigner import Mode
     if assignment.representative_mode() != Mode.PREEMPT:
         pytest.skip("scenario did not require preemption")
-    d_found, d_targets, d_overflow = device_targets(eng, info, assignment,
-                                                    now)
+    d_found, d_targets, d_overflow = device_targets(
+        eng, info, assignment, now, layout=layout)
     assert not d_overflow
     assert d_found == bool(h_targets), (h_targets, d_targets)
     assert d_targets == h_targets
+
+
+# -- the same columns, as the cycle program hands them on ---------------
+
+
+def tapped_cycle(eng):
+    """One schedule_once() on the device path; what the executor was
+    handed and what it returned."""
+    seen = {}
+    inner = eng.oracle.executor.cycle_step
+
+    def tap(tensors, statics):
+        out = inner(tensors, statics)
+        seen.setdefault("call", (dict(tensors), dict(statics), out))
+        return out
+
+    eng.oracle.executor.cycle_step = tap
+    result = eng.schedule_once()
+    return result, seen["call"]
+
+
+def small_engine(fair=False):
+    from kueue_tpu.scheduler.cycle import SchedulerCycle
+
+    eng = Engine(cycle=SchedulerCycle(enable_fair_sharing=True)) \
+        if fair else Engine()
+    eng.create_resource_flavor(ResourceFlavor("default"))
+    for i in range(3):
+        eng.create_cluster_queue(ClusterQueue(
+            name=f"cq{i}", cohort=f"co{i}",
+            preemption=ClusterQueuePreemption(
+                within_cluster_queue=PreemptionPolicy.LOWER_PRIORITY),
+            resource_groups=(ResourceGroup(
+                ("cpu",), (FlavorQuotas(
+                    "default", {"cpu": ResourceQuota(1000)}),)),)))
+        eng.create_local_queue(LocalQueue(f"lq{i}", "default", f"cq{i}"))
+    eng.attach_oracle()
+    return eng
+
+
+def test_cycle_program_pads_the_packed_victims_to_v_cap():
+    """Fewer admitted than v_cap (V = 8 of 32 here): the cycle program's
+    outputs 12 and 13 are [C, v_cap] all the same — ids -1 and variant 0
+    in the pad columns and wherever a column holds no target."""
+    eng = small_engine()
+    for i in range(3):
+        eng.clock += 0.5
+        eng.submit(Workload(name=f"low{i}", queue_name=f"lq{i}", priority=0,
+                            pod_sets=(PodSet("main", 1, {"cpu": 700}),)))
+    eng.schedule_once()
+    for i in (0, 2):
+        eng.clock += 0.5
+        eng.submit(Workload(name=f"high{i}", queue_name=f"lq{i}",
+                            priority=5,
+                            pod_sets=(PodSet("main", 1, {"cpu": 800}),)))
+    result, (tensors, _statics, out) = tapped_cycle(eng)
+    assert result.stats.preempting == 2
+    C, V = 3, tensors["adm_by_root"].shape[1]
+    assert V == 8  # the bridge's smallest bucket, under v_cap = 32
+    ids, variant = np.asarray(out[12]), np.asarray(out[13])
+    assert ids.shape == variant.shape == (C, 32)
+    assert ids.dtype == variant.dtype == np.int32
+    assert (ids[:, V:] == -1).all() and (variant[:, V:] == 0).all()
+    assert ((ids >= 0).sum(axis=1) == [1, 0, 1]).all()
+    assert (variant[ids >= 0] == pops.V_WITHIN_CQ).all()
+    assert (variant[ids < 0] == 0).all()
+    assert bool(out[14])  # the preemptor's branch ran
+
+
+def test_fair_mode_has_no_fused_preemptor_and_returns_empty_columns():
+    eng = small_engine(fair=True)
+    eng.clock += 0.5
+    eng.submit(Workload(name="w", queue_name="lq0",
+                        pod_sets=(PodSet("main", 1, {"cpu": 700}),)))
+    result, (tensors, statics, out) = tapped_cycle(eng)
+    assert result.stats.admitted == 1
+    assert statics["fair_mode"] and "adm_cq" not in tensors
+    assert np.asarray(out[12]).shape == np.asarray(out[13]).shape == (3, 0)
+    assert np.asarray(out[12]).dtype == np.int32
+    assert not bool(out[14])

@@ -43,9 +43,14 @@ COMMIT = ["verdict_decode", "apply", "finalize"]
 
 
 def make_engine(oracle=True, cohorts=1, nominal=1000, preemption=True,
-                strategy=QueueingStrategy.BEST_EFFORT_FIFO):
+                strategy=QueueingStrategy.BEST_EFFORT_FIFO, groups=1):
     eng = Engine()
     eng.create_resource_flavor(ResourceFlavor("default"))
+    second = ()
+    if groups == 2:  # memory, in a resource group and flavor of its own
+        eng.create_resource_flavor(ResourceFlavor("dimm"))
+        second = (ResourceGroup(("memory",), (FlavorQuotas(
+            "dimm", {"memory": ResourceQuota(nominal)}),)),)
     for i in range(cohorts):
         eng.create_cluster_queue(ClusterQueue(
             name=f"cq{i}", cohort=f"co{i}", queueing_strategy=strategy,
@@ -54,7 +59,8 @@ def make_engine(oracle=True, cohorts=1, nominal=1000, preemption=True,
                 if preemption else ClusterQueuePreemption()),
             resource_groups=(ResourceGroup(
                 ("cpu",),
-                (FlavorQuotas("default", {"cpu": ResourceQuota(nominal)}),)),),
+                (FlavorQuotas("default", {"cpu": ResourceQuota(nominal)}),)),
+            ) + second,
         ))
         eng.create_local_queue(LocalQueue(f"lq{i}", "default", f"cq{i}"))
     if oracle:
@@ -62,10 +68,12 @@ def make_engine(oracle=True, cohorts=1, nominal=1000, preemption=True,
     return eng
 
 
-def submit(eng, name, cpu, priority=0, lq="lq0", **podset):
+def submit(eng, name, cpu, priority=0, lq="lq0", memory=None, **podset):
     eng.clock += 0.5
+    requests = {"cpu": cpu} if memory is None else {
+        "cpu": cpu, "memory": memory}
     wl = Workload(name=name, queue_name=lq, priority=priority,
-                  pod_sets=(PodSet("main", 1, {"cpu": cpu}, **podset),))
+                  pod_sets=(PodSet("main", 1, requests, **podset),))
     eng.submit(wl)
     return wl
 
@@ -442,20 +450,30 @@ def _preemptor_predicate(tensors, statics):
     return bool(jnp.any(needs_oracle & valid & t["slot_maybe"]))
 
 
-def test_lattice_attr_is_the_cycle_programs_own_predicate():
-    """Across a churn that admits, evicts, parks on the preemptor, parks
-    without it and idles: the `lattice` attr of every launch equals the
-    branch predicate recomputed from that launch's inputs, and is true
-    exactly in the cycles that evict or park on the preemptor."""
-    eng = make_engine(cohorts=2, nominal=1000)
+def tap_predicate(eng):
+    """Per launch: (the branch predicate recomputed from the launch's
+    inputs, the cycle program's own output 14)."""
     truth = []
     inner = eng.oracle.executor.cycle_step
 
     def tap(tensors, statics):
-        truth.append(_preemptor_predicate(tensors, statics))
-        return inner(tensors, statics)
+        out = inner(tensors, statics)
+        truth.append((_preemptor_predicate(tensors, statics),
+                      bool(out[14])))
+        return out
 
     eng.oracle.executor.cycle_step = tap
+    return truth
+
+
+def test_lattice_attr_is_the_cycle_programs_own_predicate():
+    """Across a churn that admits, evicts, parks on the preemptor, parks
+    without it and idles: the `lattice` attr of every launch is the
+    cycle program's own output, which equals the branch predicate
+    recomputed from that launch's inputs, and is true exactly in the
+    cycles that evict or park on the preemptor."""
+    eng = make_engine(cohorts=2, nominal=1000)
+    truth = tap_predicate(eng)
     seen, told, counts = [], [], []
 
     def run(expect_lattice):
@@ -471,7 +489,7 @@ def test_lattice_attr_is_the_cycle_programs_own_predicate():
         if spec.children:
             own.append(spec.attrs["lattice"])
         seen.extend(own)
-        assert own == truth[n:], (own, truth[n:])
+        assert [(a, a) for a in own] == truth[n:], (own, truth[n:])
         told.append((vd.attrs["lattice"], expect_lattice))
         return r
 
@@ -497,30 +515,52 @@ def test_lattice_attr_is_the_cycle_programs_own_predicate():
         return sum(c.get(key, 0) for c in counts)
 
     assert total("n_launches") == len(truth)
-    assert total("n_lattice_launches") == sum(truth)
+    assert total("n_lattice_launches") == sum(t for t, _ in truth)
     assert total("n_device_cycles") == 5 and total("n_device_heads") >= 5
 
 
-def test_lattice_is_unknown_not_guessed_where_the_marks_cannot_tell():
-    """A StrictFIFO head the preemptor turns down does not park, so where
-    one could have driven the branch the verdicts do not show whether it
-    did: the attr is None and the phase dict leaves the lattice count
-    out, for a reader to report nothing rather than a low share."""
-    eng = make_engine(strategy=QueueingStrategy.STRICT_FIFO)
-    submit(eng, "low", 600, priority=0)
-    _, root = cycle(eng)
-    # Nobody is running yet: the host's precheck keeps every head off
-    # the preemptor, and that much is exact on any queue.
-    assert child(child(root, "cycle"), "verdict_decode").attrs[
-        "lattice"] is False
-    assert eng.last_cycle_phases["n_lattice_launches"] == 0
-    submit(eng, "high", 600, priority=10)   # `low` is a candidate now
-    r, root = cycle(eng)
-    assert r.stats.preempting == 1
-    assert child(child(root, "cycle"), "verdict_decode").attrs[
-        "lattice"] is None
-    ph = eng.last_cycle_phases
-    assert ph["n_launches"] >= 1 and "n_lattice_launches" not in ph
+@pytest.mark.parametrize("world", [
+    dict(strategy=QueueingStrategy.STRICT_FIFO),
+    dict(groups=2),
+], ids=["strict_fifo", "two_resource_groups"])
+def test_lattice_is_told_where_the_verdicts_could_not_tell(world):
+    """A StrictFIFO head the preemptor turns down does not park, and with
+    two resource groups a head can park with a flavor assigned in the
+    other: no reading of the verdicts tells the branch there (the attr
+    was None). The program's own output does: true where a head drove
+    the preemptor, whatever it decided, and counted."""
+    eng = make_engine(**world)
+    memory = 100 if world.get("groups") == 2 else None
+    truth = tap_predicate(eng)
+
+    def told():
+        r, root = cycle(eng)
+        lattice = child(child(root, "cycle"), "verdict_decode").attrs[
+            "lattice"]
+        assert isinstance(lattice, bool)
+        # From the tree: a cycle that decides nothing leaves the last
+        # deciding cycle's phase dict in place. And a cycle served by a
+        # speculation launched nothing itself.
+        return r, lattice, phase_seconds(root).get("n_lattice_launches", 0)
+
+    submit(eng, "low", 600, priority=0, memory=memory)
+    submit(eng, "tiny", 100, priority=0, memory=memory)
+    # Nobody is running yet, then `tiny` fits: no head needs victims.
+    for _ in range(2):
+        r, lattice, ran = told()
+        assert r.stats.admitted == 1 and lattice is False and ran == 0
+    submit(eng, "high", 600, priority=10, memory=memory)
+    r, lattice, ran = told()      # `low` goes, `tiny` is spared
+    assert r.stats.preempting == 1 and lattice is True and ran >= 1
+    r, lattice, ran = told()
+    assert r.stats.admitted == 1 and lattice is False and ran == 0
+    submit(eng, "mid", 900, priority=5, memory=memory)
+    # `tiny` is a candidate and too small: the preemptor runs, turns
+    # `mid` down, and no verdict of this cycle shows that it ran.
+    r, lattice, ran = told()
+    assert r is None or (r.stats.preempting, r.stats.admitted) == (0, 0)
+    assert lattice is True and ran >= 1
+    assert truth and all(mine == programs for mine, programs in truth)
 
 
 # -- digest neutrality -------------------------------------------------
