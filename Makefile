@@ -94,14 +94,13 @@ obs-smoke: lint
 perf-smoke: lint
 	JAX_PLATFORMS=cpu $(PY) tools/perf_smoke.py
 
-# Columnar-apply / pipelined-cycle smoke: one churn world drained
-# through every KUEUE_TPU_PIPELINE x KUEUE_TPU_COLUMNAR arm to
-# byte-identical digests and final state, the full arm proven to use
-# speculative encodes, then two lethal subprocess stages (SIGKILL at
-# the Nth bulk admission, torn journal tail) whose journal rebuilds
-# must converge to the uninterrupted control — zero lost/duplicate
-# admissions (controllers/colapply.py, oracle/engine_bridge.py,
-# replay/faults.py). lint first: colapply sits in a U1/D1 zone.
+# Columnar-apply smoke: one churn world drained through both
+# KUEUE_TPU_COLUMNAR arms to byte-identical digests and final state,
+# then two lethal subprocess stages (SIGKILL at the Nth bulk admission,
+# torn journal tail) whose journal rebuilds must converge to the
+# uninterrupted control — zero lost/duplicate admissions
+# (controllers/colapply.py, oracle/engine_bridge.py, replay/faults.py).
+# lint first: colapply sits in a U1/D1 zone.
 apply-smoke: lint
 	JAX_PLATFORMS=cpu $(PY) tools/apply_smoke.py
 

@@ -192,13 +192,11 @@ def drive(eng, max_cycles: int) -> dict:
 
 
 def bridge_counters(eng) -> dict:
-    """bench._device_share plus the breaker and the pipeline."""
+    """bench._device_share plus the breaker."""
     import bench
 
-    b = eng.oracle
     return dict(bench._device_share(eng),
-                breaker=b.supervisor.status()["state"],
-                pipeline_stats=dict(b.pipeline_stats))
+                breaker=eng.oracle.supervisor.status()["state"])
 
 
 def check_bridge(eng, flat: bool) -> list:

@@ -2,10 +2,10 @@
 
 One recorder times the engine: ``obs.span.SpanRecorder`` (``eng.spans``,
 always on) keeps a real span tree per ``schedule_once()`` — pre_hooks,
-cycle (take_speculation, host_encode > tas_place, upload, dispatch,
-device_wait, readback, verdict_decode, apply, finalize, host_tail),
-snapshot / decide / apply on the sequential path, speculate, gc_sweep,
-journal_sync, listeners — each span also a ``kueue.<name>``
+cycle (host_encode > tas_place, upload, dispatch, device_wait,
+readback, verdict_decode, apply, finalize, host_tail), snapshot /
+decide / apply on the sequential path, gc_sweep, journal_sync,
+listeners — each span also a ``kueue.<name>``
 ``jax.profiler.TraceAnnotation``, so a profiler capture holds the same
 tree beside the device's operations. ``Engine.last_cycle_phases`` is
 derived from it.

@@ -7,8 +7,9 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     schedule_once                 controllers/engine.py — attrs seq, mode
     ├─ pre_hooks
     ├─ cycle                      oracle bridge: try_cycle; attrs
-    │  │                          lattice, if it launched (as speculate)
-    │  ├─ take_speculation        attrs outcome = used | discarded | none
+    │  │                          lattice, if it launched (whether the
+    │  │                          launch took the preemptor's branch:
+    │  │                          the cycle program's own output)
     │  ├─ host_encode             _encode_cycle up to the device cycle
     │  │  └─ tas_place            (attrs heads, pending)
     │  ├─ sim_nomination          multi-flavor groups on preempting CQs
@@ -31,15 +32,6 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     │  ├─ apply · finalize
     │  └─ host_tail               hybrid cycles only
     ├─ snapshot · decide · apply  sequential path (no bridge; fallback)
-    ├─ speculate                  next cycle's encode + launch; same
-    │                             children as ``cycle`` up to readback;
-    │                             attrs lattice (whether its launch took
-    │                             the preemptor's branch: the cycle
-    │                             program's own output), and outcome
-    │                             once the next cycle learns it; or
-    │                             gate = closed and no children, where
-    │                             the last gap was mutated and nothing
-    │                             was launched
     └─ gc_sweep · journal_sync · listeners
 
 Every span carries name, start and duration on the recorder's clock
@@ -126,36 +118,30 @@ class Span:
 # The spans whose self time is ``unattributed``; everything directly
 # under one of them is a leaf of the identity
 #   sum(leaves) + unattributed == schedule_once.
-CONTAINERS = frozenset({"schedule_once", "cycle", "speculate",
-                        "sim_nomination"})
+CONTAINERS = frozenset({"schedule_once", "cycle", "sim_nomination"})
 
 # Keys of Engine.last_cycle_phases that repeat time the leaf keys
 # already hold: a nested span (tas_place, inside host_encode), a
 # container's wall, and the LEGACY AGGREGATES, which keep the meaning
 # the bridge's perf_counter marks gave them, mark to mark, for the
 # readers that predate the tree (benchmark encode_ms / verdict_decode_ms
-# / unused_speculation_ms, bench.py, chip_smoke.py), to retire with them:
-#   encode      = take_speculation's start to verdict_decode's start
-#                 (the time between the spans included)
-#   device      = verdict_decode (the host's verdict scan, never the
-#                 device; the name is the legacy)
-#   spec_encode = a used speculation's own encode + launch, first
-#                 child's start to last child's end, paid inside the
-#                 previous schedule_once()
-AGGREGATE_KEYS = frozenset({"tas_place", "speculate", "schedule_once",
-                            "encode", "device", "spec_encode",
-                            "sim_nomination"})
+# / unused_speculation_ms, bench.py, profile_apply.py), to retire with
+# them:
+#   encode = the ``cycle`` span's first child's start (host_encode) to
+#            verdict_decode's start (the time between the spans
+#            included)
+#   device = verdict_decode (the host's verdict scan, never the device;
+#            the name is the legacy)
+AGGREGATE_KEYS = frozenset({"tas_place", "schedule_once", "encode",
+                            "device", "sim_nomination"})
 
 # Keys of Engine.last_cycle_phases that are counts of this
 # schedule_once(), not seconds, summed from span attrs recorded at the
 # same boundary as the time, so that a reader holding a window's dicts
 # has the window's own counts:
-#   n_launches, n_lattice_launches  containers (cycle, speculate) that
-#       launched the cycle program (attr ``lattice``), and those whose
-#       launch took the fused preemptor's branch
-#   n_spec_used, n_spec_discarded   take_speculation's ``outcome``
-#   n_spec_skipped                  ``speculate`` spans the gate closed
-#       (attr ``gate``): nothing encoded, nothing launched
+#   n_launches, n_lattice_launches  ``cycle`` spans that launched the
+#       cycle program (attr ``lattice``), and those whose launch took
+#       the fused preemptor's branch
 #   n_device_cycles, n_device_heads verdict_decode spans, and the heads
 #       the device decided in them (attr ``device_heads``)
 #   n_sim_heads, n_sim_rows, n_sim_launches, n_sim_overflow
@@ -163,8 +149,7 @@ AGGREGATE_KEYS = frozenset({"tas_place", "speculate", "schedule_once",
 #       needed preemption simulations, the (head, flavor, resource)
 #       cells simulated, the sim program's launches, and the heads the
 #       sim program handed to the host (more candidates than it scans)
-COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches", "n_spec_used",
-                        "n_spec_discarded", "n_spec_skipped",
+COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches",
                         "n_device_cycles", "n_device_heads",
                         "n_sim_heads", "n_sim_rows", "n_sim_launches",
                         "n_sim_overflow"})
@@ -287,10 +272,9 @@ class SpanRecorder:
 
 def phase_seconds(root: Span) -> dict:
     """A schedule_once() tree as ``Engine.last_cycle_phases``: seconds,
-    one key per leaf name (its time summed over the cycle's own call
-    and a speculation's alike), ``tas_place`` (nested in host_encode),
-    ``speculate`` (that subtree's wall) and the legacy aggregates; and
-    the counts of COUNT_KEYS. ``close_phases`` adds what only the closed
+    one key per leaf name, ``tas_place`` (nested in host_encode),
+    ``sim_nomination`` (that subtree's wall) and the legacy aggregates;
+    and the counts of COUNT_KEYS. ``close_phases`` adds what only the closed
     root knows."""
     out: dict = {}
     launches = lattice = 0
@@ -303,11 +287,7 @@ def phase_seconds(root: Span) -> dict:
         for c in box.children:
             if c.name in CONTAINERS:
                 boxes.append(c)
-                if c.name == "speculate":
-                    _add(out, "speculate", c.dur * 1e-6)
-                    if c.attrs.get("gate") == "closed":
-                        _add(out, "n_spec_skipped", 1)
-                elif c.name == "cycle":
+                if c.name == "cycle":
                     _cycle_aggregates(c, out)
                 elif c.name == "sim_nomination":
                     _add(out, "sim_nomination", c.dur * 1e-6)
@@ -332,14 +312,7 @@ def _cycle_aggregates(cycle: Span, out: dict) -> None:
     """What is read off the ``cycle`` subtree alone: the legacy
     aggregates, mark to mark, and the counts its spans carry."""
     for c in cycle.children:
-        if c.name == "take_speculation":
-            outcome = c.attrs.get("outcome")
-            if outcome in ("used", "discarded"):
-                _add(out, "n_spec_used", outcome == "used")
-                _add(out, "n_spec_discarded", outcome == "discarded")
-            if "spec_encode_s" in c.attrs:
-                out["spec_encode"] = c.attrs["spec_encode_s"]
-        elif c.name == "verdict_decode":
+        if c.name == "verdict_decode":
             # (Else the bridge declined the cycle before a verdict.)
             out["encode"] = (c.ts - cycle.children[0].ts) * 1e-6
             out["device"] = c.dur * 1e-6

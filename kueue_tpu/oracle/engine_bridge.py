@@ -49,6 +49,12 @@ rows that changed, instead of the whole pending world.
 
 Fair sharing runs on device for arbitrary cohort forests: the
 hierarchical LCA tournament is ops/commit.commit_grouped_fair.
+
+There is one way through a cycle: try_cycle's checks, _encode_cycle
+(encode, launch, wait, read back: the executor returns with the
+verdicts on the host) and _commit_cycle, every schedule_once() from the
+engine's state as it then is. Nothing of the next cycle is prepared
+ahead.
 """
 
 from __future__ import annotations
@@ -73,27 +79,6 @@ from kueue_tpu.scheduler.flavorassigner import (
 )
 
 _HOST_BIG = np.int64(1) << 60
-
-
-def pipeline_enabled() -> bool:
-    """Double-buffered cycle loop toggle (ISSUE 16).
-
-    KUEUE_TPU_PIPELINE=0 restores the strictly serial loop (no
-    speculative encode/dispatch of cycle N+1 — the escape hatch the
-    digest-identity suite flips). KUEUE_TPU_PIPELINE_DEPTH bounds the
-    in-flight speculative cycles; the implementation is single-slot, so
-    any depth >= 1 runs one cycle ahead and 0 disables like
-    KUEUE_TPU_PIPELINE=0.
-    """
-    import os
-
-    if os.environ.get("KUEUE_TPU_PIPELINE", "1") == "0":
-        return False
-    try:
-        depth = int(os.environ.get("KUEUE_TPU_PIPELINE_DEPTH", "1"))
-    except ValueError:
-        depth = 1
-    return depth > 0
 
 
 # The cycle_step arguments _encode_cycle converts from host arrays every
@@ -174,20 +159,6 @@ def _fold_fungibility(pm, br, in_group, group_flavors, borrow_try_next,
     return choice.astype(np.int32), mode, borrow
 
 
-class _CycleExit:
-    """An early-exit verdict from :meth:`OracleBridge._encode_cycle`:
-    either a named fallback (``fallback_reason``) or a literal return
-    value (idle cycles). Speculative encodes that hit one are simply
-    discarded — the exit is re-derived (with its stats counted exactly
-    once) by the next synchronous attempt."""
-
-    __slots__ = ("fallback_reason", "value")
-
-    def __init__(self, fallback_reason=None, value=None):
-        self.fallback_reason = fallback_reason
-        self.value = value
-
-
 def _flavor_taint_unsafe(rf) -> bool:
     """A flavor whose workloads must take the host path regardless of
     the batched TAS planner: taints need the host toleration
@@ -210,6 +181,12 @@ def _flavor_predicate():
 
 
 class OracleBridge:
+    # Read by benchmark/sut.py counters() ("pipeline"), which no reader
+    # uses, and by nothing else: the speculative cycle loop these four
+    # counted is gone. Leaves with that reader (ROADMAP C13, item 12).
+    pipeline_stats = {"speculated": 0, "used": 0, "discarded": 0,
+                      "skipped": 0}
+
     def __init__(self, engine, max_depth: int = 4, executor=None,
                  supervisor=None):
         self.engine = engine
@@ -255,29 +232,6 @@ class OracleBridge:
             "placed_host": 0, "memo_hits": 0, "commit_drops": 0,
             "encode_s": 0.0, "place_s": 0.0, "decode_s": 0.0}
         self.tas_heads_per_launch: dict[int, int] = {}
-        # Double-buffered cycle loop (ISSUE 16): the speculatively
-        # encoded + device-dispatched next cycle (stamped with the
-        # state token in _gap_token, below), or the exception when the
-        # speculative dispatch failed — the error surfaces at the next
-        # try_cycle, exactly where the serial loop would have hit it.
-        # None = nothing in flight.
-        self._spec = None
-        self.pipeline_stats: dict[str, int] = {
-            "speculated": 0, "used": 0, "discarded": 0, "skipped": 0}
-        # The speculation gate: speculate only after a quiet gap. A
-        # client that speaks between two schedule_once() calls moves the
-        # state token, and every speculation across such a gap is
-        # thrown away. Whether a speculation WOULD have been used costs
-        # nothing to learn: _maybe_speculate leaves the token it stamps
-        # (or would have stamped) in _gap_token, and the next device
-        # cycle's _take_speculation compares it with the token it finds
-        # — the comparison that decides used / discarded, made whether
-        # or not a speculation exists. None = a gap the bridge did not
-        # observe (fresh bridge, fallback, idle or breaker cycle), which
-        # counts as quiet. Digest-neutral by construction — speculation
-        # only moves work earlier, never changes a decision.
-        self._gap_token: Optional[tuple] = None
-        self._gap_quiet = True
 
     def world_is_fast_path_safe(self) -> bool:
         eng = self.engine
@@ -311,14 +265,12 @@ class OracleBridge:
         return True
 
     def _fallback(self, reason: str) -> None:
-        """Hand the whole cycle to the sequential path. A speculation in
-        flight is dropped, and the gap around a cycle the bridge sat
-        out is one it did not observe (the gate, _take_speculation)."""
-        self._spec = self._gap_token = None
+        """Count a cycle handed whole to the sequential path. Callers
+        write ``return self._fallback(reason)``: the None is try_cycle's
+        request for that path."""
         self.fallback_reasons[reason] = \
             self.fallback_reasons.get(reason, 0) + 1
         self._count("oracle_fallback_total", (reason,))
-        return None
 
     def _host_root(self, reason: str, count: int = 1) -> None:
         self.host_root_reasons[reason] = \
@@ -851,163 +803,36 @@ class OracleBridge:
         }
 
     def try_cycle(self) -> Optional[CycleResult]:
-        """Attempt one hybrid cycle. Returns None to request full
-        sequential fallback (nothing has been mutated in that case).
-
-        With the pipeline on (KUEUE_TPU_PIPELINE, default 1) this
-        cycle's encode + device dispatch may have been SPECULATED at
-        the end of the previous one (_maybe_speculate);
-        _take_speculation validates the state token and either consumes
-        the in-flight cycle or falls through to a fresh encode. The
-        same comparison gates the next speculation: one is made only
-        if the gap before this cycle left the token where it was, so a
-        loop whose client speaks before every cycle launches once a
-        cycle, and a drain loop speculates after every one.
-        Decisions are byte-identical either way: a speculation is used
-        only when the engine state it encoded is bit-for-bit the state
-        this cycle would encode.
-        """
-        spans = self.engine.spans
-        with spans.span("cycle") as box:
-            result = self._cycle(box)
-        if result is not None:
-            with spans.span("speculate") as spec_span:
-                self._maybe_speculate(spec_span)
-        return result
-
-    def _cycle(self, box) -> Optional[CycleResult]:
-        """``box``: the open ``cycle`` span."""
+        """One hybrid cycle, inside its ``cycle`` span: the breaker and
+        world checks, then _encode_cycle (encode, launch, wait, read
+        back) and _commit_cycle on its verdicts. Returns None to
+        request full sequential fallback (nothing has been mutated in
+        that case)."""
         eng = self.engine
-        if (self.supervisor is not None
-                and not self.supervisor.allow_cycle(eng.cycle_seq)):
-            # Breaker open: the device path is known-bad, skip straight
-            # to the host path without paying retries or timeouts.
-            return self._fallback("breaker-open")
-        if not self.world_is_fast_path_safe():
-            return self._fallback("world")
+        with eng.spans.span("cycle") as box:
+            if (self.supervisor is not None
+                    and not self.supervisor.allow_cycle(eng.cycle_seq)):
+                # Breaker open: the device path is known-bad, skip
+                # straight to the host path without paying retries or
+                # timeouts.
+                return self._fallback("breaker-open")
+            if not self.world_is_fast_path_safe():
+                return self._fallback("world")
 
-        if not any(pcq.items for pcq in
-                   eng.queues.cluster_queues.values()):
-            if any(pcq.inadmissible for pcq in
-                   eng.queues.cluster_queues.values()):
-                # Only parked workloads remain; the sequential path owns
-                # the inadmissible re-queueing bookkeeping.
-                return self._fallback("idle-inadmissible")
-            return CycleResult()
+            if not any(pcq.items for pcq in
+                       eng.queues.cluster_queues.values()):
+                if any(pcq.inadmissible for pcq in
+                       eng.queues.cluster_queues.values()):
+                    # Only parked workloads remain; the sequential path
+                    # owns the inadmissible re-queueing bookkeeping.
+                    return self._fallback("idle-inadmissible")
+                return CycleResult()
 
-        with eng.spans.span("take_speculation") as take:
-            enc = self._take_speculation(take)
-        if enc is None:
             enc = self._encode_cycle()
-            if isinstance(enc, _CycleExit):
-                if enc.fallback_reason is not None:
-                    return self._fallback(enc.fallback_reason)
-                return enc.value
-            box.attrs["lattice"] = enc.lattice  # it launched, itself
-        return self._commit_cycle(enc)
-
-    # -- the double-buffered cycle loop (ISSUE 16) --
-
-    def _state_token(self) -> tuple:
-        """Everything _encode_cycle reads, versioned. A speculation is
-        valid iff the token at dispatch equals the token at use: the
-        engine clock, the world spec, admitted-set churn, every
-        pending-row transition (rowcache mutation counter) and every
-        journaled write are covered — any engine mutation between
-        cycles flips at least one component, so a stale speculation can
-        never be committed."""
-        eng = self.engine
-        return (eng.clock,
-                eng.cache.spec_version,
-                eng.cache.admitted_version,
-                eng.queues.rows.mutation_seq,
-                getattr(eng.journal, "writes_seq", 0),
-                len(eng.workloads),
-                len(eng.namespace_labels))
-
-    def _take_speculation(self, take):
-        """Consume the in-flight speculative cycle if it is still
-        valid; None forces a fresh synchronous encode. What became of
-        the speculation is stamped on ``take`` (this cycle's
-        take_speculation span) and on the ``speculate`` span that paid
-        for it, one schedule_once() back. Every device cycle passes
-        here, so this is also where the gap since the last stamp is
-        read for the gate: quiet (token unmoved, or not observed) or
-        mutated."""
-        token = self._state_token()
-        opened, self._gap_token = self._gap_token, None
-        self._gap_quiet = opened is None or opened == token
-        payload, self._spec = self._spec, None
-        if payload is None:
-            take.attrs["outcome"] = "none"
-            return None
-        if isinstance(payload, Exception):
-            # The speculative dispatch failed. Surface the error HERE —
-            # the point where the serial loop would have raised it —
-            # so the engine's RemoteOracleError fallback (and every
-            # chaos-injected oracle fault) behaves identically with the
-            # pipeline on.
-            raise payload
-        if opened != token:
-            take.attrs["outcome"] = \
-                payload.spec_span.attrs["outcome"] = "discarded"
-            self.pipeline_stats["discarded"] += 1
-            self._count("oracle_pipeline_total", ("discarded",))
-            return None
-        for fn, a in payload.deferred:
-            fn(*a)
-        payload.deferred = ()
-        take.attrs["outcome"] = payload.spec_span.attrs["outcome"] = "used"
-        # The legacy ``spec_encode`` key (obs.span.AGGREGATE_KEYS): what
-        # that speculation's encode + launch cost, mark to mark.
-        first, last = (payload.spec_span.children[0],
-                       payload.spec_span.children[-1])
-        take.attrs["spec_encode_s"] = \
-            (last.ts + last.dur - first.ts) * 1e-6
-        self.pipeline_stats["used"] += 1
-        self._count("oracle_pipeline_total", ("used",))
-        return payload
-
-    def _maybe_speculate(self, spec_span) -> None:
-        """Encode cycle N+1 and run its device program NOW, inside this
-        schedule_once() (``spec_span`` is its open ``speculate`` span)
-        — if the last gap the bridge observed was quiet (the gate,
-        _take_speculation). The executor returns with the verdicts read
-        back, so nothing overlaps the host yet (ROADMAP A2), and across
-        a gap in which the client speaks the whole launch is thrown
-        away: after a mutated gap only the token is stamped, for the
-        next cycle to read the next gap by; the span then carries
-        ``gate = "closed"`` and has no children. Counter/stat side
-        effects of the speculative encode are deferred and committed
-        only when the speculation is USED, so a discarded one leaves
-        every diagnostic exactly as the serial loop would have (its
-        ``lattice`` attr stands either way: the launch was made)."""
-        eng = self.engine
-        self._spec = self._gap_token = None
-        if not pipeline_enabled():
-            return
-        if not any(pcq.items for pcq in
-                   eng.queues.cluster_queues.values()):
-            return
-        if not self._gap_quiet:
-            spec_span.attrs["gate"] = "closed"
-            self.pipeline_stats["skipped"] += 1
-            self._gap_token = self._state_token()
-            return
-        try:
-            enc = self._encode_cycle(defer_stats=True)
-        except Exception as e:
-            self._spec = e
-            return
-        if isinstance(enc, _CycleExit):
-            return
-        # The open ``speculate`` span: the next cycle stamps the outcome
-        # on it.
-        enc.spec_span = spec_span
-        spec_span.attrs["lattice"] = enc.lattice
-        self._gap_token = self._state_token()
-        self._spec = enc
-        self.pipeline_stats["speculated"] += 1
+            if enc is None:
+                return None
+            box.attrs["lattice"] = enc.lattice
+            return self._commit_cycle(enc)
 
     def _commit_tas_stats(self, tas_plan) -> None:
         st = self.tas_stats
@@ -1024,28 +849,17 @@ class OracleBridge:
             self.tas_heads_per_launch[n] = \
                 self.tas_heads_per_launch.get(n, 0) + 1
 
-    def _encode_cycle(self, defer_stats: bool = False):
+    def _encode_cycle(self):
         """The encode phase: world + row tensors, head selection with
         hold-back, per-root host/device partitioning, batched TAS
         nomination, sim-augmented multi-flavor nomination, and the
-        executor call. Returns the solved cycle for _commit_cycle, or a
-        _CycleExit.
-
-        ``defer_stats`` (speculative mode) buffers every counter/stat
-        side effect into ``enc.deferred`` instead of committing it, so
-        discarding a speculation cannot skew diagnostics."""
+        executor call. Returns the solved cycle for _commit_cycle, or
+        None after counting the fallback (held-head-churn, all-host)."""
         import jax.numpy as jnp
 
         eng = self.engine
         spans = eng.spans
         host = spans.begin("host_encode")
-        deferred: list = []
-        if defer_stats:
-            def emit(fn, *a):
-                deferred.append((fn, a))
-        else:
-            def emit(fn, *a):
-                fn(*a)
         now = eng.clock
         # Incremental encoding: the queue manager's row cache carries the
         # pending world as live tensors; a cycle pays only for rows that
@@ -1095,7 +909,7 @@ class OracleBridge:
         else:
             # Pathological hold churn: give up on the fast path.
             spans.end()
-            return _CycleExit(fallback_reason="held-head-churn")
+            return self._fallback("held-head-churn")
 
         head_eligible = np.zeros(C, bool)
         head_eligible[has_head] = wl.eligible[head_wid[has_head]]
@@ -1126,7 +940,7 @@ class OracleBridge:
             roots = np.unique(root_of_cq[cq_mask])
             new = roots[~host_root[roots]]
             if new.size:
-                emit(self._host_root, reason, int(new.size))
+                self._host_root(reason, int(new.size))
                 host_root[new] = True
 
         demote(has_head & ~head_eligible, "head-ineligible")
@@ -1191,7 +1005,7 @@ class OracleBridge:
                     m = np.zeros(C, bool)
                     m[closed] = True
                     demote(m, "tas-forest-shared")
-                emit(self._commit_tas_stats, tas_plan)
+                self._commit_tas_stats(tas_plan)
         spans.end()
         cq_on_device = ~host_root[root_of_cq]
 
@@ -1264,7 +1078,7 @@ class OracleBridge:
             & cq_on_device[cq_safe_idx]
         if not device_w.any():
             spans.end()
-            return _CycleExit(fallback_reason="all-host")
+            return self._fallback("all-host")
         host.attrs["heads"] = int(np.count_nonzero(has_head))
         host.attrs["pending"] = int(np.count_nonzero(device_w))
 
@@ -1353,11 +1167,10 @@ class OracleBridge:
         upload.attrs["bytes"] = sum(
             _inputs[k].nbytes for k in _PER_CYCLE_UPLOADS if k in _inputs)
         spans.end()
-        emit(_obs_perf.device_call, "cycle_step", _inputs, statics)
+        _obs_perf.device_call("cycle_step", _inputs, statics)
         # The executor blocks until the verdicts are on the host
         # (service._run_cycle_step records dispatch / device_wait /
-        # readback): a speculative launch is waited for here, inside
-        # the schedule_once() that launched it.
+        # readback).
         out = self._exec_call("cycle_step", self.executor.cycle_step,
                               _inputs, statics)
         # Whether this launch took the fused preemptor's branch: the
@@ -1371,15 +1184,12 @@ class OracleBridge:
             cq_on_device=cq_on_device, host_root=host_root,
             root_of_cq=root_of_cq, has_head=has_head,
             tas_plan=tas_plan, fused=fused, admitted=admitted,
-            lattice=lattice,
-            deferred=deferred, spec_span=None)
+            lattice=lattice)
 
     def _commit_cycle(self, enc) -> Optional[CycleResult]:
         """Commit the cycle from the verdicts the executor read back:
         verdict decode, TAS commit-order recheck, columnar apply,
-        finalize, host tail. ``enc`` comes from _encode_cycle — fresh
-        this cycle or used from the speculation slot (byte-identical
-        either way)."""
+        finalize, host tail. ``enc`` is this cycle's _encode_cycle."""
         from kueue_tpu.tas import batched as _tb
 
         eng = self.engine

@@ -184,10 +184,6 @@ class Journal:
         self._read_offset = 0
         self._read_ino = os.fstat(self._fh.fileno()).st_ino
         self._active_lines = 0
-        # Monotone count of lines written through this handle; the
-        # pipelined cycle loop folds it into its speculation token so a
-        # speculative encode is discarded after any journaled mutation.
-        self.writes_seq = 0
         # Generations recovered from a checkpoint (seed_generations):
         # segments the retention pass deleted may hold a key's only
         # write, so the file scan alone would under-count. Merged as a
@@ -630,7 +626,6 @@ class Journal:
         # and re-parse it (one open+parse per record on the hot path).
         self._read_offset += len(blob.encode("utf-8"))
         self._active_lines += len(lines)
-        self.writes_seq += len(lines)
 
     def sync(self) -> None:
         """Crash-safe cycle boundary (Engine.schedule_once calls this

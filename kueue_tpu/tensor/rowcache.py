@@ -92,11 +92,6 @@ class WorkloadRowCache:
         self._tas_req: list = [None] * self._cap
         self._dirty: set[int] = set()
         self._hashes = _HashRegistry()
-        # Monotone mutation counter: bumped on every structural change
-        # (push/park/pop/remove/world-bind).  The pipelined cycle loop
-        # folds it into its speculation token so a speculative encode is
-        # only reused when the cache is bit-for-bit unchanged.
-        self.mutation_seq = 0
 
         # world-independent columns
         self.priority = np.zeros(self._cap, np.int64)
@@ -131,7 +126,6 @@ class WorkloadRowCache:
 
     def on_push(self, info: WorkloadInfo, sort_key: tuple) -> None:
         """Workload entered (or re-entered) a pending heap."""
-        self.mutation_seq += 1
         i = self._row_of.get(info.key)
         wl = info.obj
         if i is None:
@@ -172,7 +166,6 @@ class WorkloadRowCache:
     def on_park(self, info: WorkloadInfo) -> None:
         """Workload moved to the inadmissible side map (row kept: a
         cluster event can re-activate it)."""
-        self.mutation_seq += 1
         i = self._row_of.get(info.key)
         if i is None:  # parked without ever being pushed
             from kueue_tpu.workload_info import queue_order_timestamp
@@ -185,14 +178,12 @@ class WorkloadRowCache:
 
     def on_pop(self, key: str) -> None:
         """Workload popped (in flight with the sequential path)."""
-        self.mutation_seq += 1
         i = self._row_of.get(key)
         if i is not None:
             self.active[i] = False
 
     def on_remove(self, key: str) -> None:
         """Workload left the pending world (admitted / deleted)."""
-        self.mutation_seq += 1
         i = self._row_of.pop(key, None)
         if i is None:
             return
@@ -217,7 +208,6 @@ class WorkloadRowCache:
         release, free-list push); row order is preserved so the
         free-list matches the serial path exactly.
         """
-        self.mutation_seq += 1
         rows = []
         row_pop = self._row_of.pop
         info_of = self.info_of
@@ -360,7 +350,6 @@ class WorkloadRowCache:
         sig = self.world_signature(world)
         if sig == self._signature:
             return
-        self.mutation_seq += 1
         self._signature = sig
         S = max(world.num_resources, 1)
         if S != self.requests.shape[2]:

@@ -1,7 +1,6 @@
 """Columnar apply (controllers/colapply.py) equivalence and chaos
-suite: the columnar batch-assume path and the pipelined device cycle
-must be byte-identical to the serial escape hatches
-(KUEUE_TPU_COLUMNAR=0 / KUEUE_TPU_PIPELINE=0) — same chained decision
+suite: the columnar batch-assume path must be byte-identical to the
+serial escape hatch (KUEUE_TPU_COLUMNAR=0) — same chained decision
 digests, same final admitted state, same tensor-row free-list order —
 and the fault layer's sigkill@admission ordinal must fire at the same
 admission count on the bulk path as on the per-entry path, with
@@ -26,10 +25,8 @@ from kueue_tpu.replay.trace import (  # noqa: E402
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 ARMS = {
-    "serial": {"KUEUE_TPU_PIPELINE": "0", "KUEUE_TPU_COLUMNAR": "0"},
-    "columnar": {"KUEUE_TPU_PIPELINE": "0", "KUEUE_TPU_COLUMNAR": "1"},
-    "pipelined": {"KUEUE_TPU_PIPELINE": "1", "KUEUE_TPU_COLUMNAR": "0"},
-    "full": {"KUEUE_TPU_PIPELINE": "1", "KUEUE_TPU_COLUMNAR": "1"},
+    "serial": {"KUEUE_TPU_COLUMNAR": "0"},
+    "columnar": {"KUEUE_TPU_COLUMNAR": "1"},
 }
 
 
@@ -78,7 +75,7 @@ def _fingerprint(eng):
 
 
 class TestDigestIdentity:
-    """Every PIPELINE x COLUMNAR arm decides the same stream."""
+    """Both COLUMNAR arms decide the same stream."""
 
     def _arm_digest(self, monkeypatch, arm):
         _set_arm(monkeypatch, arm)
@@ -87,7 +84,7 @@ class TestDigestIdentity:
         assert cycles > 0, f"{arm}: no cycles ran"
         return digest, _fingerprint(eng)
 
-    @pytest.mark.parametrize("arm", ["columnar", "pipelined", "full"])
+    @pytest.mark.parametrize("arm", ["columnar"])
     def test_matches_serial(self, monkeypatch, arm):
         base = self._arm_digest(monkeypatch, "serial")
         assert self._arm_digest(monkeypatch, arm) == base, (
@@ -125,7 +122,7 @@ class TestChaosSeededIdentity:
         return digest, _fingerprint(eng)
 
     def test_columnar_matches_serial_under_faults(self, monkeypatch):
-        assert (self._arm(monkeypatch, "full")
+        assert (self._arm(monkeypatch, "columnar")
                 == self._arm(monkeypatch, "serial"))
 
 
@@ -208,7 +205,6 @@ class TestRowBatchRelease:
         assert a._hash_tuple == b._hash_tuple
         assert a._tas_req == b._tas_req
         assert a._dirty == b._dirty
-        assert a.mutation_seq > 0 and b.mutation_seq > 0
         # Refill consumes the free list in the same order on both.
         for i in (3, 0, 17):
             wl = Workload(name=f"r{i}", queue_name="lq",
@@ -244,7 +240,7 @@ class TestBulkKillOrdinal:
         return eng, injector
 
     def test_ordinal_counts_bulk_admissions(self, monkeypatch, tmp_path):
-        _set_arm(monkeypatch, "full")
+        _set_arm(monkeypatch, "columnar")
         path = str(tmp_path / "j.jsonl")
         eng, injector = self._arm_and_boom(monkeypatch, path, 12)
         assert injector.admissions == 12, (
@@ -256,7 +252,7 @@ class TestBulkKillOrdinal:
             _recover_and_fingerprint,
         )
 
-        _set_arm(monkeypatch, "full")
+        _set_arm(monkeypatch, "columnar")
         path = str(tmp_path / "j.jsonl")
         self._arm_and_boom(monkeypatch, path, 12)
         # The dead engine's journal handle stays open — exactly like a
@@ -275,7 +271,7 @@ class TestBulkKillOrdinal:
             _recover_and_fingerprint,
         )
 
-        _set_arm(monkeypatch, "full")
+        _set_arm(monkeypatch, "columnar")
         path = str(tmp_path / "j.jsonl")
         eng, _ = self._arm_and_boom(monkeypatch, path, 12)
         _tear_journal_tail(eng.journal)
@@ -288,7 +284,7 @@ class TestBulkKillOrdinal:
 
 # -- real-SIGKILL child arm (slow tier): the in-process _Boom tests
 # above prove the ordinal and the convergence; this proves them under
-# an actual SIGKILL with the pipeline on, mirroring
+# an actual SIGKILL on the columnar path, mirroring
 # tests/test_replay_faults.py for the device path.
 
 _CHILD = r"""
@@ -296,7 +292,6 @@ import sys
 sys.path.insert(0, {repo!r})
 import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ["KUEUE_TPU_PIPELINE"] = "1"
 os.environ["KUEUE_TPU_COLUMNAR"] = "1"
 import jax
 jax.config.update("jax_platforms", "cpu")
@@ -316,7 +311,7 @@ print("done", flush=True)
 
 
 @pytest.mark.slow
-def test_pipelined_sigkill_mid_apply_recovers_to_control(tmp_path):
+def test_sigkill_mid_apply_recovers_to_control(tmp_path):
     from tests.test_replay_faults import (
         _control_fingerprint,
         _recover_and_fingerprint,
@@ -338,4 +333,4 @@ def test_pipelined_sigkill_mid_apply_recovers_to_control(tmp_path):
         f"err={child.stderr.read()[-800:]}")
     assert "done" not in out, "child finished churn — kill never fired"
     assert _recover_and_fingerprint(path) == _control_fingerprint(), (
-        "pipelined post-crash recovery diverged from the control")
+        "post-crash recovery diverged from the control")

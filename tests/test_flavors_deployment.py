@@ -54,9 +54,11 @@ def probe_world(cohorts: int, scenario: int, when_can_preempt: str,
 
 
 def drive(program, world: dict, cycles: int = CYCLES,
-          per_cycle: int = 2) -> tuple:
+          per_cycle: int = 2, again: tuple = ()) -> tuple:
     """The loop of benchmark/run.py without its clocks: (events,
-    verdicts, phases of each cycle)."""
+    verdicts, phases of each cycle). After a cycle in ``again`` the
+    client says nothing and the program cycles once more: its phases
+    are listed, its verdicts applied."""
     mix = dict(trafficgen.read_mix("trickle-turnover", tiny=True),
                turnover_share=per_cycle / len(world["cluster_queues"]))
     gen = trafficgen.Generator(mix, world)
@@ -75,6 +77,9 @@ def drive(program, world: dict, cycles: int = CYCLES,
         events.append((finishes, arrivals, now))
         verdicts.append(v)
         phases.append(program.phases())
+        if k in again:
+            sets.apply(program.cycle(now))
+            phases.append(program.phases())
     return events, verdicts, phases
 
 
@@ -133,12 +138,13 @@ def test_the_sim_program_has_one_shape_whatever_the_rows():
     """48 ClusterQueues in 4 cohorts (the world file's `tiny`): the
     rows a cycle simulates vary more than 8-fold, from under one block
     (a row a queue, to the next power of two) to several, which loop
-    it; the sim program is launched with one shape, and so is the cycle
-    program."""
+    it — where the first cycle's admissions are followed by no finish,
+    every queue's next head meets full flavors at once; the sim program
+    is launched with one shape, and so is the cycle program."""
     cfg = run.read_config(CONFIG, tiny=True)
     world = worldgen_flavors.build_world(cfg, seed=9)
     device = sut_flavors.Program(world, "local")
-    _events, _got, phases = drive(device, world, cycles=24)
+    _events, _got, phases = drive(device, world, cycles=24, again=(0,))
     rows = [p["n_sim_rows"] for p in phases if p.get("n_sim_rows")]
     assert max(rows) >= 8 * min(rows), rows
     assert len(device.sim_shapes) == 1

@@ -118,10 +118,10 @@ def test_remote_cycle_records_the_wire_spans(oracle_proc):
     eng.schedule_once()
     (cyc,) = [c for c in eng.spans.last().children if c.name == "cycle"]
     names = [c.name for c in cyc.children]
-    assert names[:6] == ["take_speculation", "host_encode", "upload",
-                         "upload", "device_wait", "readback"]
+    assert names[:5] == ["host_encode", "upload", "upload", "device_wait",
+                         "readback"]
     assert "dispatch" not in names
-    wire_spans = {c.name: c for c in cyc.children[3:6]}
+    wire_spans = {c.name: c for c in cyc.children[2:5]}
     assert wire_spans["upload"].attrs["bytes"] > 0
     assert wire_spans["readback"].attrs["bytes"] > 0
     assert {"upload", "device_wait", "readback"} <= set(

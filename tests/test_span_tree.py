@@ -1,8 +1,8 @@
 """The engine's span tree (obs/span.py SpanRecorder, ISSUE 27): one real
 tree per schedule_once(), recorded where the work happens; the phase
-dict derived from it; what became of each speculation; the preemptor's
-branch counted per launch; the device stages' scopes in the lowered
-cycle program; and the same tree in a profiler capture."""
+dict derived from it; the preemptor's branch counted per launch; the
+device stages' scopes in the lowered cycle program; and the same tree
+in a profiler capture."""
 
 import contextlib
 import glob
@@ -43,9 +43,13 @@ COMMIT = ["verdict_decode", "apply", "finalize"]
 
 
 def make_engine(oracle=True, cohorts=1, nominal=1000, preemption=True,
-                strategy=QueueingStrategy.BEST_EFFORT_FIFO, groups=1):
+                strategy=QueueingStrategy.BEST_EFFORT_FIFO, groups=1,
+                flavors=1):
     eng = Engine()
     eng.create_resource_flavor(ResourceFlavor("default"))
+    later = [f"later{f}" for f in range(1, flavors)]  # of the cpu group
+    for name in later:
+        eng.create_resource_flavor(ResourceFlavor(name))
     second = ()
     if groups == 2:  # memory, in a resource group and flavor of its own
         eng.create_resource_flavor(ResourceFlavor("dimm"))
@@ -58,8 +62,9 @@ def make_engine(oracle=True, cohorts=1, nominal=1000, preemption=True,
                 within_cluster_queue=PreemptionPolicy.LOWER_PRIORITY)
                 if preemption else ClusterQueuePreemption()),
             resource_groups=(ResourceGroup(
-                ("cpu",),
-                (FlavorQuotas("default", {"cpu": ResourceQuota(nominal)}),)),
+                ("cpu",), tuple(
+                    FlavorQuotas(name, {"cpu": ResourceQuota(nominal)})
+                    for name in ["default"] + later)),
             ) + second,
         ))
         eng.create_local_queue(LocalQueue(f"lq{i}", "default", f"cq{i}"))
@@ -210,11 +215,10 @@ def test_device_cycle_tree():
     submit(eng, "b", 400)
     _, root = cycle(eng)
     assert root.attrs == {"seq": 0, "mode": "device"}
-    assert names(root) == ["pre_hooks", "cycle", "speculate", "listeners"]
-    cyc, spec = child(root, "cycle"), child(root, "speculate")
-    assert names(cyc) == ["take_speculation"] + ENCODE + COMMIT
-    assert names(spec) == ENCODE
-    assert child(cyc, "take_speculation").attrs == {"outcome": "none"}
+    assert names(root) == ["pre_hooks", "cycle", "listeners"]
+    cyc = child(root, "cycle")
+    assert names(cyc) == ENCODE + COMMIT
+    assert cyc.attrs == {"lattice": False}
     host = child(cyc, "host_encode")
     assert names(host) == ["tas_place"]
     assert host.attrs == {"heads": 1, "pending": 2}
@@ -257,80 +261,9 @@ def test_fallback_cycle_keeps_both_attempts_in_one_tree():
     assert eng.oracle.fallback_reasons == {"all-host": 1}
     assert names(root) == ["pre_hooks", "cycle", "snapshot", "decide",
                            "apply", "listeners"]
-    assert names(child(root, "cycle")) == ["take_speculation",
-                                           "host_encode"]
+    assert names(child(root, "cycle")) == ["host_encode"]
     ph = eng.last_cycle_phases
     assert "encode" not in ph and "device" not in ph  # no verdict came
-    assert_adds_up(ph)
-
-
-# -- speculations: what became of each ---------------------------------
-
-
-def test_used_speculation_is_stamped_on_both_sides():
-    eng = make_engine()
-    for i in range(3):
-        submit(eng, f"w{i}", 400)
-    _, first = cycle(eng)
-    spec = child(first, "speculate")
-    assert "outcome" not in spec.attrs  # nobody knows yet
-    _, second = cycle(eng)              # nothing happened in between
-    take = child(child(second, "cycle"), "take_speculation")
-    assert take.attrs["outcome"] == "used"
-    assert spec.attrs["outcome"] == "used"
-    assert names(child(second, "cycle")) == ["take_speculation"] + COMMIT
-    stats = eng.oracle.pipeline_stats
-    assert (stats["used"], stats["discarded"]) == (1, 0)
-    # Legacy `spec_encode`: the used speculation's own encode + launch,
-    # paid one schedule_once() back; mark to mark, as `encode` is.
-    ph = eng.last_cycle_phases
-    first, last = spec.children[0], spec.children[-1]
-    assert ph["spec_encode"] == pytest.approx(
-        (last.ts + last.dur - first.ts) * 1e-6)
-    assert ph["spec_encode"] >= sum(c.dur for c in spec.children) * 1e-6
-    vd = child(child(second, "cycle"), "verdict_decode")
-    assert ph["encode"] == pytest.approx((vd.ts - take.ts) * 1e-6)
-    # This schedule_once()'s own counts: the speculation it learned of
-    # and the one it made.
-    assert (ph["n_spec_used"], ph["n_spec_discarded"]) == (1, 0)
-    assert (ph["n_launches"], ph["n_device_cycles"]) == (1, 1)
-    assert_adds_up(ph)
-
-
-def test_discarded_speculation_is_stamped_and_counted_in_its_cycle():
-    eng = make_engine()
-    for i in range(3):
-        submit(eng, f"w{i}", 300)
-    _, first = cycle(eng)
-    spec = child(first, "speculate")
-    submit(eng, "late", 100)            # the client spoke: token flips
-    _, second = cycle(eng)
-    take = child(child(second, "cycle"), "take_speculation")
-    assert take.attrs == {"outcome": "discarded"}
-    assert spec.attrs["outcome"] == "discarded"
-    stats = eng.oracle.pipeline_stats
-    assert (stats["used"], stats["discarded"]) == (0, 1)
-    # What it cost is on the span that paid for it.
-    assert spec.dur > 0 and spec.attrs["lattice"] is False
-    assert names(child(second, "cycle")) == \
-        ["take_speculation"] + ENCODE + COMMIT
-    ph = eng.last_cycle_phases
-    assert "spec_encode" not in ph
-    assert (ph["n_spec_used"], ph["n_spec_discarded"]) == (0, 1)
-    # Every cycle_step call is a launch, thrown away or not: the first
-    # schedule_once() made two, the cycle's own and its speculation's.
-    assert phase_seconds(first)["n_launches"] == 2
-    # The gap was mutated, so the gate is closed (ISSUE 28): the second
-    # launches once, and its speculate span says why it is empty.
-    skipped = child(second, "speculate")
-    assert skipped.attrs == {"gate": "closed"} and not skipped.children
-    assert "gate" not in spec.attrs and "n_spec_skipped" not in \
-        phase_seconds(first)
-    assert ph["n_launches"] == ph["n_device_cycles"] == 1
-    assert ph["n_lattice_launches"] == 0
-    assert ph["n_spec_skipped"] == 1
-    assert (stats["speculated"], stats["skipped"]) == (1, 1)
-    assert_nested(second)
     assert_adds_up(ph)
 
 
@@ -343,47 +276,40 @@ def test_phase_keys_are_sums_over_everything_that_ran():
         submit(eng, f"w{i}", 300)
     _, root = cycle(eng)
     ph = eng.last_cycle_phases
-    cyc, spec = child(root, "cycle"), child(root, "speculate")
+    cyc = child(root, "cycle")
 
-    def total(name, *boxes):
-        return sum(c.dur for b in boxes for c in b.children
-                   if c.name == name) * 1e-6
+    def total(name):
+        return sum(c.dur for c in cyc.children if c.name == name) * 1e-6
 
-    # Leaves: the cycle's own call and the speculation's alike.
-    for name in ("host_encode", "upload", "dispatch", "device_wait",
-                 "readback"):
-        assert ph[name] == pytest.approx(total(name, cyc, spec))
-    for name in COMMIT + ["take_speculation"]:
-        assert ph[name] == pytest.approx(total(name, cyc))
-    assert ph["speculate"] == pytest.approx(spec.dur * 1e-6)
+    # Leaves: `upload` is two spans a cycle (the bridge's, then the
+    # executor's leftovers), one key.
+    for name in ENCODE + COMMIT:
+        assert ph[name] == pytest.approx(total(name))
     assert ph["schedule_once"] == pytest.approx(root.dur * 1e-6)
-    assert ph["tas_place"] == pytest.approx(sum(
-        child(h, "tas_place").dur for b in (cyc, spec)
-        for h in b.children if h.name == "host_encode") * 1e-6)
-    # Unattributed is the three containers' self time.
+    assert ph["tas_place"] == pytest.approx(
+        child(child(cyc, "host_encode"), "tas_place").dur * 1e-6)
+    # Unattributed is the two containers' self time.
     self_time = sum(b.dur - sum(c.dur for c in b.children)
-                    for b in (root, cyc, spec)) * 1e-6
-    # (`sim_nomination`, the fourth, is in a tree only where a head's
+                    for b in (root, cyc)) * 1e-6
+    # (`sim_nomination`, the third, is in a tree only where a head's
     # flavor choice needs simulations: tests/test_flavors_deployment.py.)
-    assert {b.name for b in (root, cyc, spec)} == CONTAINERS - {
-        "sim_nomination"}
+    assert {b.name for b in (root, cyc)} == CONTAINERS - {"sim_nomination"}
     assert ph["unattributed"] == pytest.approx(self_time, abs=1e-9)
-    # Legacy aggregates, read off the cycle's own subtree alone, mark
-    # to mark as the bridge's perf_counter marks were: the time between
-    # the spans is `encode`'s too.
+    # Legacy aggregates, read off the cycle's subtree, mark to mark as
+    # the bridge's perf_counter marks were: the time between the spans
+    # is `encode`'s too.
     vd = child(cyc, "verdict_decode")
     assert ph["encode"] == pytest.approx(
-        (vd.ts - child(cyc, "take_speculation").ts) * 1e-6)
+        (vd.ts - child(cyc, "host_encode").ts) * 1e-6)
     assert ph["encode"] >= sum(
-        c.dur for c in cyc.children
-        if c.name in ["take_speculation"] + ENCODE) * 1e-6
+        c.dur for c in cyc.children if c.name in ENCODE) * 1e-6
     assert ph["device"] == ph["verdict_decode"]
-    assert {"encode", "device", "tas_place", "speculate",
+    assert {"encode", "device", "tas_place",
             "schedule_once"} <= AGGREGATE_KEYS
     # Counts, from the attrs of this tree's spans; nothing that adds
     # seconds up takes them in.
     assert {k: ph[k] for k in COUNT_KEYS if k in ph} == {
-        "n_launches": 2, "n_lattice_launches": 0, "n_device_cycles": 1,
+        "n_launches": 1, "n_lattice_launches": 0, "n_device_cycles": 1,
         "n_device_heads": 1}
     assert not COUNT_KEYS & set(leaf_phases(ph))
     # The histogram takes the leaves and the whole, no aggregate.
@@ -482,14 +408,10 @@ def test_lattice_attr_is_the_cycle_programs_own_predicate():
         counts.append(dict(eng.last_cycle_phases))
         cyc = child(root, "cycle")
         vd = child(cyc, "verdict_decode")
-        own = [] if "take_speculation" in names(cyc) and child(
-            cyc, "take_speculation").attrs["outcome"] == "used" \
-            else [vd.attrs["lattice"]]
-        spec = child(root, "speculate")
-        if spec.children:
-            own.append(spec.attrs["lattice"])
-        seen.extend(own)
-        assert [(a, a) for a in own] == truth[n:], (own, truth[n:])
+        # One launch a cycle, told on the container and on the decode.
+        assert cyc.attrs["lattice"] is vd.attrs["lattice"]
+        seen.append(vd.attrs["lattice"])
+        assert [(vd.attrs["lattice"],) * 2] == truth[n:], truth[n:]
         told.append((vd.attrs["lattice"], expect_lattice))
         return r
 
@@ -539,9 +461,8 @@ def test_lattice_is_told_where_the_verdicts_could_not_tell(world):
             "lattice"]
         assert isinstance(lattice, bool)
         # From the tree: a cycle that decides nothing leaves the last
-        # deciding cycle's phase dict in place. And a cycle served by a
-        # speculation launched nothing itself.
-        return r, lattice, phase_seconds(root).get("n_lattice_launches", 0)
+        # deciding cycle's phase dict in place.
+        return r, lattice, phase_seconds(root)["n_lattice_launches"]
 
     submit(eng, "low", 600, priority=0, memory=memory)
     submit(eng, "tiny", 100, priority=0, memory=memory)
@@ -551,7 +472,7 @@ def test_lattice_is_told_where_the_verdicts_could_not_tell(world):
         assert r.stats.admitted == 1 and lattice is False and ran == 0
     submit(eng, "high", 600, priority=10, memory=memory)
     r, lattice, ran = told()      # `low` goes, `tiny` is spared
-    assert r.stats.preempting == 1 and lattice is True and ran >= 1
+    assert r.stats.preempting == 1 and lattice is True and ran == 1
     r, lattice, ran = told()
     assert r.stats.admitted == 1 and lattice is False and ran == 0
     submit(eng, "mid", 900, priority=5, memory=memory)
@@ -559,7 +480,7 @@ def test_lattice_is_told_where_the_verdicts_could_not_tell(world):
     # `mid` down, and no verdict of this cycle shows that it ran.
     r, lattice, ran = told()
     assert r is None or (r.stats.preempting, r.stats.admitted) == (0, 0)
-    assert lattice is True and ran >= 1
+    assert lattice is True and ran == 1
     assert truth and all(mine == programs for mine, programs in truth)
 
 
@@ -675,10 +596,8 @@ def test_profiler_capture_holds_the_tree(tmp_path):
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         submit(eng, "x", 100)
-        eng.schedule_once()  # the speculation is stale: a fresh encode,
-        #                      and the gate closes: an empty speculate
-        eng.schedule_once()  # a quiet gap: fresh encode, gate open again
-        eng.schedule_once()  # this one's is used
+        for _ in range(3):
+            eng.schedule_once()
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(os.path.join(
@@ -695,29 +614,15 @@ def test_profiler_capture_holds_the_tree(tmp_path):
         return [e for e in events if e[0] == name
                 and outer[1] <= e[1] and e[2] <= outer[2]]
 
-    def launched(box):
-        (host,) = inside("kueue.host_encode", box)
-        assert len(inside("kueue.tas_place", host)) == 1
-        (dispatch,) = inside("kueue.dispatch", box)
-        (wait,) = inside("kueue.device_wait", box)
-        (readback,) = inside("kueue.readback", box)
-        assert host[2] <= dispatch[1] and dispatch[2] <= wait[1] \
-            and wait[2] <= readback[1]
-
-    fresh, reopened, served = sorted(e for e in events
-                                     if e[0] == "kueue.schedule_once")
-    for root in (fresh, reopened, served):
+    roots = sorted(e for e in events if e[0] == "kueue.schedule_once")
+    assert len(roots) == 3
+    for root in roots:
         (cyc,) = inside("kueue.cycle", root)
-        (spec,) = inside("kueue.speculate", root)
-        assert cyc[2] <= spec[1]
-        assert len(inside("kueue.take_speculation", cyc)) == 1
-        assert len(inside("kueue.verdict_decode", cyc)) == 1
-        assert not inside("kueue.verdict_decode", spec)
-        if root is fresh:  # gate closed: nothing encoded or launched
-            assert not inside("kueue.host_encode", spec)
-            assert not inside("kueue.dispatch", spec)
-        else:
-            launched(spec)
-    launched(inside("kueue.cycle", fresh)[0])
-    launched(inside("kueue.cycle", reopened)[0])
-    assert not inside("kueue.dispatch", inside("kueue.cycle", served)[0])
+        (host,) = inside("kueue.host_encode", cyc)
+        assert len(inside("kueue.tas_place", host)) == 1
+        (dispatch,) = inside("kueue.dispatch", cyc)
+        (wait,) = inside("kueue.device_wait", cyc)
+        (readback,) = inside("kueue.readback", cyc)
+        (decode,) = inside("kueue.verdict_decode", cyc)
+        assert host[2] <= dispatch[1] and dispatch[2] <= wait[1] \
+            and wait[2] <= readback[1] and readback[2] <= decode[1]

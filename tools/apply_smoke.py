@@ -1,18 +1,15 @@
 #!/usr/bin/env python
-"""apply-smoke: the end-to-end columnar-apply / pipelined-cycle check
-behind ``make apply-smoke``.
+"""apply-smoke: the end-to-end columnar-apply check behind ``make
+apply-smoke``.
 
-Four proofs over the cycle commit path (controllers/colapply.py,
-oracle/engine_bridge.py pipelined loop):
+Three proofs over the cycle commit path (controllers/colapply.py,
+oracle/engine_bridge.py):
 
-  * digest identity: every KUEUE_TPU_PIPELINE x KUEUE_TPU_COLUMNAR arm
-    drains the same churn world (priority preemption, requeues — both
-    the fast and the slow apply shapes) to byte-identical chained
-    decision digests and final admitted state;
-  * the pipeline really pipelines: the full arm must report speculative
-    encodes used (bridge.pipeline_stats), or the double-buffering is
-    silently disabled and the identity proof proves nothing;
-  * crash mid-apply (subprocess): a child draining with the pipeline on
+  * digest identity: both KUEUE_TPU_COLUMNAR arms drain the same churn
+    world (priority preemption, requeues — both the fast and the slow
+    apply shapes) to byte-identical chained decision digests and final
+    admitted state;
+  * crash mid-apply (subprocess): a child draining on the columnar path
     is SIGKILLed by the fault layer at the Nth admission — the ordinal
     counts bulk-path admissions — then rebuilt from its journal; the
     converged admitted set must equal an uninterrupted control's: zero
@@ -35,12 +32,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-ARMS = (
-    ("serial", "0", "0"),
-    ("columnar", "0", "1"),
-    ("pipelined", "1", "0"),
-    ("full", "1", "1"),
-)
+ARMS = (("serial", "0"), ("columnar", "1"))
 
 KILL_AT = 12
 STAGE_TIMEOUT = 180
@@ -140,10 +132,9 @@ def fingerprint(eng):
     return out, {k: v for k, v in usage.items() if v}
 
 
-def _digest_arm(pipeline: str, columnar: str):
+def _digest_arm(columnar: str):
     from kueue_tpu.replay.trace import canonical_decisions, decision_digest
 
-    os.environ["KUEUE_TPU_PIPELINE"] = pipeline
     os.environ["KUEUE_TPU_COLUMNAR"] = columnar
     eng = build_world()
     eng.attach_oracle()
@@ -162,11 +153,10 @@ def _digest_arm(pipeline: str, columnar: str):
     return eng, f"{state['digest']:08x}", state["cycles"]
 
 
-# -- child mode: drain the journalled world with the pipeline on until
+# -- child mode: drain the journalled world on the columnar path until
 # the armed fault kills us.
 
 def child_main(journal_path: str, spec: str) -> int:
-    os.environ["KUEUE_TPU_PIPELINE"] = "1"
     os.environ["KUEUE_TPU_COLUMNAR"] = "1"
     from kueue_tpu.replay.faults import arm_faults
 
@@ -209,7 +199,6 @@ def _crash_stage(label: str, spec: str, control_fp) -> int:
                 return fail(f"{label}: journal tail not torn")
     # Reboot from the journal (sequential path), re-drive the inputs
     # the child never submitted, converge, compare.
-    os.environ["KUEUE_TPU_PIPELINE"] = "0"
     os.environ["KUEUE_TPU_COLUMNAR"] = "0"
     rebuilt = rebuild_engine(path)
     if not rebuilt.workloads:
@@ -235,31 +224,24 @@ def main() -> int:
     if len(sys.argv) >= 2 and sys.argv[1] == "--child":
         return child_main(sys.argv[2], sys.argv[3])
 
-    # 1. Digest identity across every PIPELINE x COLUMNAR arm.
+    # 1. Digest identity across both COLUMNAR arms.
     results = {}
-    for label, pipeline, columnar in ARMS:
-        eng, digest, cycles = _digest_arm(pipeline, columnar)
+    for label, columnar in ARMS:
+        eng, digest, cycles = _digest_arm(columnar)
         if cycles == 0:
             return fail(f"{label}: no cycles ran")
-        results[label] = (digest, fingerprint(eng), eng)
-    base_digest, base_fp, _ = results["serial"]
-    for label, (digest, fp, _) in results.items():
+        results[label] = (digest, fingerprint(eng))
+    base_digest, base_fp = results["serial"]
+    for label, (digest, fp) in results.items():
         if digest != base_digest:
             return fail(f"digest drift: {label}={digest} "
                         f"serial={base_digest}")
         if fp != base_fp:
             return fail(f"final-state drift: {label} != serial")
-    print(f"digest identity OK (all {len(ARMS)} arms {base_digest})")
+    print(f"digest identity OK (both arms {base_digest})")
 
-    # 2. The full arm actually pipelined.
-    stats = results["full"][2].oracle.pipeline_stats
-    if stats.get("used", 0) == 0:
-        return fail(f"pipeline never used a speculative encode: {stats}")
-    print(f"pipeline OK (speculated={stats['speculated']} "
-          f"used={stats['used']} discarded={stats['discarded']})")
-
-    # 3/4. Crash recovery under the pipelined+columnar path. The
-    # control is the uninterrupted serial drain from stage 1.
+    # 2/3. Crash recovery under the columnar path. The control is the
+    # uninterrupted serial drain from stage 1.
     rc = _crash_stage("sigkill mid-apply",
                       f"sigkill@admission:{KILL_AT}", base_fp)
     if rc:
@@ -268,8 +250,8 @@ def main() -> int:
     if rc:
         return rc
 
-    print("apply-smoke OK: four-arm digest identity, live speculation, "
-          "and mid-apply crash recovery all validate")
+    print("apply-smoke OK: two-arm digest identity and mid-apply crash "
+          "recovery both validate")
     return 0
 
 
