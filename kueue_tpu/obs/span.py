@@ -144,6 +144,9 @@ AGGREGATE_KEYS = frozenset({"tas_place", "schedule_once", "encode",
 #       the fused preemptor's branch
 #   n_device_cycles, n_device_heads verdict_decode spans, and the heads
 #       the device decided in them (attr ``device_heads``)
+#   n_commit_victim_entries  the slots the fused preemptor gave a victim
+#       set (attr ``victim_entries``): at most that many steps of the
+#       commit's loop take the branch that removes victims
 #   n_sim_heads, n_sim_rows, n_sim_launches, n_sim_overflow
 #       ``sim_nomination`` spans' attrs: the heads whose flavor choice
 #       needed preemption simulations, the (head, flavor, resource)
@@ -151,6 +154,7 @@ AGGREGATE_KEYS = frozenset({"tas_place", "schedule_once", "encode",
 #       sim program handed to the host (more candidates than it scans)
 COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches",
                         "n_device_cycles", "n_device_heads",
+                        "n_commit_victim_entries",
                         "n_sim_heads", "n_sim_rows", "n_sim_launches",
                         "n_sim_overflow"})
 
@@ -319,6 +323,9 @@ def _cycle_aggregates(cycle: Span, out: dict) -> None:
             _add(out, "n_device_cycles", 1)
             if "device_heads" in c.attrs:
                 _add(out, "n_device_heads", c.attrs["device_heads"])
+            if "victim_entries" in c.attrs:
+                _add(out, "n_commit_victim_entries",
+                     c.attrs["victim_entries"])
 
 
 def close_phases(phases: dict, root: Span) -> None:

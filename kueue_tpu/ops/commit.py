@@ -30,9 +30,7 @@ ENTRY_SKIP = 0  # never commits (NoFit / ineligible slot)
 ENTRY_FIT = 1  # commits if it still fits against evolving usage
 ENTRY_RESERVE = 2  # preempt-mode w/o candidates: reserve capacity
 #   (scheduler.go:499 reserveCapacityForUnreclaimablePreempt)
-ENTRY_FORCE = 3  # adds full usage unconditionally (replay of a decided
-#   admission, e.g. the reservation-free second pass)
-ENTRY_PREEMPT = 4  # preempt-mode with device-selected targets: fit is
+ENTRY_PREEMPT = 3  # preempt-mode with device-selected targets: fit is
 #   checked with the entry's victims removed (scheduler.go:680 fits with
 #   preemption targets); on success the removal persists in the carry
 #   (victim usage is gone for later entries, like preempted_workloads)
@@ -66,7 +64,7 @@ def _entry_verdict(g_sq, g_lq, g_bl, g_usage, chain_ok, frs, req, kind,
         0, jnp.minimum(req, sat_sub(cq_nom, cq_usage_now)))
     reserve_req = jnp.where(borrows_k > 0, borrowing_amt, nominal_amt)
 
-    do_add = fits | (kind == ENTRY_RESERVE) | (kind == ENTRY_FORCE)
+    do_add = fits | (kind == ENTRY_RESERVE)
     v = jnp.where(kind == ENTRY_RESERVE, reserve_req, req)
     v = jnp.where(active & do_add, v, 0)  # [S]
 
@@ -133,24 +131,35 @@ def commit_scan(
 
 def _apply_victims(usage_l, lq_l, parent_local, rows, vals, *, depth):
     """Aggregated victim-usage removal (resource_node.go:156 removeUsage)
-    over a root-local node set: scatter victim usage at their CQ rows,
-    then propagate each row's above-local-quota share to its parent,
-    level by level. Exact vs sequential per-victim removal: headroom
-    consumption is monotone, so min-sum aggregation per row equals the
-    per-victim walk.
+    over a root-local node set: take the victims' usage off their CQ
+    rows, then propagate each row's above-local-quota share to its
+    parent, level by level. Exact vs sequential per-victim removal:
+    headroom consumption is monotone, so min-sum aggregation per row
+    equals the per-victim walk.
+
+    Only the victims' own chains are touched: V rows a level, gathered
+    and scattered, victims that meet in a row summed there and carried
+    on by the first of them.
 
     usage_l, lq_l: int64[K, R]; parent_local: int32[K]; rows: int32[V]
     victim CQ positions (-1 = none); vals: int64[V, R]."""
     K = usage_l.shape[0]
-    rem = jnp.zeros_like(usage_l).at[
-        jnp.where(rows >= 0, rows, K)].add(vals, mode="drop")
-    p_safe = jnp.where(parent_local >= 0, parent_local, K)
+    first_of = jnp.arange(rows.shape[0])
+    row = jnp.where(rows >= 0, rows, K)  # K = nothing left to remove
+    rem = jnp.where((rows >= 0)[:, None], vals, 0)
     for _ in range(depth + 1):
-        prop = jnp.minimum(rem, jnp.maximum(0, usage_l - lq_l))
+        same = row[:, None] == row[None, :]  # [V, V]
+        total = jnp.sum(jnp.where(same[:, :, None], rem[None], 0), axis=1)
+        r_safe = jnp.minimum(row, K - 1)
+        prop = jnp.minimum(total,
+                           jnp.maximum(0, usage_l[r_safe] - lq_l[r_safe]))
         prop = jnp.maximum(prop, 0)
-        usage_l = usage_l - rem
-        rem = jnp.zeros_like(rem).at[p_safe].add(
-            jnp.where((parent_local >= 0)[:, None], prop, 0), mode="drop")
+        usage_l = usage_l.at[row].add(-rem, mode="drop")
+        parent = parent_local[r_safe]
+        carries = (row < K) & (parent >= 0) \
+            & (jnp.argmax(same, axis=1) == first_of)
+        row = jnp.where(carries, parent, K)
+        rem = jnp.where(carries[:, None], prop, 0)
     return usage_l
 
 
@@ -252,11 +261,11 @@ def commit_grouped(
 
     Admissions never interact across roots (all quota math — borrowing,
     lending, usage bubbling — stays under the entry's root cohort), so the
-    reference's one-at-a-time commit order is reproduced exactly by
-    scanning each root's entries in global key order, vmapped over roots.
-    Scan length drops from C (all slots) to max-CQs-per-root — the
-    difference between a 1000-step and an ~8-step sequential section per
-    cycle on TPU.
+    reference's one-at-a-time commit order is reproduced exactly by one
+    scan over the positions of the roots' entries in global key order,
+    each step committing that position of every root at once. Scan length
+    drops from C (all slots) to max-CQs-per-root — the difference between
+    a 1000-step and an ~8-step sequential section per cycle on TPU.
 
     slot_victim_* carry device-selected preemption victims for
     ENTRY_PREEMPT slots (ops/preempt.classical_targets_impl output): the
@@ -295,24 +304,46 @@ def commit_grouped(
         lq_locals = jnp.zeros((Rn, 1, 1), lq.dtype)
         root_parent_local = jnp.full((Rn, K), -1, jnp.int32)
 
-    def per_root(members, local_usage, lq_l, parent_local):
-        def step(carry, c):  # usage_l: [K, R]
-            usage_l, claimed = carry
+    def commit_all(usage_ls, claimeds, cs, *, with_victims):
+        """One position of every root at once: cs int32[Rn]."""
+        def one_root(usage_l, claimed, c, lq_l, parent_local):
             victims = ((slot_victim_row, slot_victim_vals,
                         slot_victim_ids, lq_l, parent_local)
-                       if has_victims else None)
-            usage_l, claimed, fits = _commit_one_local(
+                       if with_victims else None)
+            return _commit_one_local(
                 usage_l, c, entry_fr, entry_req, entry_kind, entry_borrows,
                 subtree_quota, lq, borrow_limit, nominal, ancestors,
                 local_chain, depth=depth, victims=victims, claimed=claimed)
-            return (usage_l, claimed), fits
 
-        (usage_f, _), fits_seq = jax.lax.scan(
-            step, (local_usage, claimed0), members)
-        return usage_f, fits_seq
+        return jax.vmap(one_root)(usage_ls, claimeds, cs, lq_locals,
+                                  root_parent_local)
 
-    final_local, admitted_seq = jax.vmap(per_root)(
-        sorted_members, init_local, lq_locals, root_parent_local)
+    # The sequential section does only what depends on the carry: a
+    # position at which no root's entry preempts takes the branch without
+    # the victim removal, the claimed-victims lookup and its update (for
+    # every other kind they are the identity) — a real branch, the scan
+    # being over positions with the roots vmapped inside the step.
+    preempts_at = jnp.any(
+        (sorted_members >= 0)
+        & (entry_kind[jnp.maximum(sorted_members, 0)] == ENTRY_PREEMPT),
+        axis=0)  # [M]
+
+    def step(carry, position):  # usage_ls [Rn, K, R], claimeds [Rn, A]
+        cs, some_root_preempts = position
+        if not has_victims:
+            usage_ls, claimeds, fits = commit_all(*carry, cs,
+                                                  with_victims=False)
+        else:
+            usage_ls, claimeds, fits = jax.lax.cond(
+                some_root_preempts,
+                partial(commit_all, with_victims=True),
+                partial(commit_all, with_victims=False), *carry, cs)
+        return (usage_ls, claimeds), fits
+
+    claimeds0 = jnp.broadcast_to(claimed0, (Rn,) + claimed0.shape)
+    (final_local, _), admitted_seq = jax.lax.scan(
+        step, (init_local, claimeds0), (sorted_members.T, preempts_at))
+    admitted_seq = admitted_seq.T  # [Rn, M], as sorted_members
 
     # Scatter per-root verdicts back to slot order.
     flat_members = sorted_members.reshape(-1)

@@ -356,7 +356,6 @@ def _cycle_core(
             # Positions: tournament round within the root (rounds are the
             # reference's pop order; roots are independent).
             slot_position = jnp.maximum(slot_round, 0)
-            key = slot_round.astype(jnp.int64)  # replay order for usage_clean
         else:
             # 4. Commit order (scheduler.go:971).
             key = cops.make_commit_order_key(
@@ -405,15 +404,15 @@ def _cycle_core(
     new_inadmissible = inadmissible | (wl_parked & new_pending)
 
     # Reservations are cycle-local (snapshot-local in the reference):
-    # recompute post-cycle usage from admissions only.
-    committed_kind = jnp.where(slot_admitted, cops.ENTRY_FORCE,
-                               cops.ENTRY_SKIP)
+    # post-cycle usage holds admissions only. Bubbling consumes headroom
+    # monotonically, so the order they were added in does not matter:
+    # the bottom-up aggregation of step 1, over the CQ rows with each
+    # admitted slot's request added, is what replaying them gives.
     with jax.named_scope("kueue.commit"):
-        _, usage_clean = cops.commit_grouped(
-            key, slot_valid, entry_fr_d, req_fr, committed_kind, borrows,
-            full_usage, derived["subtree_quota"], lend_limit, borrow_limit,
-            nominal, ancestors, root_members, root_nodes, local_chain,
-            depth=depth)
+        admitted_req = jnp.where(slot_admitted[:, None], req_fr, 0)
+        usage_clean = qops.compute_node_usage(
+            cq_usage.at[:C].add(admitted_req), derived["subtree_quota"],
+            lend_limit, parent, derived["level"], depth=depth)
 
     any_needs_oracle = jnp.any(slot_oracle)
     return (new_pending, new_inadmissible, usage_clean, wl_admitted,

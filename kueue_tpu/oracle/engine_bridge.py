@@ -1227,6 +1227,9 @@ class OracleBridge:
         vids = np.asarray(victim_ids)
         by_id = np.argsort(vids, axis=1)
         vids = np.take_along_axis(vids, by_id, axis=1)
+        # Slots with a selected victim set.
+        found_any = (vids >= 0).any(axis=1)
+        decode.attrs["victim_entries"] = int(np.count_nonzero(found_any))
 
         if fused:
             overflow = np.asarray(slot_overflow) & cq_on_device
@@ -1238,11 +1241,10 @@ class OracleBridge:
             # Host-side Target lists for the preempting slots, from the
             # in-program victim selection.
             sp = np.asarray(slot_preempting)
-            # Slots with a selected victim set: committed ones become
+            # Of the slots with a victim set, committed ones become
             # PREEMPTING entries; uncommitted ones (capacity claimed by
             # an earlier entry) are the reference's skipped preemptions
             # and are counted by _apply.
-            found_any = (vids >= 0).any(axis=1)
             if (sp | found_any).any():
                 vvar = np.take_along_axis(np.asarray(victim_variant),
                                           by_id, axis=1)

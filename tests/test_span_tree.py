@@ -223,7 +223,7 @@ def test_device_cycle_tree():
     assert names(host) == ["tas_place"]
     assert host.attrs == {"heads": 1, "pending": 2}
     assert child(cyc, "verdict_decode").attrs == {
-        "lattice": False, "device_heads": 1}
+        "lattice": False, "device_heads": 1, "victim_entries": 0}
     # The bridge's upload counts this cycle's tensors; the executor had
     # nothing left to convert; the verdicts came back as bytes.
     assert [c.attrs["bytes"] > 0 for c in cyc.children
@@ -310,7 +310,7 @@ def test_phase_keys_are_sums_over_everything_that_ran():
     # seconds up takes them in.
     assert {k: ph[k] for k in COUNT_KEYS if k in ph} == {
         "n_launches": 1, "n_lattice_launches": 0, "n_device_cycles": 1,
-        "n_device_heads": 1}
+        "n_device_heads": 1, "n_commit_victim_entries": 0}
     assert not COUNT_KEYS & set(leaf_phases(ph))
     # The histogram takes the leaves and the whole, no aggregate.
     h = eng.registry.histogram("scheduler_phase_duration_seconds")
@@ -439,6 +439,8 @@ def test_lattice_attr_is_the_cycle_programs_own_predicate():
     assert total("n_launches") == len(truth)
     assert total("n_lattice_launches") == sum(t for t, _ in truth)
     assert total("n_device_cycles") == 5 and total("n_device_heads") >= 5
+    # mid-a's is the one victim set: mid-b's preemptor found too few.
+    assert total("n_commit_victim_entries") == 1
 
 
 @pytest.mark.parametrize("world", [

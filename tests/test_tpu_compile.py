@@ -122,3 +122,41 @@ def test_flat_cycle_program_compiles_as_the_bridge_calls_it(
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 16 * 2**30  # one v5e chip's HBM
+
+
+def test_commit_loop_compiles_to_one_while_that_branches(one_chip):
+    """commit_grouped at the first benchmark cell's shapes (one cohort of
+    1,000 ClusterQueues, 8,192 running, depth 4): one sequential loop,
+    and in its body the conditional that keeps the K-row victim removal
+    out of the steps whose entries preempt nothing. The chip compiler's
+    text names the ops as a device trace does, which is how the loop's
+    cost was placed (PERF.md, PR 33)."""
+    import re
+
+    from kueue_tpu.ops import commit as cops
+
+    C, K, V, A, D, R, Rn = 1_000, 1_001, 32, 8_192, 4, 1, 1
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    quota = arg(np.int64, K, R)
+    compiled = cops.commit_grouped.lower(
+        entry_key=arg(np.int64, C), entry_valid=arg(np.bool_, C),
+        entry_fr=arg(np.int32, C, R), entry_req=arg(np.int64, C, R),
+        entry_kind=arg(np.int32, C), entry_borrows=arg(np.int32, C),
+        usage0=quota, subtree_quota=quota, lend_limit=quota,
+        borrow_limit=quota, nominal=quota, ancestors=arg(np.int32, K, D),
+        root_members=arg(np.int32, Rn, C), root_nodes=arg(np.int32, Rn, K),
+        local_chain=arg(np.int32, C, D + 1),
+        root_parent_local=arg(np.int32, Rn, K),
+        slot_victim_row=arg(np.int32, C, V),
+        slot_victim_vals=arg(np.int64, C, V, R),
+        slot_victim_ids=arg(np.int32, C, V), claimed0=arg(np.bool_, A),
+        depth=D).compile()
+    text = compiled.as_text()
+    (body,) = re.findall(r" while\(.*\bbody=%([\w.\-]+)", text)
+    computation = re.search(
+        r"^%" + re.escape(body) + r" \(.*?^\}", text, re.M | re.S).group(0)
+    assert computation.count(" conditional(") == 1
+    assert text.count(" conditional(") == 1
