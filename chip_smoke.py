@@ -593,16 +593,14 @@ def run_local(args, sizes, device) -> list:
     emit(results[-1])
     del ref
     n_pending, n_cohorts = sizes["preempt"]
-    # Not by cycle: on this world the two paths pick different victims
-    # in different cycles — on the CPU as well — and at this seed end in
-    # the same admissions; at others they do not (ROADMAP A0; PERF.md
-    # section 7). tests/test_preempt_churn.py compares this shape by
-    # outcome too.
+    # By cycle since PR 34: the two paths parted where a cohort's entry
+    # was skipped for a target it shared with an earlier preemptor and
+    # the device's commit booked its usage all the same.
     results.append(engine_pair_phase(
         "preempt",
         lambda seed, oracle: bench.preempt_churn_engine(
             n_pending, n_cohorts=n_cohorts, seed=seed, oracle=oracle),
-        args.seed + 7, clog, by_cycle=False))
+        args.seed + 7, clog, by_cycle=True))
     emit(results[-1])
     results.append(phase_fair(sizes, args.seed + 1, clog))
     emit(results[-1])

@@ -6,10 +6,14 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
 
     schedule_once                 controllers/engine.py — attrs seq, mode
     ├─ pre_hooks
-    ├─ cycle                      oracle bridge: try_cycle; attrs
-    │  │                          lattice, if it launched (whether the
-    │  │                          launch took the preemptor's branch:
-    │  │                          the cycle program's own output)
+    ├─ cycle                      oracle bridge: try_cycle; attrs, if
+    │  │                          it launched, from the cycle program's
+    │  │                          own output: preempt_slots (the slots
+    │  │                          its preemptor was run for),
+    │  │                          preempt_skipped (ordered candidates
+    │  │                          the preemptor's scans passed over as
+    │  │                          invalid), lattice (preempt_slots > 0:
+    │  │                          the launch took the preemptor's branch)
     │  ├─ host_encode             _encode_cycle up to the device cycle
     │  │  └─ tas_place            (attrs heads, pending)
     │  ├─ sim_nomination          multi-flavor groups on preempting CQs
@@ -28,7 +32,10 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     │  ├─ device_wait             block_until_ready on the outputs
     │  ├─ readback                np.asarray of the outputs (attrs bytes)
     │  ├─ verdict_decode          attrs lattice (the branch of the
-    │  │                          launch that served it), device_heads
+    │  │                          launch that served it), device_heads,
+    │  │                          victim_entries, reclaim_victims (the
+    │  │                          committed victims of another
+    │  │                          ClusterQueue than their preemptor's)
     │  ├─ apply · finalize
     │  └─ host_tail               hybrid cycles only
     ├─ snapshot · decide · apply  sequential path (no bridge; fallback)
@@ -142,6 +149,9 @@ AGGREGATE_KEYS = frozenset({"tas_place", "schedule_once", "encode",
 #   n_launches, n_lattice_launches  ``cycle`` spans that launched the
 #       cycle program (attr ``lattice``), and those whose launch took
 #       the fused preemptor's branch
+#   n_preempt_slots, n_preempt_skipped  the same spans' attrs
+#       ``preempt_slots`` and ``preempt_skipped``
+#   n_reclaim_victims  verdict_decode's attr ``reclaim_victims``
 #   n_device_cycles, n_device_heads verdict_decode spans, and the heads
 #       the device decided in them (attr ``device_heads``)
 #   n_commit_victim_entries  the slots the fused preemptor gave a victim
@@ -153,8 +163,9 @@ AGGREGATE_KEYS = frozenset({"tas_place", "schedule_once", "encode",
 #       cells simulated, the sim program's launches, and the heads the
 #       sim program handed to the host (more candidates than it scans)
 COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches",
+                        "n_preempt_slots", "n_preempt_skipped",
                         "n_device_cycles", "n_device_heads",
-                        "n_commit_victim_entries",
+                        "n_commit_victim_entries", "n_reclaim_victims",
                         "n_sim_heads", "n_sim_rows", "n_sim_launches",
                         "n_sim_overflow"})
 
@@ -281,13 +292,15 @@ def phase_seconds(root: Span) -> dict:
     and the counts of COUNT_KEYS. ``close_phases`` adds what only the closed
     root knows."""
     out: dict = {}
-    launches = lattice = 0
+    launches = lattice = slots = skipped = 0
     boxes = [root]
     while boxes:
         box = boxes.pop()
         if "lattice" in box.attrs:  # this container launched
             launches += 1
             lattice += box.attrs["lattice"]
+            slots += box.attrs.get("preempt_slots", 0)
+            skipped += box.attrs.get("preempt_skipped", 0)
         for c in box.children:
             if c.name in CONTAINERS:
                 boxes.append(c)
@@ -305,6 +318,8 @@ def phase_seconds(root: Span) -> dict:
     if launches:
         out["n_launches"] = launches
         out["n_lattice_launches"] = lattice
+        out["n_preempt_slots"] = slots
+        out["n_preempt_skipped"] = skipped
     return out
 
 
@@ -326,6 +341,9 @@ def _cycle_aggregates(cycle: Span, out: dict) -> None:
             if "victim_entries" in c.attrs:
                 _add(out, "n_commit_victim_entries",
                      c.attrs["victim_entries"])
+            if "reclaim_victims" in c.attrs:
+                _add(out, "n_reclaim_victims",
+                     c.attrs["reclaim_victims"])
 
 
 def close_phases(phases: dict, root: Span) -> None:

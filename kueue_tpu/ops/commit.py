@@ -210,6 +210,10 @@ def _commit_one_local(usage_l, c, entry_fr, entry_req, entry_kind,
         A = claimed.shape[0]
         overlap = is_pre & jnp.any(
             (ids >= 0) & claimed[jnp.clip(ids, 0, A - 1)])
+        # An entry whose targets overlap an earlier entry's is skipped
+        # before its fit is looked at (scheduler.go:432): it adds no
+        # usage either.
+        kind = jnp.where(overlap, ENTRY_SKIP, kind)
     else:
         trial = usage_l
 
@@ -218,7 +222,6 @@ def _commit_one_local(usage_l, c, entry_fr, entry_req, entry_kind,
         g_sq, g_lq, g_bl, g_usage, chain_ok, frs, req, kind,
         entry_borrows[c_safe], nominal[c_safe, frs_safe],
         borrow_limit[c_safe, frs_safe], g_usage[0], depth=depth)
-    fits = fits & ~overlap
 
     # ENTRY_PREEMPT: the victim removal persists only when the entry
     # commits; otherwise the carry is untouched. `adds` is already masked

@@ -28,6 +28,7 @@ tests/test_preempt_device.py.
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -211,6 +212,35 @@ def within_cq_targets(
         slot_fr, slot_req, wcq_policy)
 
 
+class _Window(NamedTuple):
+    """What the greedy scan reads of V ordered candidates of one slot."""
+    cand: jax.Array  # bool[V] a candidate sits here
+    variant: jax.Array  # int32[V] V_*
+    same: jax.Array  # bool[V] of the preemptor's own ClusterQueue
+    loc: jax.Array  # int32[V, D+1] its chain, root-local rows
+    lca_pos: jax.Array  # int32[V] where its chain meets the preemptor's
+    usage: jax.Array  # int64[V, S] what it holds of the slot's columns
+
+
+def next_window(key, open_, cursor, width: int):
+    """The next ``width`` candidates of one slot's order: the local ids
+    (int32[width], -1 once none is left) of the lowest ``key``s above
+    ``cursor`` among the rows of ``open_``. ``key`` int64[A_l] is the
+    slot's candidate order (unique among candidates), ``open_`` bool[A_l]
+    the candidates an attempt may still take. ``width`` masked argmins:
+    no sort, no gather."""
+    big = jnp.iinfo(key.dtype).max
+
+    def pick(cur, _):
+        k = jnp.where(open_ & (key > cur), key, big)
+        a = jnp.argmin(k).astype(jnp.int32)
+        has = k[a] < big
+        return jnp.where(has, k[a], cur), jnp.where(has, a, -1)
+
+    _, ids = jax.lax.scan(pick, cursor, None, length=width)
+    return ids
+
+
 def classical_targets_impl(
     slot_need,  # bool[C] head needs preemption on this slot
     slot_pri,  # int64[C] preemptor effective priority
@@ -242,9 +272,10 @@ def classical_targets_impl(
     #   sim-augmented nomination paid one launch per cell before).
     adm_rank=None,  # int64[A] OPTIONAL precomputed rank of the slot-
     #   independent ordering tail (priority asc, reservation recency
-    #   desc, uid asc — common/ordering.go:42). When provided, candidate
-    #   ordering is ONE composite-key argsort per slot instead of a
-    #   6-key lexsort (the dominant kernel cost at large admitted sets).
+    #   desc, uid asc — common/ordering.go:42); worked out here where it
+    #   is not given. Candidate ordering is ONE composite-key argsort
+    #   per slot over it (a 6-key lexsort was the dominant kernel cost
+    #   at large admitted sets).
     adm_by_root=None,  # int32[Rn, A_l] OPTIONAL admitted ids grouped by
     #   cohort root (-1 pad): per-slot candidate work shrinks from O(A)
     #   to O(max admitted per root). Victim ids in the outputs stay
@@ -267,12 +298,21 @@ def classical_targets_impl(
     candidate_generator.go:136), then fill back spared victims
     (preemption.go:334).
 
-    The greedy scan is bounded at v_cap ordered candidates; slots that
-    fail to fit with more candidates available report overflow=True and
-    must fall back to the host preemptor.
+    The greedy scan finds its targets wherever they lie in the order:
+    the first V = min(v_cap, A_l) ordered candidates are walked as one
+    window; a slot that has not fit by then while candidates are left
+    walks on, window by window (next_window: the next V that the attempt
+    may take and that are still valid), holding its targets as a mask
+    over the candidates, then gives back in reverse order and packs what
+    is left. Where no slot has more than V candidates no launch enters
+    that region. `overflow` means one thing: the targets that remain
+    after fill-back are more than V, which the packed columns cannot
+    hold; such a slot is reported and must fall back to the host
+    preemptor.
 
-    Returns per slot, the victims packed to V = min(v_cap, A_l) columns
-    (the scanned candidates, in candidate order):
+    Returns per slot, the victims packed to V columns (the targets in
+    candidate order; for a slot decided in its first window, that
+    window's candidates with `taken` marking the targets):
       found bool[C], overflow bool[C], n_targets int32[C],
       borrow_after int32[C] — the assignment borrow level with the
         victims removed (preemption_oracle.go:41 SimulatePreemption →
@@ -282,7 +322,9 @@ def classical_targets_impl(
         (-1 on an ``adm_by_root`` pad row),
       taken bool[C, V] — which of them are the targets,
       v_variant int32[C, V] — each one's candidate variant (V_*, for
-        the preemption reason; 0 on a pad row).
+        the preemption reason; 0 on a pad row),
+      skipped int32[C] — the ordered candidates the scans passed over
+        as invalid before the slot fit or ran out (both attempts).
     """
     C, S = slot_req.shape
     A = adm_cq.shape[0]
@@ -290,11 +332,18 @@ def classical_targets_impl(
     V = min(v_cap, A_l)
     K = root_nodes.shape[1]
     lq_all = local_quota(subtree_quota, lend_limit)
+    if adm_rank is None:
+        adm_rank = jnp.zeros((A,), jnp.int64).at[
+            jnp.lexsort((adm_uid, -adm_qrt, adm_pri))].set(
+            jnp.arange(A, dtype=jnp.int64))
 
     adm_chain = jnp.concatenate(
         [adm_cq[:, None], ancestors[jnp.maximum(adm_cq, 0)]],
         axis=1)  # [A, D+1] global node ids
     adm_loc = local_chain[jnp.maximum(adm_cq, 0)]  # [A, D+1]
+    # Level e of a candidate's chain can lie strictly below its LCA with
+    # the preemptor only where some chain of the world has a level e + 1.
+    levels_below_an_lca = jnp.any(adm_loc[:, 1:] >= 0, axis=0)  # [D]
 
     def per_slot(c, need, p_pri, p_ts, frs, req):
         frs_safe = jnp.maximum(frs, 0)
@@ -307,7 +356,7 @@ def classical_targets_impl(
         if adm_by_root is None:
             l_ok = jnp.ones((A,), bool)
             l_cq, l_pri, l_ts = adm_cq, adm_pri, adm_ts
-            l_qrt, l_uid, l_ev = adm_qrt, adm_uid, adm_evicted
+            l_ev = adm_evicted
             l_usage = adm_usage
             l_chain, l_loc = adm_chain, adm_loc
             l_rank = adm_rank
@@ -319,16 +368,13 @@ def classical_targets_impl(
             l_cq = jnp.where(l_ok, adm_cq[rsafe], -1)
             l_pri = adm_pri[rsafe]
             l_ts = adm_ts[rsafe]
-            l_qrt = adm_qrt[rsafe]
-            l_uid = adm_uid[rsafe]
             l_ev = adm_evicted[rsafe] & l_ok
             l_usage = jnp.where(l_ok[:, None], adm_usage[rsafe], 0)
             l_chain = jnp.where(l_ok[:, None], adm_chain[rsafe], -1)
             l_loc = jnp.where(l_ok[:, None], adm_loc[rsafe], -1)
             # Pad rows sort last; ties among pads are irrelevant (they
             # can never be candidates).
-            l_rank = (None if adm_rank is None
-                      else jnp.where(l_ok, adm_rank[rsafe], A))
+            l_rank = jnp.where(l_ok, adm_rank[rsafe], A)
 
         # Root-local state over the slot's root, columns = the slot's
         # chosen flavor-resources.
@@ -437,19 +483,28 @@ def classical_targets_impl(
                                 jnp.int32(V_RECLAIM_WITHOUT_BORROWING),
                                 jnp.int32(V_RECLAIM_WHILE_BORROWING))))
 
-        # Static within-nominal pruning (collectCandidatesInSubtree +
-        # candidateIsValid at cycle start): every node on the candidate's
-        # chain strictly below the LCA must be above nominal in some
-        # needed resource. Level-wise loop keeps peak memory at O(A * S).
-        wn_rownominal = jnp.all(jnp.where(
-            need_fr[None, :], sq_l >= usage_l0, True), axis=1)  # [K]
-        static_bad = jnp.zeros((A_l,), bool)
-        for e in range(depth + 1):
-            loc_e = l_loc[:, e]
-            below = (e < lca_pos) & (loc_e >= 0)
-            static_bad = static_bad | (
-                below & wn_rownominal[jnp.maximum(loc_e, 0)])
-        static_path_ok = ~static_bad
+        # Within-nominal pruning (collectCandidatesInSubtree +
+        # candidateIsValid): every node on the candidate's chain strictly
+        # below the LCA must be above nominal in some needed resource.
+        # Level-wise loop keeps peak memory at O(A * S).
+        def path_within_nominal(usage_l):
+            """A level that no chain of the world reaches above is not
+            looked at: the predicate is the launch's, not the slot's,
+            so the branch is a real one under the vmap."""
+            wn_row = jnp.all(jnp.where(
+                need_fr[None, :], sq_l >= usage_l, True), axis=1)  # [K]
+            bad = jnp.zeros((A_l,), bool)
+            for e in range(depth):  # level `depth` is never below an LCA
+                def at_level(bad, e=e):
+                    loc_e = l_loc[:, e]
+                    below = (e < lca_pos) & (loc_e >= 0)
+                    return bad | (below & wn_row[jnp.maximum(loc_e, 0)])
+
+                bad = jax.lax.cond(levels_below_an_lca[e], at_level,
+                                   lambda bad: bad, bad)
+            return bad
+
+        static_path_ok = ~path_within_nominal(usage_l0)
 
         is_cand = (any_need & uses_any & pol_gate & pol_ok
                    & (same_cq | (same_root & has_lca & static_path_ok)))
@@ -468,34 +523,26 @@ def classical_targets_impl(
         en2 = ~case1
 
         # Ordering: evicted first, bucket, priority asc, reservation
-        # recency desc, uid asc; non-candidates last (lexsort: last key
-        # is primary).
-        if l_rank is None:
-            order = jnp.lexsort((
-                l_uid,
-                -l_qrt,
-                l_pri,
-                bucket,
-                jnp.where(l_ev, 0, 1),
-                jnp.where(is_cand, 0, 1),
-            )).astype(jnp.int32)
-        else:
-            # Composite key: only is_cand and bucket vary per slot; the
-            # rest is the precomputed rank. Rank uniqueness makes the
-            # order total — one argsort, no ties.
-            lvl = (jnp.where(is_cand, 0, 2)
-                   + jnp.where(l_ev, 0, 1)) * 4 + bucket
-            order = jnp.argsort(
-                lvl.astype(jnp.int64) * (A + 1) + l_rank
-            ).astype(jnp.int32)
-        v_ids = order[:V]  # [V]
-        v_cand = is_cand[v_ids]
-        v_variant = variant[v_ids]
-        v_same = same_cq[v_ids]
-        v_loc = l_loc[v_ids]  # [V, D+1]
-        v_lca_pos = lca_pos[v_ids]
-        v_usage = l_usage[v_ids][:, frs_safe]  # [V, S]
+        # recency desc, uid asc; non-candidates last. Only is_cand and
+        # bucket vary per slot; the rest is the precomputed rank, whose
+        # uniqueness makes the composite key a total order — one
+        # argsort, no ties — and lets a later window be found by key
+        # alone (next_window).
+        lvl = (jnp.where(is_cand, 0, 2)
+               + jnp.where(l_ev, 0, 1)) * 4 + bucket
+        key = lvl.astype(jnp.int64) * (A + 1) + l_rank
+        order = jnp.argsort(key).astype(jnp.int32)
         n_cand = jnp.sum(is_cand.astype(jnp.int32))
+
+        def window(ids):
+            """V candidates by local id (-1: none)."""
+            at = jnp.maximum(ids, 0)
+            return _Window(is_cand[at] & (ids >= 0), variant[at],
+                           same_cq[at], l_loc[at], lca_pos[at],
+                           l_usage[at][:, frs_safe])
+
+        v_ids = order[:V]  # [V]
+        first = window(v_ids)
 
         def remove_chain(usage_l, loc, val):
             """resource_node.go:156 removeUsage along one chain."""
@@ -518,51 +565,167 @@ def classical_targets_impl(
                 val = jnp.where(row_ok, jnp.maximum(0, val - la), 0)
             return usage_l
 
-        def run_attempt(allow_borrow):
+        def scan_window(win, usage_l, found, allow_borrow):
+            """The greedy over one window of V ordered candidates: every
+            one still valid is taken until the preemptor fits. Returns
+            the usage, which were taken, found, how many candidates
+            were passed over as invalid, and the last position looked at
+            (-1: none)."""
             def step(carry, i):
-                usage_l, taken, found = carry
-                ok = v_cand[i] & ~found
+                usage_l, taken, found, skipped, last = carry
+                ok = win.cand[i] & ~found
                 # candidateIsValid (candidate_generator.go:136), dynamic.
                 bad_borrow = (allow_borrow
-                              & (v_variant[i]
+                              & (win.variant[i]
                                  == V_RECLAIM_WITHOUT_BORROWING)
-                              & ~v_same[i])
+                              & ~win.same[i])
                 wn_bad = jnp.asarray(False)
                 for e in range(depth + 1):
-                    below = (e < v_lca_pos[i]) & (v_loc[i, e] >= 0)
-                    r = jnp.maximum(v_loc[i, e], 0)
+                    below = (e < win.lca_pos[i]) & (win.loc[i, e] >= 0)
+                    r = jnp.maximum(win.loc[i, e], 0)
                     wn = jnp.all(jnp.where(need_fr,
                                            sq_l[r] >= usage_l[r], True))
                     wn_bad = wn_bad | (below & wn)
-                valid = ok & ~bad_borrow & (v_same[i] | ~wn_bad)
-                removed = remove_chain(usage_l, v_loc[i], v_usage[i])
+                valid = ok & ~bad_borrow & (win.same[i] | ~wn_bad)
+                removed = remove_chain(usage_l, win.loc[i], win.usage[i])
                 usage_l = jnp.where(valid, removed, usage_l)
                 taken = taken.at[i].set(valid)
                 fit = fits_with(usage_l, allow_borrow)
                 found = found | (valid & fit)
-                return (usage_l, taken, found), None
+                return (usage_l, taken, found,
+                        skipped + (ok & ~valid).astype(jnp.int32),
+                        jnp.where(ok, i.astype(jnp.int32), last)), None
 
-            init = (usage_l0, jnp.zeros((V,), bool), jnp.asarray(False))
-            (usage_f, taken, found), _ = jax.lax.scan(
-                step, init, jnp.arange(V))
+            init = (usage_l, jnp.zeros((V,), bool), found,
+                    jnp.int32(0), jnp.int32(-1))
+            return jax.lax.scan(step, init, jnp.arange(V))[0]
+
+        def fill_back(usage_l, taken, i, loc, val, consider, allow_borrow):
+            """preemption.go:334, one target: given back where the
+            preemptor still fits with it."""
+            trial = add_chain(usage_l, loc, val)
+            spared = consider & taken[i] & fits_with(trial, allow_borrow)
+            return (jnp.where(spared, trial, usage_l),
+                    taken.at[i].set(taken[i] & ~spared))
+
+        def first_window(allow_borrow):
+            """An attempt over the first V of the order: (usage, ids,
+            variants, taken, found, skipped, targets held)."""
+            usage_f, taken, found, skipped, _ = scan_window(
+                first, usage_l0, jnp.asarray(False), allow_borrow)
 
             # Fill-back (preemption.go:334): reverse over targets except
             # the last, re-adding any whose re-addition keeps the fit.
             last_idx = jnp.max(jnp.where(taken, jnp.arange(V), -1))
 
             def fb(carry, j):
-                usage_l, taken = carry
                 i = V - 1 - j
-                consider = found & taken[i] & (i != last_idx)
-                trial = add_chain(usage_l, v_loc[i], v_usage[i])
-                spared = consider & fits_with(trial, allow_borrow)
-                usage_l = jnp.where(spared, trial, usage_l)
-                taken = taken.at[i].set(taken[i] & ~spared)
-                return (usage_l, taken), None
+                return fill_back(*carry, i, first.loc[i], first.usage[i],
+                                 found & (i != last_idx),
+                                 allow_borrow), None
 
             (usage_fb, taken_fb), _ = jax.lax.scan(fb, (usage_f, taken),
                                                    jnp.arange(V))
-            return found, taken_fb, usage_fb
+            return (usage_fb, v_ids, first.variant, taken_fb, found,
+                    skipped, jnp.int32(0))
+
+        def beyond_first_window(state, allow_borrow, walks_on):
+            """The same greedy and fill-back over the rest of the order,
+            for a slot whose first V ordered candidates did not make it
+            fit while more are left (``walks_on``). The targets are held
+            as a mask over the candidates (a walk may take thousands
+            before it fits and give nearly all back), and packed again
+            at the end."""
+            usage_l, ids, _var, taken, found, skipped, _n = state
+            att_cand = is_cand & ~(allow_borrow & (
+                variant == V_RECLAIM_WITHOUT_BORROWING) & ~same_cq)
+            held = jnp.zeros((A_l,), bool).at[
+                jnp.where(taken, ids, A_l)].set(True, mode="drop")
+
+            def forward(c):
+                # The next V candidates this attempt may take that are
+                # valid at this usage: validity only ever goes, so none
+                # is missed, and the scan checks each again.
+                usage_l, held, found, cursor, skipped, _ = c
+                open_ = att_cand & (same_cq
+                                    | ~path_within_nominal(usage_l))
+                w_ids = next_window(key, open_, cursor, V)
+                usage_l, w_taken, found, _, last = scan_window(
+                    window(w_ids), usage_l, found, allow_borrow)
+                held = held.at[jnp.where(w_taken, w_ids, A_l)].set(
+                    True, mode="drop")
+                upto = jnp.where(
+                    last >= 0,
+                    key[jnp.maximum(w_ids[jnp.maximum(last, 0)], 0)],
+                    cursor)
+                passed = jnp.sum(
+                    is_cand & (key > cursor) & (key <= upto),
+                    dtype=jnp.int32) - jnp.sum(w_taken, dtype=jnp.int32)
+                return (usage_l, held, found, upto, skipped + passed,
+                        (w_ids[V - 1] >= 0) & ~found)
+
+            usage_l, held, found, _, skipped, _ = jax.lax.while_loop(
+                lambda c: c[-1], forward,
+                (usage_l, held, found, key[ids[V - 1]], skipped,
+                 walks_on))
+
+            def backward(c):
+                # Fill-back, V targets a page from the last taken down;
+                # the very last one stays.
+                usage_l, held, below, first_page, _ = c
+                w_ids = next_window(-key, held, -below, V)
+                win = window(w_ids)
+
+                def one(carry, i):
+                    return fill_back(*carry, i, win.loc[i], win.usage[i],
+                                     ~(first_page & (i == 0)),
+                                     allow_borrow), None
+
+                (usage_l, kept), _ = jax.lax.scan(
+                    one, (usage_l, w_ids >= 0), jnp.arange(V))
+                held = held.at[jnp.where((w_ids >= 0) & ~kept, w_ids,
+                                         A_l)].set(False, mode="drop")
+                return (usage_l, held,
+                        jnp.min(jnp.where(
+                            w_ids >= 0, key[jnp.maximum(w_ids, 0)],
+                            below)),
+                        jnp.asarray(False), w_ids[V - 1] >= 0)
+
+            usage_l, held, _, _, _ = jax.lax.while_loop(
+                lambda c: c[-1], backward,
+                (usage_l, held, jnp.iinfo(key.dtype).max,
+                 jnp.asarray(True), walks_on & found))
+
+            ids = next_window(key, held, jnp.int64(-1), V)
+            return (usage_l, ids,
+                    jnp.where(ids >= 0, variant[jnp.maximum(ids, 0)], 0),
+                    ids >= 0, found, skipped,
+                    jnp.sum(held, dtype=jnp.int32))
+
+        # One copy of an attempt in the program — its first window and
+        # the walk beyond it — run for the two attempts in turn: what a
+        # program costs when it is first loaded
+        # follows its size. The region is entered only where some slot
+        # walks on: with few candidates (n_cand <= V in every slot) a
+        # launch pays for the first windows alone.
+        allow = jnp.stack([b1, b2])
+
+        def attempt(k, attempts):
+            state = first_window(allow[k])
+            enabled = (k == 0) | (en2 & ~attempts[4][0])
+            walks_on = enabled & any_need & ~state[4] & (n_cand > V)
+            _, state = jax.lax.while_loop(
+                lambda c: c[0],
+                lambda c: (jnp.asarray(False), beyond_first_window(
+                    c[1], allow[k], walks_on)),
+                (walks_on, state))
+            return jax.tree.map(lambda x, new: x.at[k].set(new),
+                                attempts, state)
+
+        (u, ids, var, tk, f, s, n_held) = jax.lax.fori_loop(
+            0, 2, attempt, jax.tree.map(
+                lambda x: jnp.zeros((2,) + x.shape, x.dtype),
+                jax.eval_shape(first_window, b1)))
 
         def borrow_after_height(usage_l):
             """FindHeightOfLowestSubtreeThatFits
@@ -592,25 +755,34 @@ def classical_targets_impl(
                           jnp.where(found_b, found_h, root_h))
             return jnp.max(jnp.where(active, h, 0))
 
-        f1, t1, u1 = run_attempt(b1)
-        f2, t2, u2 = run_attempt(b2)
+        f1, f2 = f[0], f[1]
+        # More than V targets is the one thing the packed columns cannot
+        # hold.
+        big1, big2 = f1 & (n_held[0] > V), f2 & (n_held[1] > V)
+        ids1, ids2, var1, var2 = ids[0], ids[1], var[0], var[1]
+        t1, t2, u1, u2, s1, s2 = tk[0], tk[1], u[0], u[1], s[0], s[1]
         use2 = ~f1 & en2 & f2
-        found = (f1 | use2) & any_need
-        taken = jnp.where(f1, t1, jnp.where(use2, t2,
-                                            jnp.zeros((V,), bool)))
-        overflow = need & any_need & ~found & (n_cand > V)
+        overflow = need & any_need & (big1 | (use2 & big2))
+        found = (f1 | use2) & any_need & ~overflow
+        taken = jnp.where(found, jnp.where(f1, t1, t2),
+                          jnp.zeros((V,), bool))
+        ids = jnp.where(f1, ids1, ids2)
         borrow_after = jnp.where(
             f1, borrow_after_height(u1),
             jnp.where(use2, borrow_after_height(u2), 0)).astype(jnp.int32)
+        skipped = s1 + jnp.where(en2 & ~f1, s2, 0)
 
+        ids_ok = ids >= 0
+        ids_safe = jnp.maximum(ids, 0)
         if g_rows is None:
-            g_v_ids, g_variant = v_ids, v_variant
+            g_v_ids = jnp.where(ids_ok, ids, -1)
         else:
             # Map local victim positions back to GLOBAL ids.
-            g_v_ids = jnp.where(l_ok[v_ids], g_rows[v_ids], -1)
-            g_variant = jnp.where(l_ok[v_ids], v_variant, 0)
+            ids_ok = ids_ok & l_ok[ids_safe]
+            g_v_ids = jnp.where(ids_ok, g_rows[ids_safe], -1)
+        g_variant = jnp.where(ids_ok, jnp.where(f1, var1, var2), 0)
         return (found, overflow, jnp.sum(taken.astype(jnp.int32)),
-                borrow_after, g_v_ids, taken, g_variant)
+                borrow_after, g_v_ids, taken, g_variant, skipped)
 
     if slot_cq is None:
         slot_cq = jnp.arange(C, dtype=jnp.int32)
@@ -627,13 +799,15 @@ def sim_targets(*args, slot_cq, adm_rank, adm_by_root, depth: int,
     found bool[B], overflow bool[B], borrow_after int32[B], and whether
     any victim sits in the row's own ClusterQueue (Preempt, else
     Reclaim). The victim sets stay on the device: the fold does not
-    need them, and the final target selection is the cycle program's."""
+    need them, and the final target selection is the cycle program's.
+    A row whose targets are more than the packed columns hold reports
+    overflow, which the bridge hands to the host (`sim-overflow`)."""
     adm_cq = args[10]
     out = classical_targets_impl(*args, slot_cq=slot_cq,
                                  adm_rank=adm_rank,
                                  adm_by_root=adm_by_root, depth=depth,
                                  v_cap=v_cap)
-    found, overflow, _n, borrow_after, v_ids, taken, _variant = out
+    found, overflow, _n, borrow_after, v_ids, taken, _variant, _skip = out
     same = jnp.any(taken & (v_ids >= 0)
                    & (adm_cq[jnp.maximum(v_ids, 0)] == slot_cq[:, None]),
                    axis=1)

@@ -248,7 +248,7 @@ def _cycle_core(
     slot_overflow = jnp.zeros((C,), bool)
     victim_ids = jnp.zeros((C, 0), jnp.int32)
     victim_variant = jnp.zeros((C, 0), jnp.int32)
-    lattice_ran = jnp.asarray(False)
+    preempt_counts = jnp.zeros((2,), jnp.int32)
     fused_preempt = jnp.zeros((C,), bool)
     slot_victim_row = slot_victim_vals = slot_victim_ids = claimed0 = None
     if adm_cq is not None and not fair_mode:
@@ -265,7 +265,7 @@ def _cycle_core(
         def _run_targets(_):
             with jax.named_scope("kueue.preempt"):
                 (found, overflow, _n, borrow, v_ids, taken,
-                 v_variant) = pops.classical_targets_impl(
+                 v_variant, skipped) = pops.classical_targets_impl(
                     oracle_eff, h_pri, h_ts, entry_fr_d, req_fr,
                     pc_wcq_policy, pc_reclaim_policy, pc_bwc_forbidden,
                     pc_bwc_threshold, pc_cq_has_parent,
@@ -279,19 +279,25 @@ def _cycle_core(
                 # exactly.
                 return (found, overflow, borrow.astype(jnp.int32),
                         v_ids.astype(jnp.int32), taken,
-                        v_variant.astype(jnp.int32))
+                        v_variant.astype(jnp.int32),
+                        jnp.sum(jnp.where(oracle_eff, skipped, 0),
+                                dtype=jnp.int32))
 
         def _skip_targets(_):
             return (jnp.zeros((C,), bool), jnp.zeros((C,), bool),
                     jnp.zeros((C,), jnp.int32),
                     jnp.zeros((C, V), jnp.int32),
                     jnp.zeros((C, V), bool),
-                    jnp.zeros((C, V), jnp.int32))
+                    jnp.zeros((C, V), jnp.int32), jnp.int32(0))
 
-        lattice_ran = jnp.any(oracle_eff)
+        # The slots the preemptor is run for (the launch takes its
+        # branch where there is one), and the ordered candidates its
+        # scans passed over as invalid.
+        n_slots = jnp.sum(oracle_eff, dtype=jnp.int32)
         (pfound, poverflow, pborrow, pv_ids, ptaken,
-         pvariant) = jax.lax.cond(
-            lattice_ran, _run_targets, _skip_targets, None)
+         pvariant, n_skipped) = jax.lax.cond(
+            n_slots > 0, _run_targets, _skip_targets, None)
+        preempt_counts = jnp.stack([n_slots, n_skipped])
         pfound = pfound & oracle_eff
         fused_preempt = pfound
         slot_overflow = poverflow & oracle_eff
@@ -418,7 +424,7 @@ def _cycle_core(
     return (new_pending, new_inadmissible, usage_clean, wl_admitted,
             slot_admitted, slot_position, flavor_of_res, any_needs_oracle,
             slot_oracle, slot_preempting, head_idx, slot_overflow,
-            victim_ids, victim_variant, lattice_ran)
+            victim_ids, victim_variant, preempt_counts)
 
 
 cycle_step = partial(jax.jit,
@@ -482,7 +488,8 @@ def drain_loop(
         (pending, inadmissible, usage, wl_admitted, _slot_admitted,
          slot_position, flavor_of_res, any_oracle, _slot_oracle,
          _slot_preempting, _head_idx, _slot_overflow, _victim_ids,
-         _victim_variant, _lattice_ran) = step(pending, inadmissible, usage)
+         _victim_variant, _preempt_counts) = step(pending, inadmissible,
+                                                  usage)
         admit_cycle = jnp.where(wl_admitted, cycle, admit_cycle)
         admit_pos = jnp.where(wl_admitted, slot_position[wl_cq], admit_pos)
         wl_flavor = jnp.where(wl_admitted[:, None, None],

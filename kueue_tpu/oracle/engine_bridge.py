@@ -15,8 +15,10 @@ member ClusterQueues needs the host this cycle:
   * its current head is not fast-path encodable (multi-podset, partial
     admission, TAS, node selectors, uncovered resources);
   * one of its flavors carries taints or a topology (host assigner path);
-  * its head needs preemption outside the device preemptor's scope
-    (fair-sharing preemption strategies, > v_max victims).
+  * its head needs preemption outside the device preemptor's scope:
+    fair-sharing preemption strategies, or more than v_cap targets once
+    the preemptor has given back what it can (`preemption-overflow`;
+    how many candidates it walks past on the way does not matter).
 
 Multi-flavor resource groups on preemption-enabled ClusterQueues run
 the sim-augmented nomination: the pre-oracle flavor grid
@@ -832,6 +834,8 @@ class OracleBridge:
             if enc is None:
                 return None
             box.attrs["lattice"] = enc.lattice
+            box.attrs["preempt_slots"] = enc.preempt_slots
+            box.attrs["preempt_skipped"] = enc.preempt_skipped
             return self._commit_cycle(enc)
 
     def _commit_tas_stats(self, tas_plan) -> None:
@@ -1173,9 +1177,12 @@ class OracleBridge:
         # readback).
         out = self._exec_call("cycle_step", self.executor.cycle_step,
                               _inputs, statics)
-        # Whether this launch took the fused preemptor's branch: the
-        # program's own predicate (batched._cycle_core's lax.cond).
-        lattice = bool(out[14])
+        # The program's own counts (batched._cycle_core): the slots its
+        # fused preemptor was run for — the launch took that branch
+        # where there is one — and the ordered candidates the scans
+        # passed over as invalid.
+        preempt_slots, preempt_skipped = (int(n) for n in out[14])
+        lattice = preempt_slots > 0
 
         from types import SimpleNamespace
         return SimpleNamespace(
@@ -1184,7 +1191,8 @@ class OracleBridge:
             cq_on_device=cq_on_device, host_root=host_root,
             root_of_cq=root_of_cq, has_head=has_head,
             tas_plan=tas_plan, fused=fused, admitted=admitted,
-            lattice=lattice)
+            lattice=lattice, preempt_slots=preempt_slots,
+            preempt_skipped=preempt_skipped)
 
     def _commit_cycle(self, enc) -> Optional[CycleResult]:
         """Commit the cycle from the verdicts the executor read back:
@@ -1218,7 +1226,7 @@ class OracleBridge:
         (new_pending, new_inadmissible, usage2, wl_admitted, slot_admitted,
          slot_position, flavor_of_res, any_oracle, slot_oracle,
          slot_preempting, head_idx, slot_overflow, victim_ids,
-         victim_variant, _lattice) = out
+         victim_variant, _preempt_counts) = out
         # The victims come packed, [C, v_cap] admitted ids (-1 where a
         # column holds no target; [C, 0] with no fused preemptor), each
         # with its variant. A slot's targets are listed by ascending
@@ -1230,6 +1238,7 @@ class OracleBridge:
         # Slots with a selected victim set.
         found_any = (vids >= 0).any(axis=1)
         decode.attrs["victim_entries"] = int(np.count_nonzero(found_any))
+        decode.attrs["reclaim_victims"] = 0
 
         if fused:
             overflow = np.asarray(slot_overflow) & cq_on_device
@@ -1248,6 +1257,10 @@ class OracleBridge:
             if (sp | found_any).any():
                 vvar = np.take_along_axis(np.asarray(victim_variant),
                                           by_id, axis=1)
+                from kueue_tpu.ops import preempt as pops
+                decode.attrs["reclaim_victims"] = int(np.count_nonzero(
+                    (vids >= 0) & (vvar != pops.V_WITHIN_CQ)
+                    & (sp & cq_on_device)[:, None]))
                 variant_reason = self._variant_reason()
                 from kueue_tpu.scheduler.preemption import IN_CLUSTER_QUEUE
                 for ci in np.nonzero((sp | found_any) & cq_on_device)[0]:

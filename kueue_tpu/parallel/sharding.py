@@ -74,7 +74,7 @@ def sharded_cycle_step(mesh: Mesh, depth: int, num_resources: int,
     # 15 outputs (batched._cycle_core): ... plus slot_overflow [C],
     # victim_ids [C, 0], victim_variant [C, 0] (the packed victims:
     # empty when the fused preemption tensors are not provided, as
-    # here) and the scalar lattice_ran.
+    # here) and the preemptor's two counts, int32[2].
     out_shardings = (
         sh["wl"], sh["wl"], sh["r2"], sh["wl"], sh["r"], sh["r"],
         sh["r3"], sh["r"], sh["r"], sh["r"], sh["r"], sh["r"],

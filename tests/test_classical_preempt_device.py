@@ -135,7 +135,10 @@ def by_root_layout(world, adm):
                 adm_rank=jnp.asarray(rank))
 
 
-def device_targets(eng, wl_info, assignment, now, v_cap=16, layout="flat"):
+def preemptor_inputs(eng, wl_info, assignment, now, layout):
+    """classical_targets_impl's arguments for one asking slot: the
+    positional ones, the layout's keyword ones, and (world, adm, the
+    slot's index)."""
     snapshot = eng.cache.snapshot()
     world = encode_snapshot(snapshot, max_depth=4)
     admitted = [info for cqs in snapshot.cluster_queues.values()
@@ -185,7 +188,7 @@ def device_targets(eng, wl_info, assignment, now, v_cap=16, layout="flat"):
         jnp.asarray(world.parent), depth=world.depth)
 
     grouped = by_root_layout(world, adm) if layout == "by_root" else {}
-    found, overflow, n, _borrow, v_ids, taken, variant = _classical_targets(
+    return (
         jnp.asarray(slot_need), jnp.asarray(slot_pri),
         jnp.asarray(slot_ts), jnp.asarray(slot_fr),
         jnp.asarray(slot_req), jnp.asarray(wcq_policy),
@@ -200,7 +203,16 @@ def device_targets(eng, wl_info, assignment, now, v_cap=16, layout="flat"):
         jnp.asarray(world.ancestors), jnp.asarray(world.height),
         jnp.asarray(world.local_chain),
         jnp.asarray(world.root_nodes), jnp.asarray(world.root_of_cq),
-        depth=world.depth, v_cap=v_cap, **grouped)
+    ), grouped, (world, adm, ci)
+
+
+def device_targets(eng, wl_info, assignment, now, v_cap=16, layout="flat"):
+    args, grouped, (world, adm, ci) = preemptor_inputs(
+        eng, wl_info, assignment, now, layout)
+    C = world.num_cqs
+    found, overflow, n, _borrow, v_ids, taken, variant, _skipped = \
+        _classical_targets(*args, depth=world.depth, v_cap=v_cap,
+                           **grouped)
     # Packed: V = min(v_cap, the candidate axis) columns a slot.
     A_l = (grouped["adm_by_root"].shape[1] if grouped
            else adm.num_admitted)
@@ -217,7 +229,10 @@ def device_targets(eng, wl_info, assignment, now, v_cap=16, layout="flat"):
     assert len(set(v_ids[taken])) == int(taken.sum())
     targets = sorted((adm.keys[i], _VARIANT_REASON[int(var)])
                      for i, var in zip(v_ids[taken], variant[taken]))
-    return found, targets, bool(np.asarray(overflow)[ci])
+    # Slots that did not ask passed nothing over either.
+    assert not np.asarray(_skipped)[np.arange(C) != ci].any()
+    return (found, targets, bool(np.asarray(overflow)[ci]),
+            int(np.asarray(_skipped)[ci]))
 
 
 @pytest.mark.parametrize("layout", ["flat", "by_root"])
@@ -241,11 +256,139 @@ def test_classical_targets_match_host(seed, layout):
     from kueue_tpu.scheduler.flavorassigner import Mode
     if assignment.representative_mode() != Mode.PREEMPT:
         pytest.skip("scenario did not require preemption")
-    d_found, d_targets, d_overflow = device_targets(
+    d_found, d_targets, d_overflow, _ = device_targets(
         eng, info, assignment, now, layout=layout)
     assert not d_overflow
     assert d_found == bool(h_targets), (h_targets, d_targets)
     assert d_targets == h_targets
+
+
+# -- the scan reaches the valid candidates wherever they lie ------------
+
+
+def lending_cohort(n_borrowers, per_queue, request):
+    """One cohort: `home` (nominal 9,000, idle, reclaimWithinCohort Any)
+    lends to ``n_borrowers`` queues of nominal 1,000 that each run
+    ``per_queue`` workloads of ``request``, admitted one a cycle, queue
+    by queue."""
+    eng = Engine()
+    eng.create_resource_flavor(ResourceFlavor("default"))
+    eng.create_cohort(Cohort("root"))
+    stanza = ClusterQueuePreemption(
+        within_cluster_queue=PreemptionPolicy.LOWER_PRIORITY,
+        reclaim_within_cohort=PreemptionPolicy.ANY)
+    for name, nominal in [("home", 9000)] + [
+            (f"b{i:02d}", 1000) for i in range(n_borrowers)]:
+        eng.create_cluster_queue(ClusterQueue(
+            name=name, cohort="root", preemption=stanza,
+            resource_groups=(ResourceGroup(
+                ("cpu",), (FlavorQuotas(
+                    "default", {"cpu": ResourceQuota(nominal)}),)),)))
+        eng.create_local_queue(LocalQueue("lq-" + name, "default", name))
+    for i in range(n_borrowers):
+        for j in range(per_queue):
+            eng.clock += 1.0
+            eng.submit(Workload(
+                name=f"w{i:02d}-{j}", queue_name=f"lq-b{i:02d}",
+                priority=0,
+                pod_sets=(PodSet("main", 1, {"cpu": request}),)))
+            assert eng.schedule_once().assumed
+    return eng
+
+
+@pytest.mark.parametrize("layout", ["flat", "by_root"])
+def test_the_scan_walks_on_past_candidates_that_turn_invalid(layout):
+    """41 queues each borrow 200 with three workloads of 400: in the
+    order (latest admitted first) every queue's first candidate is
+    valid and, once it is gone, the queue is within nominal and its
+    other two are not. `home` takes 9,000 back: 8,200 to free, 21
+    targets, the 21st at position 61 of 123 — of the first 40 ordered
+    candidates 14 are taken and 26 passed over. A scan of the first 32
+    positions, valid or not, reported overflow here."""
+    eng = lending_cohort(n_borrowers=41, per_queue=3, request=400)
+    now = eng.clock + 1.0
+    eng.clock = now
+    wl = Workload(name="back", queue_name="lq-home", priority=5,
+                  creation_time=now,
+                  pod_sets=(PodSet("main", 1, {"cpu": 9000}),))
+    eng.submit(wl)
+    info = eng.queues.cluster_queues["home"].items[wl.key]
+    assignment, h_targets = host_targets(eng, info, now)
+    assert len(h_targets) == 21
+    assert {r for _k, r in h_targets} == {"InCohortReclamation"}
+    found, targets, overflow, skipped = device_targets(
+        eng, info, assignment, now, v_cap=32, layout=layout)
+    assert found and not overflow
+    assert targets == h_targets
+    assert skipped == 40  # two a queue behind each target but the last
+
+
+def test_more_targets_than_v_cap_is_still_overflow():
+    """The one meaning `overflow` keeps: 21 targets do not go into 16
+    packed columns, however far the scan walks."""
+    eng = lending_cohort(n_borrowers=41, per_queue=3, request=400)
+    now = eng.clock + 1.0
+    eng.clock = now
+    wl = Workload(name="back", queue_name="lq-home", priority=5,
+                  creation_time=now,
+                  pod_sets=(PodSet("main", 1, {"cpu": 9000}),))
+    eng.submit(wl)
+    info = eng.queues.cluster_queues["home"].items[wl.key]
+    assignment, _ = host_targets(eng, info, now)
+    found, targets, overflow, _ = device_targets(
+        eng, info, assignment, now, v_cap=16, layout="by_root")
+    assert overflow and not found and targets == []
+
+
+def test_the_sim_program_walks_on_like_the_cycle_program():
+    """The same slot as a simulated row: the sim program is the same
+    scan, so it finds the 21 targets past the first window (Reclaim:
+    none of them in the row's own queue) and reports overflow only
+    where they do not go into the packed columns."""
+    eng = lending_cohort(n_borrowers=41, per_queue=3, request=400)
+    now = eng.clock + 1.0
+    eng.clock = now
+    wl = Workload(name="back", queue_name="lq-home", priority=5,
+                  creation_time=now,
+                  pod_sets=(PodSet("main", 1, {"cpu": 9000}),))
+    eng.submit(wl)
+    info = eng.queues.cluster_queues["home"].items[wl.key]
+    assignment, _ = host_targets(eng, info, now)
+    args, grouped, (world, _adm, ci) = preemptor_inputs(
+        eng, info, assignment, now, "by_root")
+    rows = jnp.arange(world.num_cqs, dtype=jnp.int32)
+    for v_cap, want in [(32, (True, False)), (16, (False, True))]:
+        found, overflow, _borrow, same = pops.sim_targets(
+            *args, slot_cq=rows, depth=world.depth, v_cap=v_cap,
+            **grouped)
+        assert (bool(found[ci]), bool(overflow[ci])) == want
+        assert not bool(same[ci])
+
+
+def test_a_walk_that_takes_more_than_v_cap_and_gives_them_back():
+    """A head that may not borrow (its queue would be over nominal) walks
+    through every candidate of the other queues before it reaches its
+    own — 41 targets held on the way, more than v_cap — and fill-back
+    gives back all but what it needs."""
+    eng = lending_cohort(n_borrowers=41, per_queue=3, request=400)
+    eng.clock += 1.0
+    eng.submit(Workload(name="mine", queue_name="lq-home", priority=1,
+                        pod_sets=(PodSet("main", 1, {"cpu": 600}),)))
+    assert eng.schedule_once().assumed
+    now = eng.clock + 1.0
+    eng.clock = now
+    wl = Workload(name="back", queue_name="lq-home", priority=5,
+                  creation_time=now,
+                  pod_sets=(PodSet("main", 1, {"cpu": 9000}),))
+    eng.submit(wl)
+    info = eng.queues.cluster_queues["home"].items[wl.key]
+    assignment, h_targets = host_targets(eng, info, now)
+    assert ("default/mine", "InClusterQueue") in h_targets
+    assert 16 < len(h_targets) <= 32
+    found, targets, overflow, _ = device_targets(
+        eng, info, assignment, now, v_cap=32, layout="by_root")
+    assert found and not overflow
+    assert targets == h_targets
 
 
 # -- the same columns, as the cycle program hands them on ---------------
@@ -312,7 +455,9 @@ def test_cycle_program_pads_the_packed_victims_to_v_cap():
     assert ((ids >= 0).sum(axis=1) == [1, 0, 1]).all()
     assert (variant[ids >= 0] == pops.V_WITHIN_CQ).all()
     assert (variant[ids < 0] == 0).all()
-    assert bool(out[14])  # the preemptor's branch ran
+    # The preemptor's branch ran, for the two asking slots; no
+    # candidate was passed over.
+    assert out[14].tolist() == [2, 0]
 
 
 def test_fair_mode_has_no_fused_preemptor_and_returns_empty_columns():
@@ -325,4 +470,4 @@ def test_fair_mode_has_no_fused_preemptor_and_returns_empty_columns():
     assert statics["fair_mode"] and "adm_cq" not in tensors
     assert np.asarray(out[12]).shape == np.asarray(out[13]).shape == (3, 0)
     assert np.asarray(out[12]).dtype == np.int32
-    assert not bool(out[14])
+    assert out[14].tolist() == [0, 0]

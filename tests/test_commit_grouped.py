@@ -252,7 +252,8 @@ def _commit_one_local_removing_over_all_rows(
         subtree_quota, lq, borrow_limit, nominal, ancestors, local_chain,
         victims, claimed, *, depth):
     """ops/commit._commit_one_local's victims path as it stood before
-    PR 33."""
+    PR 33, with PR 34's repair: an entry whose targets overlap an
+    earlier entry's is skipped outright and adds no usage."""
     ok = c >= 0
     c_safe = jnp.maximum(c, 0)
     frs = entry_fr[c_safe]
@@ -276,13 +277,13 @@ def _commit_one_local_removing_over_all_rows(
     ids = v_ids[c_safe]
     overlap = is_pre & jnp.any(
         (ids >= 0) & claimed[jnp.clip(ids, 0, claimed.shape[0] - 1)])
+    kind = jnp.where(overlap, cops.ENTRY_SKIP, kind)
 
     g_usage = trial[loc_safe[:, None], frs_safe[None, :]]
     fits, adds = cops._entry_verdict(
         g_sq, g_lq, g_bl, g_usage, chain_ok, frs, req, kind,
         entry_borrows[c_safe], nominal[c_safe, frs_safe],
         borrow_limit[c_safe, frs_safe], g_usage[0], depth=depth)
-    fits = fits & ~overlap
     new_usage = jnp.where(fits & is_pre, trial, usage_l)
     for d in range(depth + 1):
         new_usage = new_usage.at[loc_safe[d], frs_safe].add(adds[d])
@@ -435,6 +436,12 @@ VICTIM_CASES = {
          4: (PRE, 5, 2, [6]), 8: (PRE, 5, 3, [16, 17])}, [0, 4, 8]),
     "victims_already_claimed": (
         {0: (PRE, 5, 1, [2]), 1: (PRE, 5, 2, [2, 3]), 2: (PRE, 5, 3, [3])},
+        [0, 2]),
+    # Cycle 70 of the 8 x 6 world under reclaimWithinCohort Any (ISSUE
+    # 34): the second preemptor of a full cohort fits only if the entry
+    # skipped between the two, for its overlapping target, added nothing.
+    "second_preemptor_of_a_root_after_a_skipped_overlap": (
+        {0: (PRE, 5, 1, [2]), 1: (PRE, 5, 2, [2]), 2: (PRE, 5, 3, [4])},
         [0, 2]),
     "a_preemption_that_fails_its_fit_claims_nothing": (
         {0: (PRE, 11, 1, [2, 3]), 1: (PRE, 10, 2, [2, 3]),

@@ -197,7 +197,8 @@ def lattice_cycle():
     trees.submit(eng, "high", 600, priority=10)
     r, root = trees.cycle(eng)
     assert r.stats.preempting == 1
-    assert trees.child(root, "cycle").attrs == {"lattice": True}
+    assert trees.child(root, "cycle").attrs == {
+        "lattice": True, "preempt_slots": 1, "preempt_skipped": 0}
     return eng
 
 

@@ -188,7 +188,7 @@ def test_cycle_program_returns_and_scatters_nothing_slots_by_admitted():
     assert len(results) == len(out) == 15
     assert not [r for r in results if r.startswith(dense)]
     assert results[12] == results[13] == f"tensor<{C}x{V_CAP}xi32>"
-    assert results[14] == "tensor<i1>"
+    assert results[14] == "tensor<2xi32>"
     # Every scatter's operand (the first type of its signature, after
     # its update region).
     operands = re.findall(

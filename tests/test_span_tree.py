@@ -218,12 +218,14 @@ def test_device_cycle_tree():
     assert names(root) == ["pre_hooks", "cycle", "listeners"]
     cyc = child(root, "cycle")
     assert names(cyc) == ENCODE + COMMIT
-    assert cyc.attrs == {"lattice": False}
+    assert cyc.attrs == {"lattice": False, "preempt_slots": 0,
+                         "preempt_skipped": 0}
     host = child(cyc, "host_encode")
     assert names(host) == ["tas_place"]
     assert host.attrs == {"heads": 1, "pending": 2}
     assert child(cyc, "verdict_decode").attrs == {
-        "lattice": False, "device_heads": 1, "victim_entries": 0}
+        "lattice": False, "device_heads": 1, "victim_entries": 0,
+        "reclaim_victims": 0}
     # The bridge's upload counts this cycle's tensors; the executor had
     # nothing left to convert; the verdicts came back as bytes.
     assert [c.attrs["bytes"] > 0 for c in cyc.children
@@ -309,8 +311,9 @@ def test_phase_keys_are_sums_over_everything_that_ran():
     # Counts, from the attrs of this tree's spans; nothing that adds
     # seconds up takes them in.
     assert {k: ph[k] for k in COUNT_KEYS if k in ph} == {
-        "n_launches": 1, "n_lattice_launches": 0, "n_device_cycles": 1,
-        "n_device_heads": 1, "n_commit_victim_entries": 0}
+        "n_launches": 1, "n_lattice_launches": 0, "n_preempt_slots": 0,
+        "n_preempt_skipped": 0, "n_device_cycles": 1, "n_device_heads": 1,
+        "n_commit_victim_entries": 0, "n_reclaim_victims": 0}
     assert not COUNT_KEYS & set(leaf_phases(ph))
     # The histogram takes the leaves and the whole, no aggregate.
     h = eng.registry.histogram("scheduler_phase_duration_seconds")
@@ -385,7 +388,7 @@ def tap_predicate(eng):
     def tap(tensors, statics):
         out = inner(tensors, statics)
         truth.append((_preemptor_predicate(tensors, statics),
-                      bool(out[14])))
+                      bool(out[14][0] > 0)))
         return out
 
     eng.oracle.executor.cycle_step = tap
