@@ -15,10 +15,16 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     │  │                          invalid), lattice (preempt_slots > 0:
     │  │                          the launch took the preemptor's branch)
     │  ├─ host_encode             _encode_cycle up to the device cycle
-    │  │  └─ tas_place            (attrs heads, pending)
+    │  │  └─ tas_place            (attrs heads, pending; and, where no
+    │  │                          sim_nomination runs, mask_narrowed_heads)
     │  ├─ sim_nomination          multi-flavor groups on preempting CQs
     │  │  │                       only; host_encode runs on after it;
-    │  │  │                       attrs heads, rows, launches, overflow
+    │  │  │                       attrs heads, rows, launches, overflow,
+    │  │  │                       mask_narrowed_heads (the cycle's heads
+    │  │  │                       whose flavor mask excludes a flavor of
+    │  │  │                       their queue's resource groups),
+    │  │  │                       masked_flavor_cells (the cells of the
+    │  │  │                       grid those masks left out of the walk)
     │  │  ├─ flavor_grid          ops/assign.flavor_grid + readback
     │  │  ├─ sim_rows             one row a Preempt-gated cell
     │  │  ├─ sim_launch           the sim program, one block of rows
@@ -162,12 +168,21 @@ AGGREGATE_KEYS = frozenset({"tas_place", "schedule_once", "encode",
 #       needed preemption simulations, the (head, flavor, resource)
 #       cells simulated, the sim program's launches, and the heads the
 #       sim program handed to the host (more candidates than it scans)
+#   n_mask_narrowed_heads  attr ``mask_narrowed_heads`` of the
+#       ``sim_nomination`` span, or of ``host_encode`` where no nomination
+#       runs: the cycle's heads whose flavor mask (labels, taints,
+#       tolerations: rowcache.flavor_ok) excludes a flavor of their
+#       ClusterQueue's resource groups
+#   n_masked_flavor_cells  ``sim_nomination``'s attr
+#       ``masked_flavor_cells``: the (head, flavor, resource) cells of
+#       ops/assign.flavor_grid those masks left out of the walk
 COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches",
                         "n_preempt_slots", "n_preempt_skipped",
                         "n_device_cycles", "n_device_heads",
                         "n_commit_victim_entries", "n_reclaim_victims",
                         "n_sim_heads", "n_sim_rows", "n_sim_launches",
-                        "n_sim_overflow"})
+                        "n_sim_overflow", "n_mask_narrowed_heads",
+                        "n_masked_flavor_cells"})
 
 
 class SpanRecorder:
@@ -310,8 +325,10 @@ def phase_seconds(root: Span) -> dict:
                     _add(out, "sim_nomination", c.dur * 1e-6)
                     for attr in ("heads", "rows", "launches", "overflow"):
                         _add(out, "n_sim_" + attr, c.attrs.get(attr, 0))
+                    _mask_counts(c, out)
                 continue
             _add(out, c.name, c.dur * 1e-6)
+            _mask_counts(c, out)  # host_encode, where no nomination ran
             for s in c.children:  # tas_place, in host_encode
                 if s.name in AGGREGATE_KEYS:
                     _add(out, s.name, s.dur * 1e-6)
@@ -325,6 +342,12 @@ def phase_seconds(root: Span) -> dict:
 
 def _add(out: dict, key: str, value) -> None:
     out[key] = out.get(key, 0) + value
+
+
+def _mask_counts(span: Span, out: dict) -> None:
+    for attr in ("mask_narrowed_heads", "masked_flavor_cells"):
+        if attr in span.attrs:
+            _add(out, "n_" + attr, span.attrs[attr])
 
 
 def _cycle_aggregates(cycle: Span, out: dict) -> None:

@@ -11,11 +11,13 @@ isPreferred, :1127 shouldTryNextFlavor).
 Scope: multi-podset workloads are first-class — requests are
 ``int64[W, P, S]`` and the flavor scan accumulates assumed usage across
 a workload's pod sets exactly like the sequential walk
-(flavorassigner.go:1015,1213; see ``wl_req`` below). Still host-routed:
-taint/affinity filtering (worlds using those demote the root), and the
-preemption candidate SEARCH — workloads whose CQ has a non-Never
-preemption policy and that need preemption are flagged ``needs_oracle``
-for the device preemptor (ops/preempt.py) or the sequential fallback.
+(flavorassigner.go:1015,1213; see ``wl_req`` below). Taint, selector
+and affinity filtering is the caller's per-workload mask (``flavor_ok``,
+evaluated on the host at row encode), in the nomination kernel and the
+sim-grid alike. Not here: the preemption candidate SEARCH — workloads
+whose CQ has a non-Never preemption policy and that need preemption are
+flagged ``needs_oracle`` for the device preemptor (ops/preempt.py) or
+the sequential fallback.
 For CQs with all-Never policies the kernel computes the exact
 NoCandidates outcome the sequential path produces
 (preemption_oracle.go:58).
@@ -95,6 +97,8 @@ def _classify_flavor(c, req, fl, avail, potential, nominal, derived,
 def flavor_grid(
     wl_cq,  # int32[C] head CQ per slot
     wl_req,  # int64[C, S]
+    flavor_ok,  # bool[C, NF] the heads' flavor eligibility (assign_flavors'
+    #   ``flavor_ok``, one row a slot)
     derived, nominal, ancestors, height, group_of_res, group_flavors,
     no_preemption, can_pwb,
     *,
@@ -107,17 +111,20 @@ def flavor_grid(
     preemption simulation (preemption_oracle.go:41) before the
     fungibility lattice can pick the flavor; the bridge runs those sims
     with the sim program (ops/preempt.sim_targets) and folds the lattice
-    as array code (engine_bridge._fold_fungibility).
+    as array code (engine_bridge._fold_fungibility). A flavor the
+    slot's mask excludes is not in its walk (checkFlavorForPodSets
+    skips it: neither tried nor simulated): ``in_walk`` is False there
+    and none of its cells is flagged ``sim``.
 
     Returns (pmode int32[C, G, F, S] in {NO_FIT, NO_CANDIDATES, FIT},
     borrow int32[C, G, F, S] pre-sim, sim bool[C, G, F, S],
-    in_group bool[C, G, S])."""
+    in_group bool[C, G, S], in_walk bool[C, G, F])."""
     S = num_resources
     avail = jnp.maximum(0, derived["available"])
     potential = derived["potential"]
     G = group_flavors.shape[1]
 
-    def per_slot(c, req):
+    def per_slot(c, req, ok):
         g_of_res = group_of_res[c]
         active = req > 0
 
@@ -125,14 +132,16 @@ def flavor_grid(
             pmode, bh, oracle = _classify_flavor(
                 c, req, fl, avail, potential, nominal, derived, ancestors,
                 height, no_preemption, can_pwb, depth=depth)
-            return pmode, bh, oracle & active & (fl >= 0)
+            walked = (fl >= 0) & ok[jnp.maximum(fl, 0)]
+            return pmode, bh, oracle & active & walked, walked
 
-        pmode, borrow, sim = jax.vmap(jax.vmap(eval_fl))(group_flavors[c])
+        pmode, borrow, sim, in_walk = jax.vmap(jax.vmap(eval_fl))(
+            group_flavors[c])
         in_group = (g_of_res[None, :] == jnp.arange(G)[:, None]) \
             & active[None, :]  # [G, S]
-        return pmode, borrow, sim & in_group[:, None, :], in_group
+        return pmode, borrow, sim & in_group[:, None, :], in_group, in_walk
 
-    return jax.vmap(per_slot)(wl_cq, wl_req)
+    return jax.vmap(per_slot)(wl_cq, wl_req, flavor_ok)
 
 
 @partial(jax.jit, static_argnames=("depth", "num_resources"))

@@ -12,9 +12,11 @@ Hybrid partitioning (round 2): admissions only interact within a cohort
 root subtree (all quota math stays under the root), so eligibility is
 decided PER ROOT, not per cycle. A root runs on device unless one of its
 member ClusterQueues needs the host this cycle:
-  * its current head is not fast-path encodable (multi-podset, partial
-    admission, TAS, node selectors, uncovered resources);
-  * one of its flavors carries taints or a topology (host assigner path);
+  * its current head is not fast-path encodable (partial admission,
+    pod sets whose flavor masks disagree, uncovered resources, a TAS
+    request the batched planner cannot express);
+  * one of its flavors carries a topology and the batched TAS planner
+    is off or the flavor is tainted as well (host assigner path);
   * its head needs preemption outside the device preemptor's scope:
     fair-sharing preemption strategies, or more than v_cap targets once
     the preemptor has given back what it can (`preemption-overflow`;
@@ -28,6 +30,11 @@ preemption_oracle.go:41: one fixed block of rows a world), folded
 through the fungibility lattice as array code (_fold_fungibility), then
 committed via the cycle program's slot overrides; a Preempt-mode head's
 victims are the cycle program's fused preemptor's, on the chosen flavor.
+Node labels, taints and tolerations are a per-workload flavor mask
+(tensor/schema.flavor_eligibility_mask, rowcache.flavor_ok): a flavor a
+head's pod set does not match is skipped in its walk — by the cycle
+program's assign pass and by the sim-augmented nomination alike, from
+the one mask — as checkFlavorForPodSets skips it.
 Host roots are handed to the engine's sequential path in the same
 schedule_once() call (engine._sequential_cycle); because roots never
 share quota, device-then-host commit order is cycle-equivalent to the
@@ -103,7 +110,11 @@ def _fold_fungibility(pm, br, in_group, group_flavors, borrow_try_next,
     """findFlavorForPodSets (flavorassigner.go:932) for every slot at
     once, on the granular modes of its cells after the simulations:
     ``pm`` / ``br`` int[C, G, F, S] (PMode, borrow), ``in_group``
-    bool[C, G, S] (the slot requests this resource of this group).
+    bool[C, G, S] (the slot requests this resource of this group),
+    ``group_flavors`` int[C, G, F] the flavors of the slot's walk, in
+    order (-1: none here — the group has fewer, or the slot's pod set
+    does not match the flavor, checkFlavorForPodSets): such a flavor is
+    not a tried one, whatever its cells say.
 
     A flavor's representative mode is the worst of its resources'
     (isPreferred); the group's flavors are walked in order, the walk
@@ -161,25 +172,18 @@ def _fold_fungibility(pm, br, in_group, group_flavors, borrow_try_next,
     return choice.astype(np.int32), mode, borrow
 
 
-def _flavor_taint_unsafe(rf) -> bool:
-    """A flavor whose workloads must take the host path regardless of
-    the batched TAS planner: taints need the host toleration
-    matching."""
-    return rf is not None and bool(rf.node_taints)
-
-
-def _flavor_unsafe(rf) -> bool:
-    """The legacy (batched TAS off) host-path predicate: taints AND
-    topologies demote. With the planner on, a topology alone no longer
-    demotes — tas/batched.plan_cycle nominates placements for TAS
-    heads inside the hybrid cycle and demotes per-head only when a
-    request needs an unsupported TAS feature."""
-    return rf is not None and bool(rf.node_taints or rf.topology_name)
-
-
-def _flavor_predicate():
-    from kueue_tpu.tas import batched as _tb
-    return _flavor_taint_unsafe if _tb.enabled() else _flavor_unsafe
+def _flavor_unsafe(rf, tas_batched: bool) -> bool:
+    """A flavor whose ClusterQueues take the host flavorassigner path:
+    one with a topology, where the batched TAS planner is off (the
+    legacy predicate) or the flavor is tainted as well — the planner
+    (tas/batched.plan_cycle) nominates placements for TAS heads inside
+    the hybrid cycle and demotes per head only when a request needs an
+    unsupported TAS feature, but matches no toleration. Taints, labels
+    and tolerations of a flavor without a topology are the heads' flavor
+    masks (rowcache.flavor_ok) and demote nothing."""
+    if rf is None or not rf.topology_name:
+        return False
+    return not tas_batched or bool(rf.node_taints)
 
 
 class OracleBridge:
@@ -242,12 +246,13 @@ class OracleBridge:
             # BlockAdmission (scheduler.go:535): the host path owns the
             # hold-everything requeue bookkeeping.
             return False
-        # When EVERY CQ with pending work is flavor-unsafe (taints, or
-        # TAS with the batched planner off), every root would demote
-        # and the snapshot+solver built here would be thrown away —
-        # skip straight to the sequential path. Computed from the
-        # cache (no snapshot needed).
-        unsafe = _flavor_predicate()
+        # When EVERY CQ with pending work is flavor-unsafe (a topology
+        # with the batched planner off, or tainted as well), every root
+        # would demote and the snapshot+solver built here would be
+        # thrown away — skip straight to the sequential path. Computed
+        # from the cache (no snapshot needed).
+        from kueue_tpu.tas import batched as _tb
+        tas_batched = _tb.enabled()
         any_safe = False
         any_pending = False
         for name, pcq in eng.queues.cluster_queues.items():
@@ -257,7 +262,8 @@ class OracleBridge:
             cq = eng.cache.cluster_queues.get(name)
             if cq is None:
                 continue
-            if not any(unsafe(eng.cache.resource_flavors.get(fq.name))
+            if not any(_flavor_unsafe(
+                    eng.cache.resource_flavors.get(fq.name), tas_batched)
                        for rg in cq.resource_groups
                        for fq in rg.flavors):
                 any_safe = True
@@ -394,17 +400,20 @@ class OracleBridge:
 
     def _cq_flavor_safe(self, w) -> np.ndarray:
         """bool[C]: none of the CQ's flavors demotes to the host
-        flavorassigner path. With the batched TAS planner on, only
-        taints demote here; topology-carrying CQs stay and get their
-        placements from tas/batched.plan_cycle (which applies its own
-        per-head demotion matrix)."""
+        flavorassigner path (_flavor_unsafe). With the batched TAS
+        planner on, topology-carrying CQs stay and get their placements
+        from tas/batched.plan_cycle (which applies its own per-head
+        demotion matrix) unless the flavor is tainted too."""
+        from kueue_tpu.tas import batched as _tb
+
         eng = self.engine
-        unsafe = _flavor_predicate()
+        tas_batched = _tb.enabled()
         safe = np.ones(w.num_cqs, bool)
         for ci, name in enumerate(w.cq_names):
             spec = eng.cache.cluster_queues[name]
             safe[ci] = not any(
-                unsafe(eng.cache.resource_flavors.get(fq.name))
+                _flavor_unsafe(eng.cache.resource_flavors.get(fq.name),
+                               tas_batched)
                 for rg in spec.resource_groups for fq in rg.flavors)
         return safe
 
@@ -657,7 +666,7 @@ class OracleBridge:
         return [np.concatenate(col) for col in zip(*parts)]
 
     def _sim_nomination(self, box, w, wls, usage, head_idx, sim_slots,
-                        adm, pcfg, v_cap=32):
+                        head_ok, adm, pcfg, v_cap=32):
         """Sim-augmented nomination for heads whose flavor choice depends
         on preemption simulations (multi-flavor groups on
         preemption-enabled CQs): run the pre-oracle flavor grid on
@@ -670,8 +679,12 @@ class OracleBridge:
         findFlavorForPodSets), and hand the cycle program its slot
         overrides (``sim_targets``): a head whose chosen flavor's mode
         is Preempt gets its victims from the cycle program's fused
-        preemptor, on that flavor. ``box`` is the open ``sim_nomination``
-        span, whose attrs carry the cycle's counts.
+        preemptor, on that flavor. ``head_ok`` bool[C, NF] is each
+        slot's head's flavor mask (rowcache.flavor_ok, the rows the cycle
+        program's assign pass reads): a flavor it excludes is not in the
+        head's walk — no cell of it is simulated and the fold does not
+        try it (checkFlavorForPodSets). ``box`` is the open
+        ``sim_nomination`` span, whose attrs carry the cycle's counts.
 
         Returns (override, borrows_override, flavor_override,
         demote_cq bool[C])."""
@@ -697,8 +710,9 @@ class OracleBridge:
         derived = qops.derive_world(
             dev["nominal"], dev["lend_limit"], dev["borrow_limit"], usage,
             dev["parent"], depth=w.depth)
-        g_pmode, g_borrow, g_sim, in_group = aops.flavor_grid(
-            jnp.asarray(h_cq), jnp.asarray(h_req), derived,
+        g_pmode, g_borrow, g_sim, in_group, in_walk = aops.flavor_grid(
+            jnp.asarray(h_cq), jnp.asarray(h_req), jnp.asarray(head_ok),
+            derived,
             dev["nominal"], dev["ancestors"], dev["height"],
             dev["group_of_res"], dev["group_flavors"],
             dev["no_preemption"], dev["can_pwb"],
@@ -707,7 +721,14 @@ class OracleBridge:
         br = np.array(g_borrow)
         g_sim = np.asarray(g_sim) & sim_slots[:, None, None, None]
         in_group = np.asarray(in_group) & sim_slots[:, None, None]
+        # The flavors of each slot's walk: its groups' less those its
+        # mask excludes.
+        in_walk = np.asarray(in_walk)
+        walk_flavors = np.where(in_walk, w.group_flavors, -1)
+        masked = in_group[:, :, None, :] & (
+            (w.group_flavors >= 0) & ~in_walk)[..., None]
         box.attrs["heads"] = grid.attrs["heads"] = int(slots.size)
+        box.attrs["masked_flavor_cells"] = int(np.count_nonzero(masked))
 
         # One row per cell to simulate. Cells whose slot provably has no
         # candidates (_slot_maybe) skip the kernel and resolve to
@@ -755,7 +776,7 @@ class OracleBridge:
                                int(PMode.RECLAIM))
             br[hit] = borrow_after[found]
         choice, mode, borrow = _fold_fungibility(
-            pm, br, in_group, w.group_flavors, w.fung_borrow_try_next,
+            pm, br, in_group, walk_flavors, w.fung_borrow_try_next,
             w.fung_preempt_try_next, w.fung_pref_preempt_first)
 
         # What the cycle program is handed (NO_FIT heads get nothing:
@@ -1023,25 +1044,17 @@ class OracleBridge:
             mf = np.zeros(C, bool)
         sim_cq = (mf & ~w.no_preemption & has_head & head_eligible
                   & flavor_safe & cq_on_device)
-        if sim_cq.any():
-            # The sim grid (flavor_grid + per-cell preemption sims)
-            # doesn't thread per-workload flavor masks; node-filtered
-            # heads that need simulation go host.
-            if wl.flavor_ok is not None:
-                masked = np.zeros(C, bool)
-                for ci in np.nonzero(sim_cq)[0]:
-                    # Only flavors the CQ's resource groups actually
-                    # reference matter; a mask hole on an unrelated
-                    # flavor must not demote the root.
-                    fls = w.group_flavors[ci]
-                    fls = fls[fls >= 0]
-                    if fls.size and not wl.flavor_ok[
-                            head_wid[ci]][fls].all():
-                        masked[ci] = True
-                if masked.any():
-                    demote(masked, "sim-flavor-mask")
-                    cq_on_device = ~host_root[root_of_cq]
-                    sim_cq = sim_cq & cq_on_device
+        # Each head's flavor mask (labels, taints, tolerations, evaluated
+        # at row encode): the rows the cycle program's assign pass reads,
+        # so the sim nomination and it walk the same flavors. A head is
+        # narrowed where its mask excludes a flavor its queue's groups
+        # name.
+        head_ok = np.ones((C, wl.flavor_ok.shape[1]), bool)
+        head_ok[has_head] = wl.flavor_ok[head_wid[has_head]]
+        narrowed_heads = int(np.count_nonzero(has_head & np.any(
+            (w.group_flavors >= 0) & ~head_ok[
+                np.arange(C)[:, None, None],
+                np.maximum(w.group_flavors, 0)], axis=(1, 2))))
         if sim_cq.any():
             # The sim grid is single-podset; multi-podset heads needing
             # it go host.
@@ -1067,9 +1080,10 @@ class OracleBridge:
                 # A container beside host_encode, which runs on after
                 # it: its leaves are the nomination's own.
                 box = spans.next("sim_nomination")
+                box.attrs["mask_narrowed_heads"] = narrowed_heads
                 pre = self._sim_nomination(
                     box, w, wl, jnp.asarray(w.usage), head_wid, sim_cq,
-                    adm, pcfg)
+                    head_ok, adm, pcfg)
                 spans.next("host_encode")
                 demote_cq = pre[3]
                 if demote_cq.any():
@@ -1077,6 +1091,9 @@ class OracleBridge:
                     cq_on_device = ~host_root[root_of_cq]
                     for arr in pre[:3]:
                         arr[~cq_on_device] = -1
+
+        if pre is None:  # no nomination ran: host_encode has the count
+            host.attrs["mask_narrowed_heads"] = narrowed_heads
 
         device_w = active & wl.eligible & (wl.cq >= 0) \
             & cq_on_device[cq_safe_idx]
@@ -1100,11 +1117,11 @@ class OracleBridge:
             wl_hash=jnp.asarray(wl.hash_id),
             wl_ts=jnp.asarray(wl.timestamp),
         )
-        if wl.flavor_ok is not None:
-            # Per-workload flavor eligibility (taints/selectors/affinity)
-            # — lets node-filtered rows ride the dense path instead of
-            # demoting their root (round-4 verdict ask #4).
-            args["wl_flavor_ok"] = jnp.asarray(wl.flavor_ok)
+        # Per-workload flavor eligibility (taints/selectors/affinity):
+        # node-filtered rows ride the dense path instead of demoting
+        # their root. Always passed — one cycle program whether or not a
+        # row is narrowed.
+        args["wl_flavor_ok"] = jnp.asarray(wl.flavor_ok)
         args.update(self._device_world_args(w))
         # Bucket-pad the workload axis so recurring cycles with varying
         # pending counts reuse one compiled program per bucket.

@@ -2,8 +2,8 @@
 
 Before this module, any ClusterQueue carrying a TAS flavor demoted its
 whole cohort root to the sequential path (engine_bridge._flavor_unsafe
-treated ``topology_name`` like taints), so TAS-heavy worlds never ran a
-device cycle. The planner here lifts topology-aware admission into the
+demoted every flavor with a ``topology_name``), so TAS-heavy worlds
+never ran a device cycle. The planner here lifts topology-aware admission into the
 hybrid cycle:
 
   * ``plan_cycle`` nominates a topology assignment for every device-

@@ -129,6 +129,19 @@ class WorldTensors:
         self._flavor_token = tuple(out)
         return self._flavor_token
 
+    def any_flavor_tainted(self) -> bool:
+        """Whether some flavor carries a taint that keeps pods off its
+        nodes (NoSchedule / NoExecute; PreferNoSchedule keeps nobody
+        off): then a pod set with no filter of its own is still not
+        eligible everywhere. Cached like the token."""
+        cached = getattr(self, "_any_tainted", None)
+        if cached is None:
+            cached = self._any_tainted = any(
+                t.effect in ("NoSchedule", "NoExecute")
+                for rf in self.flavor_objects or () if rf is not None
+                for t in rf.node_taints)
+        return cached
+
     def fr_index(self, flavor: str, resource: str) -> int:
         return (self.flavor_names.index(flavor) * self.num_resources
                 + self.resource_names.index(resource))
@@ -628,9 +641,12 @@ def flavor_eligibility_mask(info, world):
     from kueue_tpu.scheduler.flavorassigner import flavor_matches_podset
 
     NF = max(world.num_flavors, 1)
-    filtered = [ps for ps in info.obj.pod_sets
-                if ps.node_selector or ps.node_affinity or ps.tolerations]
-    if not filtered:
+    # A pod set with no filter of its own matches every flavor — but for
+    # a flavor whose taint only the flavor's own tolerations could
+    # cover (checkFlavorForPodSets): so only in a world without taints.
+    if not world.any_flavor_tainted() and not any(
+            ps.node_selector or ps.node_affinity or ps.tolerations
+            for ps in info.obj.pod_sets):
         mask = np.ones(NF, bool)
         info._flavor_mask = (token, mask)
         return mask

@@ -222,7 +222,8 @@ def test_device_cycle_tree():
                          "preempt_skipped": 0}
     host = child(cyc, "host_encode")
     assert names(host) == ["tas_place"]
-    assert host.attrs == {"heads": 1, "pending": 2}
+    assert host.attrs == {"heads": 1, "pending": 2,
+                          "mask_narrowed_heads": 0}
     assert child(cyc, "verdict_decode").attrs == {
         "lattice": False, "device_heads": 1, "victim_entries": 0,
         "reclaim_victims": 0}
@@ -313,7 +314,8 @@ def test_phase_keys_are_sums_over_everything_that_ran():
     assert {k: ph[k] for k in COUNT_KEYS if k in ph} == {
         "n_launches": 1, "n_lattice_launches": 0, "n_preempt_slots": 0,
         "n_preempt_skipped": 0, "n_device_cycles": 1, "n_device_heads": 1,
-        "n_commit_victim_entries": 0, "n_reclaim_victims": 0}
+        "n_commit_victim_entries": 0, "n_reclaim_victims": 0,
+        "n_mask_narrowed_heads": 0}
     assert not COUNT_KEYS & set(leaf_phases(ph))
     # The histogram takes the leaves and the whole, no aggregate.
     h = eng.registry.histogram("scheduler_phase_duration_seconds")

@@ -160,3 +160,33 @@ def test_commit_loop_compiles_to_one_while_that_branches(one_chip):
         r"^%" + re.escape(body) + r" \(.*?^\}", text, re.M | re.S).group(0)
     assert computation.count(" conditional(") == 1
     assert text.count(" conditional(") == 1
+
+
+def test_flavor_grid_compiles_with_the_heads_masks(one_chip):
+    """ops/assign.flavor_grid at the fourth benchmark cell's shapes
+    (1,000 slots, three flavors in one group, two resources, five
+    cohorts of 200; PR 37): the heads' flavor masks are an operand, and
+    the walk's flavors come back beside the grid."""
+    from kueue_tpu.ops import assign as aops
+    from kueue_tpu.ops import quota as qops
+
+    C, N, S, NF, G, F, D = 1_000, 1_005, 2, 3, 1, 3, 4
+    R = NF * S
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    quota = arg(np.int64, N, R)
+    derived = jax.eval_shape(
+        lambda *a: qops.derive_world(*a, depth=D), quota, quota, quota,
+        quota, arg(np.int32, N))
+    derived = jax.tree_util.tree_map(
+        lambda x: arg(x.dtype, *x.shape), derived)
+    compiled = aops.flavor_grid.lower(
+        arg(np.int32, C), arg(np.int64, C, S), arg(np.bool_, C, NF),
+        derived, quota, arg(np.int32, N, D), arg(np.int32, N),
+        arg(np.int32, C, S), arg(np.int32, C, G, F), arg(np.bool_, C),
+        arg(np.bool_, C), depth=D, num_resources=S).compile()
+    shapes = [tuple(o.shape) for o in jax.tree_util.tree_leaves(
+        compiled.out_info)]
+    assert shapes == [(C, G, F, S)] * 3 + [(C, G, S), (C, G, F)]
