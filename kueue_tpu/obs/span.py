@@ -13,7 +13,11 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     │  │                          preempt_skipped (ordered candidates
     │  │                          the preemptor's scans passed over as
     │  │                          invalid), lattice (preempt_slots > 0:
-    │  │                          the launch took the preemptor's branch)
+    │  │                          the launch took the preemptor's branch);
+    │  │                          and from the program's shapes:
+    │  │                          preempt_columns (the width its
+    │  │                          preemptor runs at, 0 where it has none:
+    │  │                          batched.preempt_width)
     │  ├─ host_encode             _encode_cycle up to the device cycle
     │  │  └─ tas_place            (attrs heads, pending; and, where no
     │  │                          sim_nomination runs, mask_narrowed_heads)

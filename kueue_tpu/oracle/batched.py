@@ -195,19 +195,7 @@ def _cycle_core(
             flavor_of_res >= 0,
             flavor_of_res * S + jnp.arange(S)[None, None, :], -1)
 
-    # Dense per-flavor-resource entry form for the commit/preemption
-    # kernels: requests aggregated over podsets per fr column, so
-    # columns are UNIQUE by construction (two podsets sharing a flavor
-    # must be fit-checked against their combined usage; per-column
-    # checks would double-book headroom).
-    R = nominal.shape[1]
-    flat_fr = usage_fr.reshape(C, -1)
-    flat_req = h_req.reshape(C, -1)
-    req_fr = jnp.zeros((C, R), h_req.dtype).at[
-        jnp.arange(C)[:, None], jnp.where(flat_fr >= 0, flat_fr, 0)
-    ].add(jnp.where(flat_fr >= 0, flat_req, 0))
-    entry_fr_d = jnp.where(req_fr > 0,
-                           jnp.arange(R, dtype=jnp.int32)[None, :], -1)
+    entry_fr_d, req_fr = entry_columns(usage_fr, h_req, nominal.shape[1])
 
     # 5. Commit. Entry kinds: FIT commits; preempt-mode-no-candidates
     # reserves capacity unless the CQ can always reclaim
@@ -264,9 +252,11 @@ def _cycle_core(
 
         def _run_targets(_):
             with jax.named_scope("kueue.preempt"):
+                p_fr, p_req = preempt_columns(usage_fr, h_req, entry_fr_d,
+                                              req_fr)
                 (found, overflow, _n, borrow, v_ids, taken,
                  v_variant, skipped) = pops.classical_targets_impl(
-                    oracle_eff, h_pri, h_ts, entry_fr_d, req_fr,
+                    oracle_eff, h_pri, h_ts, p_fr, p_req,
                     pc_wcq_policy, pc_reclaim_policy, pc_bwc_forbidden,
                     pc_bwc_threshold, pc_cq_has_parent,
                     adm_cq, adm_pri, adm_ts, adm_qrt, adm_uid, adm_evicted,
@@ -425,6 +415,70 @@ def _cycle_core(
             slot_admitted, slot_position, flavor_of_res, any_needs_oracle,
             slot_oracle, slot_preempting, head_idx, slot_overflow,
             victim_ids, victim_variant, preempt_counts)
+
+
+def entry_columns(usage_fr, h_req, num_fr: int):
+    """Dense per-flavor-resource entry form for the commit kernels (and
+    the preemptor, where it is no wider than the head's own columns:
+    preempt_columns): ``entry_fr_d`` int32[C, R] (the column's id, -1
+    where it asks nothing) and ``req_fr`` [C, R], the requests of
+    ``h_req`` [C, P, S] aggregated over podsets per fr column of
+    ``usage_fr`` [C, P, S], so columns are UNIQUE by construction (two
+    podsets sharing a flavor must be fit-checked against their combined
+    usage; per-column checks would double-book headroom)."""
+    C = usage_fr.shape[0]
+    flat_fr = usage_fr.reshape(C, -1)
+    flat_req = h_req.reshape(C, -1)
+    req_fr = jnp.zeros((C, num_fr), h_req.dtype).at[
+        jnp.arange(C)[:, None], jnp.where(flat_fr >= 0, flat_fr, 0)
+    ].add(jnp.where(flat_fr >= 0, flat_req, 0))
+    entry_fr_d = jnp.where(req_fr > 0,
+                           jnp.arange(num_fr, dtype=jnp.int32)[None, :], -1)
+    return entry_fr_d, req_fr
+
+
+def preempt_width(num_podsets: int, num_resources: int, num_fr: int) -> int:
+    """The columns the cycle program's fused preemptor runs at: a head
+    holds one flavor a (pod set, resource), so P · S columns where that
+    is narrower than the flavor-resource grid's R, else the grid's."""
+    return min(num_podsets * num_resources, num_fr)
+
+
+def pack_columns(usage_fr, h_req):
+    """Each head's own columns, W = P · S of them: its chosen flavor-
+    resource ids int32[C, W] (-1 where the column asks nothing or has no
+    flavor) and their requests [C, W]. A column that repeats an earlier
+    one of its row (two pod sets on one flavor) is added into it and
+    dropped, so columns stay unique as entry_columns' are."""
+    C, P, S = usage_fr.shape
+    W = P * S
+    fr = usage_fr.reshape(C, W)
+    req = h_req.reshape(C, W)
+    live = (fr >= 0) & (req > 0)
+    fr = jnp.where(live, fr, -1).astype(jnp.int32)
+    req = jnp.where(live, req, 0)
+    if P > 1:
+        same = (fr[:, :, None] == fr[:, None, :]) & live[:, :, None] \
+            & live[:, None, :]  # [C, W, W]
+        repeat = jnp.any(same & jnp.tri(W, k=-1, dtype=bool), axis=2)
+        req = jnp.where(repeat, 0, jnp.sum(
+            jnp.where(same, req[:, None, :], 0), axis=2))
+        fr = jnp.where(repeat, -1, fr)
+    return fr, req
+
+
+def preempt_columns(usage_fr, h_req, entry_fr_d, req_fr):
+    """The fused preemptor's entry form: the head's own columns
+    (pack_columns) where they are narrower than the dense [C, R] form
+    (``entry_fr_d``, ``req_fr``), which is handed back as it is
+    otherwise, with no op added. The preemptor reads a column by its id
+    and reduces over the columns with the inactive ones masked, so what
+    it decides does not depend on the form."""
+    _C, P, S = usage_fr.shape
+    if preempt_width(P, S, req_fr.shape[1]) == req_fr.shape[1]:
+        return entry_fr_d, req_fr
+    with jax.named_scope("kueue.preempt_columns"):
+        return pack_columns(usage_fr, h_req)
 
 
 cycle_step = partial(jax.jit,

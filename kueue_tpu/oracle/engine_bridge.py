@@ -857,6 +857,7 @@ class OracleBridge:
             box.attrs["lattice"] = enc.lattice
             box.attrs["preempt_slots"] = enc.preempt_slots
             box.attrs["preempt_skipped"] = enc.preempt_skipped
+            box.attrs["preempt_columns"] = enc.preempt_columns
             return self._commit_cycle(enc)
 
     def _commit_tas_stats(self, tas_plan) -> None:
@@ -1200,6 +1201,11 @@ class OracleBridge:
         # passed over as invalid.
         preempt_slots, preempt_skipped = (int(n) for n in out[14])
         lattice = preempt_slots > 0
+        # The width the program's preemptor runs at (0: it has none).
+        from kueue_tpu.oracle.batched import preempt_width
+        preempt_columns = preempt_width(
+            wl.requests.shape[1], w.num_resources,
+            w.nominal.shape[1]) if fused else 0
 
         from types import SimpleNamespace
         return SimpleNamespace(
@@ -1209,7 +1215,8 @@ class OracleBridge:
             root_of_cq=root_of_cq, has_head=has_head,
             tas_plan=tas_plan, fused=fused, admitted=admitted,
             lattice=lattice, preempt_slots=preempt_slots,
-            preempt_skipped=preempt_skipped)
+            preempt_skipped=preempt_skipped,
+            preempt_columns=preempt_columns)
 
     def _commit_cycle(self, enc) -> Optional[CycleResult]:
         """Commit the cycle from the verdicts the executor read back:

@@ -64,16 +64,17 @@ def build_engine(oracle: bool, rng: random.Random, n_cqs=3,
     return eng
 
 
-def churn(eng, rng: random.Random, n=30, resources=("cpu",)):
+def churn(eng, rng: random.Random, n=30, resources=("cpu",),
+          podsets=("main",)):
     names = []
     for i in range(n):
         eng.clock += 0.5
         wl = Workload(
             name=f"w{i}", queue_name=f"lq{rng.randrange(3)}",
             priority=rng.choice([0, 2, 5, 9]),
-            pod_sets=(PodSet("main", 1, {
+            pod_sets=tuple(PodSet(ps, 1, {
                 r: rng.choice([500, 900, 1500, 2500])
-                for r in resources}),))
+                for r in resources}) for ps in podsets))
         eng.submit(wl)
         names.append(wl.name)
         if rng.random() < 0.4:
@@ -190,3 +191,63 @@ def test_stops_at_preempt_capable_flavor():
     assert bat_pre == seq_pre, (
         "device path admitted on f2 instead of preempting on f1")
     assert bat.oracle.cycles_on_device > 0
+
+
+def own_flavor_engine(oracle: bool, rng: random.Random):
+    """Three ClusterQueues in one cohort, each on a flavor of its own
+    that covers cpu and memory: the flavor-resource grid is 3 x 2 = 6
+    columns, a head of two pod sets holds 4, and both of its pod sets
+    sit on its queue's one flavor. No group has a second flavor, so no
+    head is simulated: the cycle program's preemptor decides them at
+    the head's own columns, the second pod set's merged into the first's
+    (oracle/batched.preempt_columns)."""
+    eng = Engine()
+    flavors = ("on-demand", "spot", "reserved")
+    for f in flavors:
+        eng.create_resource_flavor(ResourceFlavor(f))
+    eng.create_cohort(Cohort("co"))
+    for i, f in enumerate(flavors):
+        eng.create_cluster_queue(ClusterQueue(
+            name=f"cq{i}", cohort="co",
+            preemption=ClusterQueuePreemption(
+                within_cluster_queue=PreemptionPolicy.LOWER_PRIORITY,
+                reclaim_within_cohort=rng.choice(
+                    [PreemptionPolicy.NEVER, PreemptionPolicy.ANY,
+                     PreemptionPolicy.LOWER_PRIORITY])),
+            resource_groups=(ResourceGroup(("cpu", "memory"), (FlavorQuotas(
+                f, {r: ResourceQuota(rng.choice([3000, 5000]))
+                    for r in ("cpu", "memory")}),)),)))
+        eng.create_local_queue(LocalQueue(f"lq{i}", "default", f"cq{i}"))
+    if oracle:
+        eng.attach_oracle()
+    return eng
+
+
+# Seeds 2, 3, 4 and 6 end in another state than the sequential engine's,
+# on the parent of the packed columns as well and to the same digest
+# there (two pod sets that preempt: ROADMAP D2 h).
+@pytest.mark.parametrize("seed", [0, 1, 5, 7])
+def test_two_pod_sets_on_one_flavor_preempt_as_sequential(seed):
+    resources, podsets = ("cpu", "memory"), ("launcher", "workers")
+    seq = own_flavor_engine(False, random.Random(3000 + seed))
+    bat = own_flavor_engine(True, random.Random(3000 + seed))
+    launches = []
+    inner = bat.oracle.executor.cycle_step
+
+    def tap(tensors, statics):
+        out = inner(tensors, statics)
+        launches.append((tensors["wl_req"].shape[1:],
+                         int(out[14][0]), bool((out[12] >= 0).any())))
+        return out
+
+    bat.oracle.executor.cycle_step = tap
+    churn(seq, random.Random(seed), resources=resources, podsets=podsets)
+    churn(bat, random.Random(seed), resources=resources, podsets=podsets)
+    assert bat.oracle.cycles_on_device > 0
+    assert state_of(seq) == state_of(bat)
+    # Two pod sets of two resources on a grid of six columns: the
+    # preemptor ran at four, asked by heads, and chose victims.
+    assert {shape for shape, _, _ in launches} == {(2, 2)}
+    assert any(slots for _, slots, _ in launches)
+    assert any(victims for _, _, victims in launches)
+    assert bat.spans.last().children[1].attrs["preempt_columns"] == 4

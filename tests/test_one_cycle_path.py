@@ -198,7 +198,8 @@ def lattice_cycle():
     r, root = trees.cycle(eng)
     assert r.stats.preempting == 1
     assert trees.child(root, "cycle").attrs == {
-        "lattice": True, "preempt_slots": 1, "preempt_skipped": 0}
+        "lattice": True, "preempt_slots": 1, "preempt_skipped": 0,
+        "preempt_columns": 1}
     return eng
 
 

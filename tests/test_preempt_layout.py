@@ -5,7 +5,9 @@ through tools/tpu_layouts.py: with two or three resources no array of a
 resource axis last came out resource-minor, 2 values padded to 128
 lanes, and the scans carried, copied and scattered into 64 times the
 data (PERF.md, PR 35) — and with one resource the carry stays laid out
-node-minor, as it was.
+node-minor, as it was. In the cycle program the preemptor's columns are
+the head's own (oracle/batched.preempt_columns): two for a head of one
+pod set and two resources behind three flavors, not the grid's six.
 
 The topology is described inside the module-scoped fixture, never at
 import (tests/test_tpu_compile.py says why), and the tests are skipped
@@ -103,3 +105,106 @@ def test_quota_carry_is_not_resource_minor(regions, resources):
         assert a.ratio <= 2.0, a
         assert a.dims[a.minor] in (ROWS, ROOT_NODES,
                                    ROOT_NODES * resources), a
+
+
+# -- the cycle program's preemptor, at the head's columns ---------------
+
+
+def _cycle_program_inputs(flavors, resources, cohorts=2, per_cohort=10):
+    """What the bridge hands the cycle program (shapes and statics) in a
+    world of ``flavors`` in one group covering ``resources``, two
+    cohorts of preempting ClusterQueues each running four workloads: one
+    real schedule_once() on the CPU, left at the executor's door."""
+    from kueue_tpu.api.types import (
+        ClusterQueue,
+        ClusterQueuePreemption,
+        Cohort,
+        FlavorQuotas,
+        LocalQueue,
+        PodSet,
+        PreemptionPolicy,
+        ResourceFlavor,
+        ResourceGroup,
+        ResourceQuota,
+        Workload,
+    )
+    from kueue_tpu.controllers.engine import Engine
+
+    eng = Engine()
+    names = [f"f{i}" for i in range(flavors)]
+    for name in names:
+        eng.create_resource_flavor(ResourceFlavor(name))
+    queues = [f"cq{c}-{i}" for c in range(cohorts)
+              for i in range(per_cohort)]
+    for c in range(cohorts):
+        eng.create_cohort(Cohort(f"co{c}"))
+    for q in queues:
+        eng.create_cluster_queue(ClusterQueue(
+            name=q, cohort="co" + q[2:q.index("-")],
+            preemption=ClusterQueuePreemption(
+                within_cluster_queue=PreemptionPolicy.LOWER_PRIORITY),
+            resource_groups=(ResourceGroup(resources, tuple(
+                FlavorQuotas(f, dict.fromkeys(resources, ResourceQuota(1000)))
+                for f in names)),)))
+        eng.create_local_queue(LocalQueue("lq" + q, "default", q))
+
+    def submit(name, q):
+        eng.clock += 0.01
+        eng.submit(Workload(name=name, queue_name="lq" + q, pod_sets=(
+            PodSet("main", 1, dict.fromkeys(resources, 200)),)))
+
+    for q in queues:
+        for j in range(4):
+            submit(f"{q}-{j}", q)
+    for _ in range(20):
+        r = eng.schedule_once()
+        if r is None or not r.assumed:
+            break
+    submit("next", queues[0])
+    eng.attach_oracle()
+
+    class Seen(Exception):
+        pass
+
+    def cycle_step(tensors, statics):
+        raise Seen(tensors, statics)
+
+    eng.oracle.executor.cycle_step = cycle_step
+    with pytest.raises(Seen) as seen:
+        eng.schedule_once()
+    tensors, statics = seen.value.args
+    assert "adm_by_root" in tensors  # the fused preemptor is in it
+    return tensors, statics
+
+
+def _lower_cycle_program(one_chip, flavors, resources):
+    from kueue_tpu.oracle import batched
+
+    tensors, statics = _cycle_program_inputs(flavors, resources)
+    lowered = batched.cycle_step.lower(
+        **{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+           for k, v in tensors.items()}, **statics)
+    K = tensors["root_nodes"].shape[1]
+    return lowered, statics["num_cqs"], K
+
+
+def test_cycle_programs_preemptor_carries_the_heads_two_columns(one_chip):
+    """Three flavors x two resources: the quota carry of the preemptor's
+    scans is [C, 2 · K] — it was [C, 6 · K], the whole grid, four of its
+    six columns inactive in every slot."""
+    lowered, C, K = _lower_cycle_program(one_chip, 3, ("cpu", "memory"))
+    assert "kueue.preempt_columns" in lowered.as_text(debug_info=True)
+    loops = [r for r in tpu_layouts.layout_report(
+        lowered.compile().as_text()) if r.kind == "while"]
+    carried = {a.dims for r in loops for a in r.carry}
+    assert (C, 2 * K) in carried
+    assert not any(6 * K in dims for dims in carried)
+
+
+def test_one_column_world_lowers_no_packing(one_chip):
+    """One flavor, one resource (the first and third benchmark cells'
+    kind): the head's columns are the grid's, so the cycle program is
+    traced without the packing — the choice is made at trace time, from
+    the shapes."""
+    lowered, _C, _K = _lower_cycle_program(one_chip, 1, ("cpu",))
+    assert "kueue.preempt_columns" not in lowered.as_text(debug_info=True)

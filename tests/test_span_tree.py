@@ -219,7 +219,7 @@ def test_device_cycle_tree():
     cyc = child(root, "cycle")
     assert names(cyc) == ENCODE + COMMIT
     assert cyc.attrs == {"lattice": False, "preempt_slots": 0,
-                         "preempt_skipped": 0}
+                         "preempt_skipped": 0, "preempt_columns": 1}
     host = child(cyc, "host_encode")
     assert names(host) == ["tas_place"]
     assert host.attrs == {"heads": 1, "pending": 2,
@@ -234,6 +234,22 @@ def test_device_cycle_tree():
     assert child(cyc, "readback").attrs["bytes"] > 0
     assert_nested(root)
     assert_adds_up(eng.last_cycle_phases)
+
+
+@pytest.mark.parametrize("world,columns", [
+    (dict(), 1),
+    (dict(flavors=3), 1),             # one resource: 1 of 3 grid columns
+    (dict(flavors=3, groups=2), 2),   # cpu and memory: 2 of 8
+    (dict(preemption=False), 0),      # no preemptor in the program
+], ids=["one_column", "three_flavors", "two_resources", "no_preemptor"])
+def test_cycle_span_tells_the_preemptors_width(world, columns):
+    """`preempt_columns` on the `cycle` span: the head's own columns
+    (pod sets x resources) where that is narrower than the flavor-
+    resource grid, whether or not the launch took the branch."""
+    eng = make_engine(**world)
+    submit(eng, "w", 400, memory=100 if world.get("groups") == 2 else None)
+    _, root = cycle(eng)
+    assert child(root, "cycle").attrs["preempt_columns"] == columns
 
 
 def test_hybrid_cycle_tree_has_a_host_tail():
