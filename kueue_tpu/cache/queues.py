@@ -222,9 +222,10 @@ class PendingClusterQueue:
                 if self.manager is not None:
                     self.manager.rows.on_park(other)
 
-    def queue_inadmissible(self) -> bool:
+    def queue_inadmissible(self) -> int:
         """manager.go QueueInadmissibleWorkloads — move all inadmissible
-        workloads back into the heap (on relevant cluster events).
+        workloads back into the heap (on relevant cluster events); how
+        many it moved.
 
         Fast path: park() leaves the heap node to lazy deletion, so an
         unchanged workload un-parks as a pure map move plus a row-cache
@@ -234,7 +235,7 @@ class PendingClusterQueue:
         would strand the new object) and a non-AFS queue (AFS keys
         freeze LocalQueue usage at push time, so a re-push must
         re-read it)."""
-        moved = bool(self.inadmissible)
+        moved = len(self.inadmissible)
         afs = self.spec.admission_scope == "UsageBasedAdmissionFairSharing"
         for info in self.inadmissible.values():
             key = info.key
@@ -433,11 +434,16 @@ class QueueManager:
             return False
         return pcq.requeue_if_not_present(info, reason)
 
-    def queue_inadmissible_workloads(self,
-                                     cq_names: Optional[set[str]] = None) -> None:
+    def queue_inadmissible_workloads(
+            self, cq_names: Optional[set[str]] = None) -> tuple[int, int]:
+        """Requeue the parked workloads of ``cq_names`` (every queue's
+        where None): (workloads moved, queues visited)."""
+        moved = visited = 0
         for name, pcq in self.cluster_queues.items():
             if cq_names is None or name in cq_names:
-                pcq.queue_inadmissible()
+                moved += pcq.queue_inadmissible()
+                visited += 1
+        return moved, visited
 
     def heads(self, now: Optional[float] = None) -> list[WorkloadInfo]:
         """manager.go:872 (Heads) — one head per ClusterQueue.  Non-blocking

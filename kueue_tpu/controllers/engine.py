@@ -19,6 +19,7 @@ Lifecycle semantics mirrored from the reference:
 
 from __future__ import annotations
 
+import functools
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Optional
@@ -41,10 +42,12 @@ from kueue_tpu.scheduler.cycle import (
 )
 from kueue_tpu.obs import perf as _perf
 from kueue_tpu.obs.span import (
+    WORK_KINDS,
     SpanRecorder,
     close_phases,
     leaf_phases,
     phase_seconds,
+    window_keys,
 )
 from kueue_tpu.workload_info import WorkloadInfo, admission_from_assignment
 
@@ -69,6 +72,18 @@ class EngineMetrics:
     admission_cycle_preemption_skips: dict[str, int] = field(
         default_factory=dict)
     cycle_durations: list[float] = field(default_factory=list)
+
+
+def _tallied(kind: str):
+    """An engine entry point timed on the span recorder's ``intake``
+    tree, as a call of ``kind`` (obs/span.py SpanRecorder.call)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def entry(self, *args, **kwargs):
+            with self.spans.call(kind):
+                return fn(self, *args, **kwargs)
+        return entry
+    return wrap
 
 
 class _BulkAdmitCtx:
@@ -316,6 +331,7 @@ class Engine:
         if self.journal is not None:
             self.journal.apply(kind, obj, ts=self.clock)
 
+    @_tallied("restore")
     def restore_workload(self, wl: Workload) -> None:
         """The informer-rebuild path (restart recovery): re-register a
         workload from durable state WITHOUT resetting its status —
@@ -533,6 +549,7 @@ class Engine:
         if sel_cqs:
             self.queues.queue_inadmissible_workloads(sel_cqs)
 
+    @_tallied("submit")
     def submit(self, wl: Workload) -> bool:
         if not wl.creation_time:
             wl.creation_time = self.clock
@@ -672,6 +689,7 @@ class Engine:
         self._event("HoldCleared", key)
         self._journal_obj("workload", wl)
 
+    @_tallied("finish")
     def finish(self, key: str) -> None:
         wl = self.workloads.get(key)
         if wl is None:
@@ -698,6 +716,7 @@ class Engine:
 
     # -- the scheduling loop --
 
+    @_tallied("tick")
     def tick(self, dt: float) -> None:
         """Advance the clock and run time-based lifecycle: maximum
         execution time enforcement (workload_controller.go:838
@@ -707,7 +726,9 @@ class Engine:
         # the admitted world is exactly the cache's workload set — at
         # churn scale iterating every known workload per tick dominated
         # the tick itself.
-        for info in list(self.cache.workloads.values()):
+        running = list(self.cache.workloads.values())
+        self.spans.add(tick_scanned=len(running))
+        for info in running:
             wl = self.workloads.get(info.key)
             if wl is None or not wl.is_admitted or wl.is_finished:
                 continue
@@ -901,6 +922,15 @@ class Engine:
             for phase, dur in leaf_phases(phases).items():
                 observe(dur, (phase,))
             observe(phases["schedule_once"], ("schedule_once",))
+            # The engine's events since the cycle before: beside the
+            # whole, not inside it.
+            observe(phases["intake"], ("intake",))
+        # What the engine counted where it happened, idle cycles' too.
+        window = phases if phases is not None else window_keys(root)
+        work = self.registry.counter("scheduler_work_total").inc
+        for kind in WORK_KINDS:
+            if window["n_" + kind]:
+                work((kind,), window["n_" + kind])
         return result
 
     def _schedule_once_impl(self) -> Optional[CycleResult]:
@@ -1932,7 +1962,7 @@ class Engine:
                 if c.cohort and self._cohort_root_of(c.cohort) == root)
             all_names.add(cq_name)
         if all_names:
-            self.queues.queue_inadmissible_workloads(all_names)
+            self._requeue_inadmissible(all_names)
 
     def _requeue_cohort_inadmissible(self, cq_name: str) -> None:
         """Capacity freed: re-activate inadmissible workloads of the cohort
@@ -1943,13 +1973,19 @@ class Engine:
         if cq is None:
             return
         if not cq.cohort:  # None or "" — no cohort membership
-            self.queues.queue_inadmissible_workloads({cq_name})
+            self._requeue_inadmissible({cq_name})
             return
         root = self._cohort_root_of(cq.cohort)
         names = {name for name, c in self.cache.cluster_queues.items()
                  if c.cohort and self._cohort_root_of(c.cohort) == root}
         names.add(cq_name)
-        self.queues.queue_inadmissible_workloads(names)
+        self._requeue_inadmissible(names)
+
+    def _requeue_inadmissible(self, cq_names: set) -> None:
+        """A cohort's requeue, counted on the open span: the workloads
+        it moved back into their queues and the queues it visited."""
+        moved, visited = self.queues.queue_inadmissible_workloads(cq_names)
+        self.spans.add(requeued=moved, requeue_queues=visited)
 
     def _event(self, kind: str, workload: str, cluster_queue: str = "",
                detail: str = "", defer_journal=None) -> None:

@@ -196,6 +196,11 @@ class MetricsRegistry:
         h("scheduler_phase_duration_seconds",
           "per-schedule_once() durations by leaf span (obs/span.py), "
           "which add up to phase=schedule_once")
+        c("scheduler_work_total",
+          "engine work counted where it happens (obs/span.py WORK_KINDS): "
+          "workloads requeued, queues a requeue visited, running "
+          "workloads tick() scanned, programs compiled or read from "
+          "the persistent cache")
         # workload lifecycle
         c("quota_reserved_workloads_total", "per CQ")
         h("quota_reserved_wait_time_seconds", "queued->reserved per CQ")

@@ -78,8 +78,10 @@ def spans_from_flight_trace(path: str) -> list[Span]:
         seq = frame["seq"]
         decisions = frame.get("decisions", [])
         # Leaf phases only: the frame's dict also carries keys that
-        # repeat their time (obs.span.AGGREGATE_KEYS).
-        phases = leaf_phases(frame.get("phases", {}))
+        # repeat their time (obs.span.AGGREGATE_KEYS) and the window's
+        # (WINDOW_KEYS), of which the intake is laid before the cycle.
+        recorded = frame.get("phases", {})
+        phases = leaf_phases(recorded)
         total = sum(phases.values()) * 1e6
         ts = frame.get("clock", 0.0) * 1e6
         cid = frame.get("cid") or correlation_id(seq, decisions)
@@ -90,6 +92,10 @@ def spans_from_flight_trace(path: str) -> list[Span]:
             "clock": frame.get("clock", 0.0),
             "admitted": len(admitted), "preempting": len(preempting),
             "digest": frame.get("digest", "")})
+        if recorded.get("intake"):
+            secs = recorded["intake"]
+            root.child("phase/intake", "phase", ts - secs * 1e6, secs * 1e6,
+                       seconds=secs)
         cursor = ts
         for phase, secs in phases.items():
             root.child(f"phase/{phase}", "phase", cursor, secs * 1e6,

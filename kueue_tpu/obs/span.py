@@ -5,6 +5,20 @@ the admission tracer serves.
 real span tree per ``Engine.schedule_once()``, where the work happens:
 
     schedule_once                 controllers/engine.py — attrs seq, mode
+    ├─ intake                     the engine's events since the last
+    │  │                          cycle (absent where there were none):
+    │  │                          from the first event to this root's
+    │  │                          start; attr seq (this root's). Its
+    │  │                          children are tallies, one a kind, in
+    │  │                          the order first called: ts the first
+    │  │                          call's start, dur the calls' sum,
+    │  │                          attr calls (SpanRecorder.call)
+    │  ├─ finish · submit         Engine.finish / submit (attrs
+    │  │                          requeued, requeue_queues: a finish's
+    │  │                          cohort requeue)
+    │  └─ restore · tick          Engine.restore_workload / tick (attr
+    │                             tick_scanned, and an eviction's
+    │                             requeue counts)
     ├─ pre_hooks
     ├─ cycle                      oracle bridge: try_cycle; attrs, if
     │  │                          it launched, from the cycle program's
@@ -30,16 +44,19 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     │  │  │                       masked_flavor_cells (the cells of the
     │  │  │                       grid those masks left out of the walk)
     │  │  ├─ flavor_grid          ops/assign.flavor_grid + readback
+    │  │  │                       (attr launched_s)
     │  │  ├─ sim_rows             one row a Preempt-gated cell
     │  │  ├─ sim_launch           the sim program, one block of rows
     │  │  │                       a launch (attrs rows, rows_padded,
     │  │  │                       launches; bytes moved, upload_s,
-    │  │  │                       device_wait_s, readback_s)
+    │  │  │                       device_wait_s, readback_s,
+    │  │  │                       launched_s)
     │  │  ├─ fungibility_fold     the flavor walk, array code
     │  │  └─ sim_targets          the cycle program's slot overrides
     │  ├─ upload                  host arrays -> device (attrs bytes)
     │  ├─ dispatch                cycle_step(...) returning futures
-    │  ├─ device_wait             block_until_ready on the outputs
+    │  ├─ device_wait             block_until_ready on the outputs (attr
+    │  │                          launched_s: the launch's window)
     │  ├─ readback                np.asarray of the outputs (attrs bytes)
     │  ├─ verdict_decode          attrs lattice (the branch of the
     │  │                          launch that served it), device_heads,
@@ -58,9 +75,19 @@ boundary as attrs; the root's ``seq`` is the id all of a cycle's spans
 share. Entering a span also enters ``jax.profiler.TraceAnnotation(
 "kueue.<name>")`` (a flag check while no profiler session is open), so
 a profiler capture holds the same tree on the device trace's clock with
-no switch to flip. ``phase_seconds`` turns a tree into
-``Engine.last_cycle_phases``: seconds by leaf name, and the counts its
-attrs hold (COUNT_KEYS).
+no switch to flip; so does every call of an engine entry point
+(``kueue.finish``, ``kueue.submit``, ``kueue.restore``, ``kueue.tick``),
+inside a cycle and on another thread too, where it tallies nothing. A
+device launch marks its window, from the dispatch of its first program
+to its outputs being ready (with a remote oracle, the round trip to
+it), as attr ``launched_s`` of the span open when it ends and as a
+``kueue.launch`` annotation carrying the program's name
+(SpanRecorder.launch). Each program JAX compiles, or reads from its
+persistent cache, adds 1 to attr ``compiles`` of the span open in the
+compiling thread. ``phase_seconds`` turns a tree
+into ``Engine.last_cycle_phases``: seconds by leaf name, the counts its
+attrs hold (COUNT_KEYS), and the keys of the window it closes
+(WINDOW_KEYS).
 
 **CycleTracer** (obs/tracer.py, attached on demand) serves per-cycle
 trees with decisions and rationale:
@@ -87,6 +114,7 @@ subsystems, and replaying a trace regenerates identical ids.
 from __future__ import annotations
 
 import sys
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -188,6 +216,69 @@ COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches",
                         "n_sim_overflow", "n_mask_narrowed_heads",
                         "n_masked_flavor_cells"})
 
+# The engine's entry points the ``intake`` tree tallies, by kind.
+TALLY_KINDS = ("finish", "submit", "restore", "tick")
+
+# Keys of Engine.last_cycle_phases of the window a cycle closes: the
+# engine's events since the cycle before, and the device launches of
+# the cycle. Every one, in every deciding cycle, and none a leaf:
+#   intake            seconds, the ``intake`` tree's tallies summed
+#   intake_<kind>     seconds, each kind's tally (TALLY_KINDS)
+#   device_launched   seconds, the launch windows (attr ``launched_s``)
+#   host_bound        intake + schedule_once - device_launched: the
+#                     engine's seconds with no device program of its own
+#                     running (close_phases)
+#   n_intake_calls    the tallies' ``calls``
+# and counts summed over the ``intake`` tree and the cycle's own:
+#   n_requeued        attr ``requeued``: workloads moved from parked back
+#                     into their queue by a cohort's requeue
+#   n_requeue_queues  attr ``requeue_queues``: the ClusterQueues those
+#                     requeues visited
+#   n_tick_scanned    attr ``tick_scanned``: running workloads tick()
+#                     looked at
+#   n_compiles        attr ``compiles``: programs JAX compiled or read
+#                     back from its persistent cache
+# These four counts are also the running totals of the metric family
+# scheduler_work_total, by kind (WORK_KINDS: the key less its ``n_``),
+# idle cycles' windows included.
+WINDOW_KEYS = frozenset(
+    {"intake", "device_launched", "host_bound", "n_intake_calls",
+     "n_requeued", "n_requeue_queues", "n_tick_scanned", "n_compiles"}
+    | {"intake_" + kind for kind in TALLY_KINDS})
+
+WORK_KINDS = ("requeued", "requeue_queues", "tick_scanned", "compiles")
+
+# Span attrs summed over a whole tree into WINDOW_KEYS.
+_WINDOW_ATTRS = (("launched_s", "device_launched"),
+                 ("requeued", "n_requeued"),
+                 ("requeue_queues", "n_requeue_queues"),
+                 ("tick_scanned", "n_tick_scanned"),
+                 ("compiles", "n_compiles"))
+
+# The recorder whose root or tallied call last opened on this thread,
+# for the one jax.monitoring listener of the process: JAX reports a
+# compile on the thread that compiles.
+_recording = threading.local()
+_compile_listener_registered = False
+
+
+def _on_compile(event: str, secs: float, **_) -> None:
+    """What JAX compiles, or reads back from its persistent cache: attr
+    ``compiles`` of the span open in the compiling thread."""
+    if event == "/jax/core/compile/backend_compile_duration":
+        rec = getattr(_recording, "rec", None)
+        if rec is not None:
+            rec.add(compiles=1)
+
+
+def _listen_for_compiles() -> None:
+    global _compile_listener_registered
+    if not _compile_listener_registered:
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_compile)
+        _compile_listener_registered = True
+
 
 class SpanRecorder:
     """Real spans, always on: one tree per schedule_once(), the last
@@ -197,15 +288,27 @@ class SpanRecorder:
     cannot leave the stack out of step. Decision code calls this and
     never reads it (graftlint D1/O1).
 
+    Between two trees, ``with rec.call(kind)`` tallies the engine's
+    entry points on an ``intake`` tree, which the next root takes as
+    its first child the instant it opens; ``with rec.launch(program)``
+    marks a device launch's window.
+
+    The recorder belongs to the thread that opened its newest root (the
+    one that built it, before any): a call from another thread, such as
+    a submit from an HTTP handler while the loop runs a cycle, is only
+    annotated, and ``add`` from it is dropped, so that no thread pushes
+    onto or pops from a stack another is recording on.
+
     What a cycle allocates is part of the cost (the engine sweeps the
     young generation every cycle, ``apply_serving_gc_posture``): a
     Span, its attrs and child list, and the profiler annotation, per
-    span; the context manager is the recorder itself. Spans hold no
-    parent pointer, so a tree dropped from the ring is freed by
-    reference count alone."""
+    span; the context manager is the recorder itself. A call allocates
+    its context manager and annotation, and a span only for the first
+    call of a kind between two cycles. Spans hold no parent pointer, so
+    a tree dropped from the ring is freed by reference count alone."""
 
     __slots__ = ("clock", "epoch", "trees", "_open", "_scopes", "_marks",
-                 "_annotation")
+                 "_annotation", "_intake", "_tallies", "_owner")
 
     def __init__(self, retain: int = 8,
                  clock: Callable[[], float] = time.perf_counter):
@@ -213,17 +316,23 @@ class SpanRecorder:
         self._open: list = []     # the open spans, root first
         self._scopes: list = []   # their profiler annotations
         self._marks: list = []    # stack depth at each ``with``
-        # jax.profiler.TraceAnnotation, from the first root that finds
-        # jax imported (this module never imports it: no session can be
-        # open in a process that has not).
+        # jax.profiler.TraceAnnotation, from the first root or call that
+        # finds jax imported (this module never imports it: no session
+        # can be open in a process that has not).
         self._annotation = None
+        self._intake: Optional[Span] = None  # open between two roots
+        self._tallies: dict = {}             # its children, by kind
+        self._owner = threading.get_ident()  # the thread recording
         self.set_clock(clock)
 
     def set_clock(self, clock: Callable[[], float]) -> None:
         """Time spans on ``clock`` from here on, with a fresh epoch:
-        ``(clock(), time.time_ns())`` read together."""
+        ``(clock(), time.time_ns())`` read together. An open ``intake``
+        tree, timed on the clock before, is dropped."""
         self.clock = clock
         self.epoch = (clock(), time.time_ns())
+        self._intake = None
+        self._tallies = {}
 
     # -- recording --
 
@@ -243,10 +352,31 @@ class SpanRecorder:
         self._push(name, attrs, self.clock())
         return self
 
+    def call(self, kind: str) -> "_Call":
+        """``with rec.call(kind)``: one call of an engine entry point
+        (TALLY_KINDS), annotated ``kueue.<kind>``. Where no span is open
+        it is timed on the ``intake`` tree's tally of its kind, open
+        while it runs; inside a span (a cycle, another call) its time is
+        that span's, and from a thread that does not own the recorder it
+        is not timed: neither tallies anything."""
+        return _Call(self, kind)
+
+    def launch(self, program: str) -> "_Launch":
+        """``with rec.launch(program)`` around a device launch, from the
+        dispatch of its first program to its outputs being ready: the
+        seconds go to attr ``launched_s`` of the span open when it ends,
+        and the window is a ``kueue.launch`` annotation carrying
+        ``program``."""
+        return _Launch(self, program)
+
     def add(self, **amounts) -> None:
         """Add ``amounts`` to the innermost open span's attrs of the
         same names (code that runs inside a leaf and has bytes or
-        seconds to report, without a span of its own)."""
+        seconds to report, without a span of its own); with no span
+        open, or from a thread that does not own the recorder, they are
+        dropped."""
+        if not self._open or threading.get_ident() != self._owner:
+            return
         attrs = self._open[-1].attrs
         for key, more in amounts.items():
             attrs[key] = attrs.get(key, 0) + more
@@ -261,20 +391,38 @@ class SpanRecorder:
             self._pop(self.clock(), None)
         return False
 
+    def _resolve_annotation(self):
+        if "jax" in sys.modules:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+            _listen_for_compiles()
+        return self._annotation
+
     def _push(self, name: str, attrs: dict, t: float) -> Span:
         stack = self._open
         annotation = self._annotation
-        if annotation is None and not stack and "jax" in sys.modules:
-            from jax.profiler import TraceAnnotation
-            annotation = self._annotation = TraceAnnotation
+        if not stack:
+            if annotation is None:
+                annotation = self._resolve_annotation()
+            self._owner = threading.get_ident()
+            _recording.rec = self
         scope = None
         if annotation is not None:
             scope = annotation("kueue." + name)
             scope.__enter__()
         self._scopes.append(scope)
-        s = Span(name, "span", (t - self.epoch[0]) * 1e6, 0.0, attrs, [])
+        ts = (t - self.epoch[0]) * 1e6
+        s = Span(name, "span", ts, 0.0, attrs, [])
         if stack:
             stack[-1].children.append(s)
+        elif self._intake is not None:
+            intake = self._intake
+            intake.dur = ts - intake.ts
+            if "seq" in attrs:
+                intake.attrs["seq"] = attrs["seq"]
+            s.children.append(intake)
+            self._intake = None
+            self._tallies = {}
         stack.append(s)
         return s
 
@@ -290,6 +438,18 @@ class SpanRecorder:
             self.trees.append(s)
         return s
 
+    def _tally(self, kind: str, t: float) -> Span:
+        """The open ``intake`` tree's tally of ``kind``, opened (and the
+        tree with it) at ``t`` by the first such call."""
+        tally = self._tallies.get(kind)
+        if tally is None:
+            ts = (t - self.epoch[0]) * 1e6
+            if self._intake is None:
+                self._intake = Span("intake", "span", ts, 0.0, {}, [])
+            tally = self._tallies[kind] = self._intake.child(
+                kind, "span", ts, 0.0, calls=0)
+        return tally
+
     # -- reading (obs zone, tests, operator surfaces) --
 
     def open_root(self) -> Optional[Span]:
@@ -304,12 +464,79 @@ class SpanRecorder:
         return self.trees[-1] if self.trees else None
 
 
+class _Call:
+    """SpanRecorder.call's context manager."""
+
+    __slots__ = ("rec", "kind", "scope", "tally", "t0")
+
+    def __init__(self, rec: SpanRecorder, kind: str):
+        self.rec = rec
+        self.kind = kind
+
+    def __enter__(self) -> None:
+        rec = self.rec
+        annotation = rec._annotation or rec._resolve_annotation()
+        self.scope = scope = (annotation("kueue." + self.kind)
+                              if annotation is not None else None)
+        if scope is not None:
+            scope.__enter__()
+        if threading.get_ident() != rec._owner or rec._open:
+            self.tally = None
+            return
+        self.t0 = t = rec.clock()
+        self.tally = tally = rec._tally(self.kind, t)
+        rec._open.append(tally)  # what the call adds lands on it
+        rec._scopes.append(None)
+        _recording.rec = rec
+
+    def __exit__(self, *exc) -> bool:
+        rec, tally = self.rec, self.tally
+        if tally is not None:
+            t = rec.clock()
+            while rec._open[-1] is not tally:  # left open by the call
+                rec._pop(t, None)
+            rec._open.pop()
+            rec._scopes.pop()
+            tally.dur += (t - self.t0) * 1e6
+            tally.attrs["calls"] += 1
+        if self.scope is not None:
+            self.scope.__exit__(None, None, None)
+        return False
+
+
+class _Launch:
+    """SpanRecorder.launch's context manager."""
+
+    __slots__ = ("rec", "program", "scope", "t0")
+
+    def __init__(self, rec: SpanRecorder, program: str):
+        self.rec = rec
+        self.program = program
+
+    def __enter__(self) -> None:
+        rec = self.rec
+        annotation = rec._annotation
+        self.scope = scope = (annotation("kueue.launch", program=self.program)
+                              if annotation is not None else None)
+        if scope is not None:
+            scope.__enter__()
+        self.t0 = rec.clock()
+
+    def __exit__(self, *exc) -> bool:
+        rec = self.rec
+        t = rec.clock()
+        if self.scope is not None:
+            self.scope.__exit__(None, None, None)
+        rec.add(launched_s=t - self.t0)
+        return False
+
+
 def phase_seconds(root: Span) -> dict:
     """A schedule_once() tree as ``Engine.last_cycle_phases``: seconds,
     one key per leaf name, ``tas_place`` (nested in host_encode),
     ``sim_nomination`` (that subtree's wall) and the legacy aggregates;
-    and the counts of COUNT_KEYS. ``close_phases`` adds what only the closed
-    root knows."""
+    the counts of COUNT_KEYS; and WINDOW_KEYS, but ``host_bound``, which
+    ``close_phases`` adds with what else only the closed root knows."""
     out: dict = {}
     launches = lattice = slots = skipped = 0
     boxes = [root]
@@ -321,6 +548,8 @@ def phase_seconds(root: Span) -> dict:
             slots += box.attrs.get("preempt_slots", 0)
             skipped += box.attrs.get("preempt_skipped", 0)
         for c in box.children:
+            if c.name == "intake":  # before the root: no leaf of it
+                continue
             if c.name in CONTAINERS:
                 boxes.append(c)
                 if c.name == "cycle":
@@ -341,6 +570,31 @@ def phase_seconds(root: Span) -> dict:
         out["n_lattice_launches"] = lattice
         out["n_preempt_slots"] = slots
         out["n_preempt_skipped"] = skipped
+    out.update(window_keys(root))
+    return out
+
+
+def window_keys(root: Span) -> dict:
+    """WINDOW_KEYS of a schedule_once() tree, but ``host_bound``."""
+    out: dict = {}
+    intake = root.children[0] if (
+        root.children and root.children[0].name == "intake") else None
+    total, calls = 0.0, 0
+    for kind in TALLY_KINDS:
+        out["intake_" + kind] = 0.0
+    for tally in intake.children if intake is not None else ():
+        out["intake_" + tally.name] = secs = tally.dur * 1e-6
+        total += secs
+        calls += tally.attrs["calls"]
+    out["intake"] = total
+    out["n_intake_calls"] = calls
+    sums = dict.fromkeys((key for _, key in _WINDOW_ATTRS), 0)
+    for s in root.walk():
+        if s.attrs:
+            for attr, key in _WINDOW_ATTRS:
+                if attr in s.attrs:
+                    sums[key] += s.attrs[attr]
+    out.update(sums)
     return out
 
 
@@ -378,21 +632,24 @@ def close_phases(phases: dict, root: Span) -> None:
     has closed: ``listeners`` (open while the listeners read the dict),
     ``schedule_once`` = the root's wall, and ``unattributed`` = the
     containers' self time, so that leaves + unattributed ==
-    schedule_once by construction."""
+    schedule_once by construction; and ``host_bound``."""
     for c in root.children:
         if c.name == "listeners":
             phases["listeners"] = c.dur * 1e-6
     total = root.dur * 1e-6
     phases["unattributed"] = total - sum(leaf_phases(phases).values())
     phases["schedule_once"] = total
+    phases["host_bound"] = (phases["intake"] + total
+                            - phases["device_launched"])
 
 
 def leaf_phases(phases: dict) -> dict:
-    """``last_cycle_phases`` without the keys that repeat time and
-    without the counts: the seconds a reader may add up or lay end to
-    end."""
+    """``last_cycle_phases`` without the keys that repeat time, without
+    the counts and without the window's keys: the seconds a reader may
+    add up or lay end to end."""
     return {k: v for k, v in phases.items()
-            if k not in AGGREGATE_KEYS and k not in COUNT_KEYS}
+            if k not in AGGREGATE_KEYS and k not in COUNT_KEYS
+            and k not in WINDOW_KEYS}
 
 
 def correlation_id(seq: int, decisions: list) -> str:

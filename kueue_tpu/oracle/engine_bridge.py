@@ -688,6 +688,7 @@ class OracleBridge:
 
         Returns (override, borrows_override, flavor_override,
         demote_cq bool[C])."""
+        import jax
         import jax.numpy as jnp
 
         from kueue_tpu.ops import assign as aops
@@ -707,16 +708,19 @@ class OracleBridge:
         h_cq = np.where(sim_slots, np.arange(C), 0).astype(np.int32)
         h_req = np.zeros((C, S), np.int64)
         h_req[slots] = wls.requests[head_idx[slots], 0]
-        derived = qops.derive_world(
-            dev["nominal"], dev["lend_limit"], dev["borrow_limit"], usage,
-            dev["parent"], depth=w.depth)
-        g_pmode, g_borrow, g_sim, in_group, in_walk = aops.flavor_grid(
-            jnp.asarray(h_cq), jnp.asarray(h_req), jnp.asarray(head_ok),
-            derived,
-            dev["nominal"], dev["ancestors"], dev["height"],
-            dev["group_of_res"], dev["group_flavors"],
-            dev["no_preemption"], dev["can_pwb"],
-            depth=w.depth, num_resources=S)
+        with spans.launch("flavor_grid"):
+            derived = qops.derive_world(
+                dev["nominal"], dev["lend_limit"], dev["borrow_limit"],
+                usage, dev["parent"], depth=w.depth)
+            grid_out = aops.flavor_grid(
+                jnp.asarray(h_cq), jnp.asarray(h_req), jnp.asarray(head_ok),
+                derived,
+                dev["nominal"], dev["ancestors"], dev["height"],
+                dev["group_of_res"], dev["group_flavors"],
+                dev["no_preemption"], dev["can_pwb"],
+                depth=w.depth, num_resources=S)
+            jax.block_until_ready(grid_out)
+        g_pmode, g_borrow, g_sim, in_group, in_walk = grid_out
         pm = np.array(g_pmode)  # writable copies: the fold's lattice
         br = np.array(g_borrow)
         g_sim = np.asarray(g_sim) & sim_slots[:, None, None, None]

@@ -30,6 +30,7 @@ through the same jit cache when local).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import socket
@@ -54,7 +55,8 @@ _CYCLE_STATICS = ("depth", "num_resources", "num_cqs", "fair_mode",
 def _run_cycle_step(tensors: dict, statics: dict, spans: SpanRecorder):
     """One launch of the cycle program, verdicts read back to the host.
     ``spans`` (the engine's recorder, or a served connection's own)
-    gets the call split where it blocks."""
+    gets the call split where it blocks, and the launch's window from
+    the dispatch to the outputs being ready."""
     import jax
     import jax.numpy as jnp
 
@@ -73,9 +75,10 @@ def _run_cycle_step(tensors: dict, statics: dict, spans: SpanRecorder):
             kwargs[k].nbytes for k, v in tensors.items()
             if kwargs[k] is not v)
         spans.next("dispatch")
-        out = B.cycle_step(**kwargs, **statics)
-        spans.next("device_wait")
-        jax.block_until_ready(out)
+        with spans.launch("cycle_step"):
+            out = B.cycle_step(**kwargs, **statics)
+            spans.next("device_wait")
+            jax.block_until_ready(out)
         readback = spans.next("readback")
         host = [np.asarray(o) for o in out]
         readback.attrs["bytes"] = sum(o.nbytes for o in host)
@@ -90,7 +93,9 @@ def _run_sim_targets(tensors: dict, statics: dict, derived=None,
     ``spans`` (the engine's recorder, inside the bridge's ``sim_launch``
     span) is told the bytes the call moved (host arrays up, answers
     back) and the seconds it spent where it blocks: attrs bytes,
-    upload_s, device_wait_s, readback_s."""
+    upload_s, device_wait_s, readback_s; and the launch's window, from
+    the dispatch of its first program to the answers being ready
+    (SpanRecorder.launch: launched_s)."""
     import jax
     import jax.numpy as jnp
 
@@ -101,25 +106,27 @@ def _run_sim_targets(tensors: dict, statics: dict, derived=None,
     t0 = clock()
     t = {k: v if isinstance(v, jax.Array) else jnp.asarray(v)
          for k, v in tensors.items()}
-    if derived is None:
-        derived = qops.derive_world(
-            t["nominal"], t["lend_limit"], t["borrow_limit"], t["usage"],
-            t["parent"], depth=statics["depth"])
-    t1 = clock()
-    out = pops.sim_targets(
-        t["slot_need"], t["slot_pri"], t["slot_ts"], t["slot_fr"],
-        t["slot_req"], t["wcq_policy"], t["reclaim_policy"],
-        t["bwc_forbidden"], t["bwc_threshold"], t["cq_has_parent"],
-        t["adm_cq"], t["adm_pri"], t["adm_ts"], t["adm_qrt"],
-        t["adm_uid"], t["adm_ev"], t["adm_usage"], derived["usage"],
-        derived["subtree_quota"], t["lend_limit"], t["borrow_limit"],
-        t["nominal"], t["ancestors"], t["height"], t["local_chain"],
-        t["root_nodes"], t["root_of_cq"],
-        slot_cq=t["slot_cq"], adm_rank=t["adm_rank"],
-        adm_by_root=t["adm_by_root"],
-        depth=statics["depth"], v_cap=statics["v_cap"])
-    jax.block_until_ready(out)
-    t2 = clock()
+    with (spans.launch("sim_targets") if spans is not None
+          else contextlib.nullcontext()):
+        if derived is None:
+            derived = qops.derive_world(
+                t["nominal"], t["lend_limit"], t["borrow_limit"],
+                t["usage"], t["parent"], depth=statics["depth"])
+        t1 = clock()
+        out = pops.sim_targets(
+            t["slot_need"], t["slot_pri"], t["slot_ts"], t["slot_fr"],
+            t["slot_req"], t["wcq_policy"], t["reclaim_policy"],
+            t["bwc_forbidden"], t["bwc_threshold"], t["cq_has_parent"],
+            t["adm_cq"], t["adm_pri"], t["adm_ts"], t["adm_qrt"],
+            t["adm_uid"], t["adm_ev"], t["adm_usage"], derived["usage"],
+            derived["subtree_quota"], t["lend_limit"], t["borrow_limit"],
+            t["nominal"], t["ancestors"], t["height"], t["local_chain"],
+            t["root_nodes"], t["root_of_cq"],
+            slot_cq=t["slot_cq"], adm_rank=t["adm_rank"],
+            adm_by_root=t["adm_by_root"],
+            depth=statics["depth"], v_cap=statics["v_cap"])
+        jax.block_until_ready(out)
+        t2 = clock()
     host = [np.asarray(o) for o in out]
     if spans is not None:
         spans.add(
@@ -200,15 +207,18 @@ class RemoteExecutor:
         with spans.span("upload") as upload:
             payload = wire.pack("cycle_step", tensors, statics)
             upload.attrs["bytes"] = len(payload)
-        with spans.span("device_wait"):
+        # The launch's window, as the engine sees it: the round trip.
+        with spans.span("device_wait"), spans.launch("cycle_step"):
             body = self._roundtrip(payload)
         with spans.span("readback", bytes=len(body)):
             return self._unpack(body)
 
     def sim_targets(self, tensors: dict, statics: dict, derived=None):
         # The service re-derives quota state server-side.
-        return self._unpack(self._roundtrip(
-            wire.pack("sim_targets", tensors, statics)))
+        payload = wire.pack("sim_targets", tensors, statics)
+        with self.spans.launch("sim_targets"):
+            body = self._roundtrip(payload)
+        return self._unpack(body)
 
     def close(self) -> None:
         with self._lock:

@@ -16,8 +16,6 @@ import time
 from contextlib import contextmanager
 from typing import Optional
 
-from kueue_tpu.obs.span import COUNT_KEYS
-
 LEVELS = {"debug": 10, "info": 20, "warning": 30, "error": 40}
 
 
@@ -89,7 +87,7 @@ def attach_engine_logging(engine, stream=None,
         result = original()
         if result is not None and engine.last_cycle_phases:
             logger.debug("cycle", **{
-                k if k in COUNT_KEYS else f"phase_{k}_s": round(v, 6)
+                k if k.startswith("n_") else f"phase_{k}_s": round(v, 6)
                 for k, v in engine.last_cycle_phases.items()})
         return result
 

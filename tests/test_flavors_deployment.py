@@ -177,10 +177,15 @@ def test_the_nominations_spans_and_counts():
         "sim_targets"]
     launch, p = box.children[2].attrs, phases[-1]
     assert set(launch) == {"rows", "rows_padded", "launches", "bytes",
-                           "upload_s", "device_wait_s", "readback_s"}
+                           "upload_s", "device_wait_s", "readback_s",
+                           "launched_s"}
     assert launch["rows_padded"] == launch["launches"] * 32
     assert 0 < (launch["upload_s"] + launch["device_wait_s"]
                 + launch["readback_s"]) <= p["sim_launch"]
+    # The launches' windows: from the sim program's dispatch to its
+    # answers being ready, inside the span.
+    assert launch["device_wait_s"] <= launch["launched_s"] \
+        <= p["sim_launch"]
     assert {"n_sim_heads", "n_sim_rows", "n_sim_launches",
             "n_sim_overflow"} <= span_mod.COUNT_KEYS
     assert p["n_sim_heads"] == box.attrs["heads"] > 0

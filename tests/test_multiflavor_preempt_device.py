@@ -250,4 +250,5 @@ def test_two_pod_sets_on_one_flavor_preempt_as_sequential(seed):
     assert {shape for shape, _, _ in launches} == {(2, 2)}
     assert any(slots for _, slots, _ in launches)
     assert any(victims for _, _, victims in launches)
-    assert bat.spans.last().children[1].attrs["preempt_columns"] == 4
+    (cycle,) = [c for c in bat.spans.last().children if c.name == "cycle"]
+    assert cycle.attrs["preempt_columns"] == 4

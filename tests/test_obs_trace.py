@@ -73,6 +73,12 @@ class TestSpanTrees:
         assert CID_RE.match(root.attrs["cid"])
         assert root.dur >= 0
         phases = [s for s in root.children if s.kind == "phase"]
+        # The engine's events before the cycle (the two submits): first,
+        # ending where the cycle begins.
+        intake = phases.pop(0)
+        assert intake.name == "phase/intake"
+        assert intake.ts + intake.dur == pytest.approx(root.ts)
+        assert [c.name for c in intake.children] == ["phase/submit"]
         assert {s.name for s in phases} >= {
             "phase/pre_hooks", "phase/snapshot", "phase/decide",
             "phase/apply"}
@@ -253,6 +259,9 @@ class TestPerfettoExport:
         assert n == len(doc["traceEvents"])
         phases = {e["ph"] for e in doc["traceEvents"]}
         assert phases == {"M", "X", "i"}
+        # The engine's events before each cycle, on the cycles' lane.
+        assert {e["name"] for e in doc["traceEvents"]} >= {
+            "phase/intake", "phase/submit"}
         # The decision lane carries the rationale args.
         instants = [e for e in doc["traceEvents"] if e["ph"] == "i"]
         assert any(e["args"].get("decision") == "preempting"
@@ -275,6 +284,11 @@ class TestPerfettoExport:
         assert CID_RE.match(roots[0].attrs["cid"])
         wl = [s for s in roots[0].children if s.kind == "workload"]
         assert wl and wl[0].attrs["decision"] == "admitted"
+        # The recorded intake (the submit) is laid just before the cycle.
+        (intake,) = [s for s in roots[0].children
+                     if s.name == "phase/intake"]
+        assert intake.attrs["seconds"] > 0
+        assert intake.ts + intake.dur == pytest.approx(roots[0].ts)
         out = str(tmp_path / "trace.json")
         write_perfetto(roots, out)
         with open(out, encoding="utf-8") as fh:

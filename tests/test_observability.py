@@ -167,10 +167,14 @@ def test_phase_timing_recorded():
     submit(eng, "w", 500)
     eng.schedule_once()
     # The sequential path's leaves, what brackets them in
-    # schedule_once(), and the two totals (obs.span.phase_seconds).
+    # schedule_once(), the two totals (obs.span.phase_seconds), and the
+    # window's keys: the submit before the cycle, no launch.
+    from kueue_tpu.obs.span import WINDOW_KEYS
+
     assert set(eng.last_cycle_phases) == {
         "pre_hooks", "snapshot", "decide", "apply", "listeners",
-        "unattributed", "schedule_once"}
+        "unattributed", "schedule_once"} | WINDOW_KEYS
+    assert eng.last_cycle_phases["n_intake_calls"] == 1
     assert all(v >= 0 for v in eng.last_cycle_phases.values())
     h = eng.registry.histogram("scheduler_phase_duration_seconds")
     assert h.totals[("decide",)] == 1

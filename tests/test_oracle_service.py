@@ -126,6 +126,13 @@ def test_remote_cycle_records_the_wire_spans(oracle_proc):
     assert wire_spans["readback"].attrs["bytes"] > 0
     assert {"upload", "device_wait", "readback"} <= set(
         eng.last_cycle_phases)
+    # The round trip is the launch's window as the engine sees it: the
+    # engine's host is not bound while it waits on the service.
+    ph = eng.last_cycle_phases
+    assert wire_spans["device_wait"].attrs["launched_s"] \
+        == ph["device_launched"] > 0
+    assert ph["host_bound"] + ph["device_launched"] == pytest.approx(
+        ph["intake"] + ph["schedule_once"])
 
 
 def test_remote_roundtrip_tensor_integrity(oracle_proc):

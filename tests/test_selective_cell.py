@@ -148,9 +148,9 @@ def test_benchmark_json_holds_the_config_the_cell_and_both_metrics():
         for e in bench[section]:
             for key in ("source", "why"):
                 assert 1 <= len(e.get(key, "x")) <= 200, (e["name"], key)
-    last = bench["per_layer"][-len(NEW_READERS):]
-    assert [m["name"] for m in last] == list(NEW_READERS)
-    for m in last:
+    mine = [m for m in bench["per_layer"] if m["name"] in NEW_READERS]
+    assert [m["name"] for m in mine] == list(NEW_READERS)
+    for m in mine:
         assert m["workloads"] == [CELL]
         assert (m["unit"], m["moves"], m["source"], m["layer"]) == (
             "count", "cycle_mean_ms", "program_span", "sim nomination")

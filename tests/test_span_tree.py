@@ -32,6 +32,7 @@ from kueue_tpu.obs.span import (  # noqa: E402
     AGGREGATE_KEYS,
     CONTAINERS,
     COUNT_KEYS,
+    WINDOW_KEYS,
     SpanRecorder,
     leaf_phases,
     phase_seconds,
@@ -101,9 +102,16 @@ def child(span, name):
 
 
 def assert_nested(span):
-    """Children lie inside their parent, in order, without overlap."""
+    """Children lie inside their parent, in order, without overlap; a
+    root's ``intake`` ends where the root begins, and its tallies (the
+    sums of calls that may interleave) lie inside it."""
     end = span.ts
     for c in span.children:
+        if c.name == "intake":
+            assert c.ts + c.dur == pytest.approx(span.ts)
+            for t in c.children:
+                assert c.ts <= t.ts and t.ts + t.dur <= span.ts + 1e-3
+            continue
         assert c.ts >= end - 1e-3, (span.name, c.name)
         assert c.dur >= 0
         end = c.ts + c.dur
@@ -200,13 +208,13 @@ def test_sequential_cycle_tree():
     _, root = cycle(eng)
     assert root.name == "schedule_once"
     assert root.attrs == {"seq": 0, "mode": "sequential"}
-    assert names(root) == ["pre_hooks", "snapshot", "decide", "apply",
-                           "listeners"]
+    assert names(root) == ["intake", "pre_hooks", "snapshot", "decide",
+                           "apply", "listeners"]
     assert_nested(root)
     assert_adds_up(eng.last_cycle_phases)
     assert set(eng.last_cycle_phases) == {
         "pre_hooks", "snapshot", "decide", "apply", "listeners",
-        "unattributed", "schedule_once"}
+        "unattributed", "schedule_once"} | WINDOW_KEYS
 
 
 def test_device_cycle_tree():
@@ -215,7 +223,7 @@ def test_device_cycle_tree():
     submit(eng, "b", 400)
     _, root = cycle(eng)
     assert root.attrs == {"seq": 0, "mode": "device"}
-    assert names(root) == ["pre_hooks", "cycle", "listeners"]
+    assert names(root) == ["intake", "pre_hooks", "cycle", "listeners"]
     cyc = child(root, "cycle")
     assert names(cyc) == ENCODE + COMMIT
     assert cyc.attrs == {"lattice": False, "preempt_slots": 0,
@@ -278,8 +286,8 @@ def test_fallback_cycle_keeps_both_attempts_in_one_tree():
     submit(eng, "partial", 400, min_count=1)  # every root is the host's
     _, root = cycle(eng)
     assert eng.oracle.fallback_reasons == {"all-host": 1}
-    assert names(root) == ["pre_hooks", "cycle", "snapshot", "decide",
-                           "apply", "listeners"]
+    assert names(root) == ["intake", "pre_hooks", "cycle", "snapshot",
+                           "decide", "apply", "listeners"]
     assert names(child(root, "cycle")) == ["host_encode"]
     ph = eng.last_cycle_phases
     assert "encode" not in ph and "device" not in ph  # no verdict came
@@ -307,8 +315,10 @@ def test_phase_keys_are_sums_over_everything_that_ran():
     assert ph["schedule_once"] == pytest.approx(root.dur * 1e-6)
     assert ph["tas_place"] == pytest.approx(
         child(child(cyc, "host_encode"), "tas_place").dur * 1e-6)
-    # Unattributed is the two containers' self time.
-    self_time = sum(b.dur - sum(c.dur for c in b.children)
+    # Unattributed is the two containers' self time (the intake lies
+    # before the root, outside it).
+    self_time = sum(b.dur - sum(c.dur for c in b.children
+                                if c.name != "intake")
                     for b in (root, cyc)) * 1e-6
     # (`sim_nomination`, the third, is in a tree only where a head's
     # flavor choice needs simulations: tests/test_flavors_deployment.py.)
@@ -333,10 +343,11 @@ def test_phase_keys_are_sums_over_everything_that_ran():
         "n_commit_victim_entries": 0, "n_reclaim_victims": 0,
         "n_mask_narrowed_heads": 0}
     assert not COUNT_KEYS & set(leaf_phases(ph))
-    # The histogram takes the leaves and the whole, no aggregate.
+    # The histogram takes the leaves, the whole and the intake before
+    # it, no aggregate.
     h = eng.registry.histogram("scheduler_phase_duration_seconds")
     assert {k for (k,) in h.totals} == \
-        set(leaf_phases(ph)) | {"schedule_once"}
+        set(leaf_phases(ph)) | {"schedule_once", "intake"}
 
 
 def test_phases_are_set_before_the_listeners_and_closed_after():
@@ -346,11 +357,14 @@ def test_phases_are_set_before_the_listeners_and_closed_after():
         lambda seq, result: seen.update(eng.last_cycle_phases))
     submit(eng, "w", 500)
     eng.schedule_once()
-    assert set(seen) == {"pre_hooks", "snapshot", "decide", "apply"}
+    assert set(seen) == {"pre_hooks", "snapshot", "decide",
+                         "apply"} | WINDOW_KEYS - {"host_bound"}
     assert set(eng.last_cycle_phases) - set(seen) == {
-        "listeners", "unattributed", "schedule_once"}
+        "listeners", "unattributed", "schedule_once", "host_bound"}
     h = eng.registry.histogram("scheduler_phase_duration_seconds")
-    assert {k for (k,) in h.totals} == set(eng.last_cycle_phases)
+    assert {k for (k,) in h.totals} == \
+        set(leaf_phases(eng.last_cycle_phases)) | {"schedule_once",
+                                                    "intake"}
     # An idle cycle leaves the last deciding cycle's phases in place.
     before = dict(eng.last_cycle_phases)
     assert eng.schedule_once() is None
@@ -619,6 +633,7 @@ def test_profiler_capture_holds_the_tree(tmp_path):
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         submit(eng, "x", 100)
+        eng.finish("default/w0")
         for _ in range(3):
             eng.schedule_once()
     finally:
@@ -649,3 +664,12 @@ def test_profiler_capture_holds_the_tree(tmp_path):
         (decode,) = inside("kueue.verdict_decode", cyc)
         assert host[2] <= dispatch[1] and dispatch[2] <= wait[1] \
             and wait[2] <= readback[1] and readback[2] <= decode[1]
+        # The launch's window: from inside the dispatch to the end of
+        # the wait.
+        (launch,) = inside("kueue.launch", cyc)
+        assert dispatch[1] <= launch[1] <= dispatch[2] \
+            and wait[1] <= launch[2] <= wait[2]
+    # The client's calls before the first cycle, on the same clock.
+    (sub,) = [e for e in events if e[0] == "kueue.submit"]
+    (fin,) = [e for e in events if e[0] == "kueue.finish"]
+    assert sub[2] <= fin[1] and fin[2] <= roots[0][1]
