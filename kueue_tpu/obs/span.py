@@ -48,9 +48,9 @@ real span tree per ``Engine.schedule_once()``, where the work happens:
     │  │  ├─ sim_rows             one row a Preempt-gated cell
     │  │  ├─ sim_launch           the sim program, one block of rows
     │  │  │                       a launch (attrs rows, rows_padded,
-    │  │  │                       launches; bytes moved, upload_s,
-    │  │  │                       device_wait_s, readback_s,
-    │  │  │                       launched_s)
+    │  │  │                       launches, rows_classified; bytes
+    │  │  │                       moved, upload_s, device_wait_s,
+    │  │  │                       readback_s, launched_s)
     │  │  ├─ fungibility_fold     the flavor walk, array code
     │  │  └─ sim_targets          the cycle program's slot overrides
     │  ├─ upload                  host arrays -> device (attrs bytes)
@@ -200,6 +200,9 @@ AGGREGATE_KEYS = frozenset({"tas_place", "schedule_once", "encode",
 #       needed preemption simulations, the (head, flavor, resource)
 #       cells simulated, the sim program's launches, and the heads the
 #       sim program handed to the host (more candidates than it scans)
+#   n_sim_rows_classified  the ``sim_launch`` span's attr
+#       ``rows_classified``: the rows the sim program's launches
+#       classified, each launch its live rows in whole chunks
 #   n_mask_narrowed_heads  attr ``mask_narrowed_heads`` of the
 #       ``sim_nomination`` span, or of ``host_encode`` where no nomination
 #       runs: the cycle's heads whose flavor mask (labels, taints,
@@ -213,8 +216,8 @@ COUNT_KEYS = frozenset({"n_launches", "n_lattice_launches",
                         "n_device_cycles", "n_device_heads",
                         "n_commit_victim_entries", "n_reclaim_victims",
                         "n_sim_heads", "n_sim_rows", "n_sim_launches",
-                        "n_sim_overflow", "n_mask_narrowed_heads",
-                        "n_masked_flavor_cells"})
+                        "n_sim_overflow", "n_sim_rows_classified",
+                        "n_mask_narrowed_heads", "n_masked_flavor_cells"})
 
 # The engine's entry points the ``intake`` tree tallies, by kind.
 TALLY_KINDS = ("finish", "submit", "restore", "tick")
@@ -561,6 +564,9 @@ def phase_seconds(root: Span) -> dict:
                     _mask_counts(c, out)
                 continue
             _add(out, c.name, c.dur * 1e-6)
+            if c.name == "sim_launch":
+                _add(out, "n_sim_rows_classified",
+                     c.attrs.get("rows_classified", 0))
             _mask_counts(c, out)  # host_encode, where no nomination ran
             for s in c.children:  # tas_place, in host_encode
                 if s.name in AGGREGATE_KEYS:

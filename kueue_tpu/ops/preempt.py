@@ -310,6 +310,9 @@ def classical_targets_impl(
     hold; such a slot is reported and must fall back to the host
     preemptor.
 
+    A slot is _preemptor_rows' classify and then its scan, under one
+    vmap over the slots.
+
     Returns per slot, the victims packed to V columns (the targets in
     candidate order; for a slot decided in its first window, that
     window's candidates with `taken` marking the targets):
@@ -326,11 +329,99 @@ def classical_targets_impl(
       skipped int32[C] — the ordered candidates the scans passed over
         as invalid before the slot fit or ran out (both attempts).
     """
-    C, S = slot_req.shape
+    classify, scan, _fill = _preemptor_rows(
+        slot_req.shape[1], wcq_policy, reclaim_policy, bwc_forbidden,
+        bwc_threshold, cq_has_parent, adm_cq, adm_pri, adm_ts, adm_qrt,
+        adm_uid, adm_evicted, adm_usage, usage, subtree_quota, lend_limit,
+        borrow_limit, nominal, ancestors, height, local_chain, root_nodes,
+        root_of_cq, adm_rank, adm_by_root, depth=depth, v_cap=v_cap)
+
+    def per_slot(c, need, p_pri, p_ts, frs, req):
+        return scan(c, need, frs, req,
+                    *classify(c, need, p_pri, p_ts, frs, req))
+
+    if slot_cq is None:
+        slot_cq = jnp.arange(slot_req.shape[0], dtype=jnp.int32)
+    return jax.vmap(per_slot)(
+        slot_cq, slot_need, slot_pri, slot_ts, slot_fr, slot_req)
+
+
+class _SlotTables(NamedTuple):
+    """A slot's view of its root's quota tables (_preemptor_rows): what
+    both parts of the preemptor read of them, a few hundred values a
+    slot. Root-local tables lie on ONE flat axis [S * K], resource s of
+    node r at s * K + r, the slot's chosen flavor-resources as the
+    columns."""
+    frs_safe: jax.Array  # int32[S] the slot's columns (0 where none)
+    active: jax.Array  # bool[S] a column the slot requests
+    usage_l0: jax.Array  # int64[S * K] cycle-start usage
+    sq_l: jax.Array  # int64[S * K] subtree quota
+    lq_l: jax.Array  # int64[S * K] local quota
+    height_l: jax.Array  # int32[K]
+    chain_ok_c: jax.Array  # bool[D+1] the slot's own chain
+    loc_c_safe: jax.Array  # int32[D+1] its rows (0 where none)
+    sq_c: jax.Array  # int64[D+1, S] along it
+    lq_c: jax.Array
+    bl_c: jax.Array
+    nom_cq: jax.Array  # int64[S] its ClusterQueue's nominal
+    usage_c0: jax.Array  # int64[D+1, S] cycle-start usage along it
+    need_fr: jax.Array  # bool[S] the columns the request does not fit
+
+
+class _Classified(NamedTuple):
+    """What a slot's scans read of its classify (_preemptor_rows): per
+    candidate of the slot's root (A_l of them), and per slot. The chain
+    is held a level a row, so that a block of these (the sim program's)
+    has no short minor axis."""
+    key: jax.Array  # int64[A_l] the candidate order, unique; others last
+    cand: jax.Array  # bool[A_l] a candidate of the slot
+    variant: jax.Array  # int32[A_l] V_*
+    same: jax.Array  # bool[A_l] of the slot's own ClusterQueue
+    lca_pos: jax.Array  # int32[A_l] where its chain meets the slot's
+    loc: jax.Array  # int32[D+1, A_l] its chain, root-local rows
+    v_ids: jax.Array  # int32[V] the first V of the order (-1: none)
+    allow: jax.Array  # bool[2] each attempt's allow_borrow
+    en2: jax.Array  # bool: a second attempt where the first fails
+
+
+class _Gathered(NamedTuple):
+    """The slot's root's admitted rows as its classify gathered them,
+    which the scans read where both run under one vmap."""
+    ok: jax.Array  # bool[A_l] a row, not a pad
+    loc: jax.Array  # int32[A_l, D+1] its chain, root-local rows
+    usage: jax.Array  # int64[A_l, S] what it holds of the slot's columns
+
+
+def _preemptor_rows(
+    S, wcq_policy, reclaim_policy, bwc_forbidden, bwc_threshold,
+    cq_has_parent, adm_cq, adm_pri, adm_ts, adm_qrt, adm_uid, adm_evicted,
+    adm_usage, usage, subtree_quota, lend_limit, borrow_limit, nominal,
+    ancestors, height, local_chain, root_nodes, root_of_cq, adm_rank,
+    adm_by_root, *, depth: int, v_cap: int,
+):
+    """One slot of the classical preemptor (classical_targets_impl, whose
+    arguments these are, less the slots' own; ``S`` the slot's columns)
+    in two parts:
+
+      classify(c, need, p_pri, p_ts, frs, req) -> (_Classified,
+        _Gathered): the work that scales with the candidates — gather
+        the root's admitted rows, LCA level and position, variant, the
+        within-nominal check, which are candidates, the attempt
+        sequencing and the order;
+      scan(c, need, frs, req, classified, gathered=None) -> the slot's
+        outputs: the attempts' greedy scans, the walk beyond the first
+        window and fill-back. Without ``gathered`` it reads what it
+        needs of the root's rows from the world.
+
+    and ``fill``, the _Classified of a slot with no candidate, whose
+    scans find nothing. The cycle program runs both parts under one
+    vmap; the sim program classifies its live rows only
+    (sim_targets)."""
     A = adm_cq.shape[0]
     A_l = A if adm_by_root is None else adm_by_root.shape[1]
     V = min(v_cap, A_l)
     K = root_nodes.shape[1]
+    NO_LCA = depth + 9
     lq_all = local_quota(subtree_quota, lend_limit)
     if adm_rank is None:
         adm_rank = jnp.zeros((A,), jnp.int64).at[
@@ -344,25 +435,103 @@ def classical_targets_impl(
     # Level e of a candidate's chain can lie strictly below its LCA with
     # the preemptor only where some chain of the world has a level e + 1.
     levels_below_an_lca = jnp.any(adm_loc[:, 1:] >= 0, axis=0)  # [D]
+    node_at = jnp.tile(jnp.arange(K, dtype=jnp.int32), S)
+    # Column-major, so that a scan's loop that reads it does not hold it
+    # padded to 128 lanes a row.
+    usage_by_col = adm_usage.T  # [R, A]
 
-    def per_slot(c, need, p_pri, p_ts, frs, req):
+    def root_rows(c):
+        """The global ids of the slot's root's admitted rows (-1 pad),
+        or None where every admitted row is in scope."""
+        return None if adm_by_root is None else adm_by_root[root_of_cq[c]]
+
+    def rows(tbl, r):
+        """Row(s) ``r`` of a root-local table: [..., S]."""
+        return jnp.stack([tbl[r + s * K] for s in range(S)], axis=-1)
+
+    def add_row(tbl, r, val):
+        """``val`` [S] added to row ``r`` of a root-local table, as
+        one elementwise pass (a scatter is a pass a cell there)."""
+        return tbl + jnp.where(node_at == r, jnp.repeat(val, K), 0)
+
+    def slot_tables(c, frs, req):
+        # No axis of size S is there to be laid out: the chip's compiler
+        # makes the window axis of a row's scatter the minor one and
+        # pads it to 128 lanes, whatever its place in the source — with
+        # two resources a [K, S] table held 64 times its data, and the
+        # scans carried, copied and scattered into that (PERF.md, PR 35;
+        # tools/tpu_layouts.py shows what a program was compiled to).
         frs_safe = jnp.maximum(frs, 0)
         active = (frs >= 0) & (req > 0)
+        nodes = root_nodes[root_of_cq[c]]  # [K]
+        nodes_safe = jnp.maximum(nodes, 0)
+        node_ok = nodes >= 0
+        flat_node = jnp.tile(nodes_safe, S)  # [S * K]
+        flat_ok = jnp.tile(node_ok, S)
+        flat_fr = jnp.repeat(frs_safe, K)
 
+        def gather_l(arr):
+            return jnp.where(flat_ok, arr[flat_node, flat_fr], 0)
+
+        usage_l0 = gather_l(usage)
+        sq_l = gather_l(subtree_quota)
+        lq_l = gather_l(lq_all)
+        loc_c = local_chain[c]  # [D+1] positions into K
+        chain_ok_c = loc_c >= 0
+        loc_c_safe = jnp.maximum(loc_c, 0)
+        # The slot's own chain, [D+1, S]; row 0 is its ClusterQueue's.
+        sq_c, lq_c, bl_c = (rows(t, loc_c_safe)
+                            for t in (sq_l, lq_l, gather_l(borrow_limit)))
+        usage_c0 = rows(usage_l0, loc_c_safe)
+        avail0 = available_along_chain(
+            chain_ok_c, sq_c, lq_c, bl_c, usage_c0, depth=depth)
+        return _SlotTables(
+            frs_safe, active, usage_l0, sq_l, lq_l,
+            jnp.where(node_ok, height[nodes_safe], 0), chain_ok_c,
+            loc_c_safe, sq_c, lq_c, bl_c,
+            rows(gather_l(nominal), loc_c_safe[0]), usage_c0,
+            active & (req > avail0))
+
+    # Within-nominal pruning (collectCandidatesInSubtree +
+    # candidateIsValid): every node on the candidate's chain strictly
+    # below the LCA must be above nominal in some needed resource.
+    # Level-wise loop keeps peak memory at O(A * S).
+    def path_within_nominal(t, loc_level, lca_pos, usage_l):
+        """Which candidates have a node below the LCA within nominal;
+        ``loc_level(e)`` is level e of the candidates' chains, int32[A_l].
+        A level that no chain of the world reaches above is not
+        looked at: the predicate is the launch's, not the slot's,
+        so the branch is a real one under the vmap."""
+        wn_cell = (t.sq_l >= usage_l) | ~jnp.repeat(t.need_fr, K)
+        wn_row = wn_cell[:K]
+        for s in range(1, S):
+            wn_row = wn_row & wn_cell[s * K:(s + 1) * K]
+        bad = jnp.zeros((A_l,), bool)
+        for e in range(depth):  # level `depth` is never below an LCA
+            def at_level(bad, e=e):
+                loc_e = loc_level(e)
+                below = (e < lca_pos) & (loc_e >= 0)
+                return bad | (below & wn_row[jnp.maximum(loc_e, 0)])
+
+            bad = jax.lax.cond(levels_below_an_lca[e], at_level,
+                               lambda bad: bad, bad)
+        return bad
+
+    def classify(c, need, p_pri, p_ts, frs, req):
+        t = slot_tables(c, frs, req)
         # Candidate scope: with adm_by_root, gather ONLY the slot's
         # root's admitted rows (candidates never cross cohort roots —
         # candidate_generator.go walks the preemptor's hierarchy) so all
         # per-candidate work is O(max admitted per root), not O(A).
-        if adm_by_root is None:
+        g_rows = root_rows(c)
+        if g_rows is None:
             l_ok = jnp.ones((A,), bool)
             l_cq, l_pri, l_ts = adm_cq, adm_pri, adm_ts
             l_ev = adm_evicted
             l_usage = adm_usage
             l_chain, l_loc = adm_chain, adm_loc
             l_rank = adm_rank
-            g_rows = None
         else:
-            g_rows = adm_by_root[root_of_cq[c]]  # [A_l] global ids
             l_ok = g_rows >= 0
             rsafe = jnp.maximum(g_rows, 0)
             l_cq = jnp.where(l_ok, adm_cq[rsafe], -1)
@@ -375,84 +544,24 @@ def classical_targets_impl(
             # Pad rows sort last; ties among pads are irrelevant (they
             # can never be candidates).
             l_rank = jnp.where(l_ok, adm_rank[rsafe], A)
-
-        # Root-local state over the slot's root: one value a (node,
-        # resource) on ONE flat axis [S * K], resource s of node r at
-        # s * K + r, the slot's chosen flavor-resources as the columns.
-        # No axis of size S is there to be laid out: the chip's compiler
-        # makes the window axis of a row's scatter the minor one and
-        # pads it to 128 lanes, whatever its place in the source — with
-        # two resources a [K, S] table held 64 times its data, and the
-        # scans carried, copied and scattered into that (PERF.md, PR 35;
-        # tools/tpu_layouts.py shows what a program was compiled to).
-        nodes = root_nodes[root_of_cq[c]]  # [K]
-        nodes_safe = jnp.maximum(nodes, 0)
-        node_ok = nodes >= 0
-        flat_node = jnp.tile(nodes_safe, S)  # [S * K]
-        flat_ok = jnp.tile(node_ok, S)
-        flat_fr = jnp.repeat(frs_safe, K)
-        node_at = jnp.tile(jnp.arange(K, dtype=jnp.int32), S)
-
-        def gather_l(arr):
-            return jnp.where(flat_ok, arr[flat_node, flat_fr], 0)
-
-        def rows(tbl, r):
-            """Row(s) ``r`` of a root-local table: [..., S]."""
-            return jnp.stack([tbl[r + s * K] for s in range(S)], axis=-1)
-
-        def add_row(tbl, r, val):
-            """``val`` [S] added to row ``r`` of a root-local table, as
-            one elementwise pass (a scatter is a pass a cell there)."""
-            return tbl + jnp.where(node_at == r, jnp.repeat(val, K), 0)
-
-        usage_l0 = gather_l(usage)
-        sq_l = gather_l(subtree_quota)
-        lq_l = gather_l(lq_all)
-        height_l = jnp.where(node_ok, height[nodes_safe], 0)
-        bl_l = gather_l(borrow_limit)
-        nom_l = gather_l(nominal)
-
-        loc_c = local_chain[c]  # [D+1] positions into K
-        chain_ok_c = loc_c >= 0
-        loc_c_safe = jnp.maximum(loc_c, 0)
-        # The slot's own chain, [D+1, S]; row 0 is its ClusterQueue's.
-        sq_c, lq_c, bl_c = (rows(t, loc_c_safe) for t in (sq_l, lq_l, bl_l))
-        nom_cq = rows(nom_l, loc_c_safe[0])
-
-        def fits_with(usage_l, allow_borrow):
-            g_usage = rows(usage_l, loc_c_safe)
-            avail = available_along_chain(
-                chain_ok_c, sq_c, lq_c, bl_c, g_usage, depth=depth)
-            ok = jnp.all(jnp.where(active, req <= avail, True))
-            # workloadFits without borrowing: usage + req must stay within
-            # the CQ's guaranteed quota (preemption.go:624 borrowingWith).
-            nb_ok = jnp.all(jnp.where(
-                active, g_usage[0] + req <= sq_c[0], True))
-            return ok & (allow_borrow | nb_ok)
-
-        usage_c0 = rows(usage_l0, loc_c_safe)
-        avail0 = available_along_chain(
-            chain_ok_c, sq_c, lq_c, bl_c, usage_c0, depth=depth)
-        need_fr = active & (req > avail0)
-        need_cell = jnp.repeat(need_fr, K)  # [S * K]
-        any_need = need & jnp.any(need_fr)
+        any_need = need & jnp.any(t.need_fr)
 
         # Hierarchical-advantage walk (hierarchical_preemption.go:149):
         # adv_before[d] = whether any strict subtree below level d already
         # fits the (remaining) request within quota.
-        lavail_c0 = jnp.maximum(0, lq_c - usage_c0)  # [D+1, S]
+        lavail_c0 = jnp.maximum(0, t.lq_c - t.usage_c0)  # [D+1, S]
         fits_cq = jnp.all(jnp.where(
-            active, sq_c[0] >= usage_c0[0] + req, True))
-        rem = jnp.where(active, jnp.maximum(0, req - lavail_c0[0]), 0)
+            t.active, t.sq_c[0] >= t.usage_c0[0] + req, True))
+        rem = jnp.where(t.active, jnp.maximum(0, req - lavail_c0[0]), 0)
         adv = fits_cq
         adv_before_list = [jnp.asarray(False)]  # level 0 unused
         for d in range(1, depth + 1):
             adv_before_list.append(adv)
-            okd = chain_ok_c[d]
+            okd = t.chain_ok_c[d]
             fits_d = jnp.all(jnp.where(
-                active, sq_c[d] >= usage_c0[d] + rem, True))
+                t.active, t.sq_c[d] >= t.usage_c0[d] + rem, True))
             adv = adv | (fits_d & okd)
-            rem = jnp.where(active, jnp.maximum(0, rem - lavail_c0[d]), 0)
+            rem = jnp.where(t.active, jnp.maximum(0, rem - lavail_c0[d]), 0)
         adv_before = jnp.stack(adv_before_list)  # [D+1]
 
         # --- candidate classification over all admitted workloads ---
@@ -465,7 +574,6 @@ def classical_targets_impl(
         # LCA level: lowest d >= 1 with c_chain[d] on the candidate's
         # chain. Loops over the (short) depth axes to keep peak memory at
         # O(A) per slot.
-        NO_LCA = depth + 9
         lca_level = jnp.full((A_l,), NO_LCA, jnp.int32)
         for d in range(depth, 0, -1):
             on_chain = jnp.zeros((A_l,), bool)
@@ -481,7 +589,7 @@ def classical_targets_impl(
             lca_pos = jnp.where(l_chain[:, e] == lca_node, e, lca_pos)
 
         uses_any = jnp.any(
-            (l_usage[:, frs_safe] > 0) & need_fr[None, :], axis=1)
+            (l_usage[:, t.frs_safe] > 0) & t.need_fr[None, :], axis=1)
         pol = jnp.where(same_cq, wcq_policy[c], reclaim_policy[c])
         pol_gate = jnp.where(
             same_cq, wcq_policy[c] != POLICY_NEVER,
@@ -498,30 +606,8 @@ def classical_targets_impl(
                                 jnp.int32(V_RECLAIM_WITHOUT_BORROWING),
                                 jnp.int32(V_RECLAIM_WHILE_BORROWING))))
 
-        # Within-nominal pruning (collectCandidatesInSubtree +
-        # candidateIsValid): every node on the candidate's chain strictly
-        # below the LCA must be above nominal in some needed resource.
-        # Level-wise loop keeps peak memory at O(A * S).
-        def path_within_nominal(usage_l):
-            """A level that no chain of the world reaches above is not
-            looked at: the predicate is the launch's, not the slot's,
-            so the branch is a real one under the vmap."""
-            wn_cell = (sq_l >= usage_l) | ~need_cell  # [S * K]
-            wn_row = wn_cell[:K]
-            for s in range(1, S):
-                wn_row = wn_row & wn_cell[s * K:(s + 1) * K]
-            bad = jnp.zeros((A_l,), bool)
-            for e in range(depth):  # level `depth` is never below an LCA
-                def at_level(bad, e=e):
-                    loc_e = l_loc[:, e]
-                    below = (e < lca_pos) & (loc_e >= 0)
-                    return bad | (below & wn_row[jnp.maximum(loc_e, 0)])
-
-                bad = jax.lax.cond(levels_below_an_lca[e], at_level,
-                                   lambda bad: bad, bad)
-            return bad
-
-        static_path_ok = ~path_within_nominal(usage_l0)
+        static_path_ok = ~path_within_nominal(
+            t, lambda e: l_loc[:, e], lca_pos, t.usage_l0)
 
         is_cand = (any_need & uses_any & pol_gate & pol_ok
                    & (same_cq | (same_root & has_lca & static_path_ok)))
@@ -530,14 +616,11 @@ def classical_targets_impl(
         no_other = ~jnp.any(is_cand & ~same_cq)
         no_hier = ~jnp.any(is_cand & (bucket == 0))
         under_nominal = jnp.all(jnp.where(
-            need_fr, nom_cq > usage_c0[0], True))
+            t.need_fr, t.nom_cq > t.usage_c0[0], True))
 
         # Attempt sequencing (preemption.go:287-311).
         case1 = no_other | (bwc_forbidden[c] & ~under_nominal)
         case2 = ~case1 & bwc_forbidden[c] & no_hier
-        b1 = jnp.where(case2, False, True)
-        b2 = jnp.where(case2, True, False)
-        en2 = ~case1
 
         # Ordering: evicted first, bucket, priority asc, reservation
         # recency desc, uid asc; non-candidates last. Only is_cand and
@@ -549,16 +632,69 @@ def classical_targets_impl(
                + jnp.where(l_ev, 0, 1)) * 4 + bucket
         key = lvl.astype(jnp.int64) * (A + 1) + l_rank
         order = jnp.argsort(key).astype(jnp.int32)
+        return (_Classified(key, is_cand, variant, same_cq, lca_pos,
+                            l_loc.T,
+                            order[:V], jnp.stack([~case2, case2]), ~case1),
+                _Gathered(l_ok, l_loc, l_usage[:, t.frs_safe]))
+
+    fill = _Classified(
+        key=jnp.full((A_l,), jnp.iinfo(jnp.int64).max, jnp.int64),
+        cand=jnp.zeros((A_l,), bool),
+        variant=jnp.zeros((A_l,), jnp.int32),
+        same=jnp.zeros((A_l,), bool),
+        lca_pos=jnp.full((A_l,), NO_LCA, jnp.int32),
+        loc=jnp.full((depth + 1, A_l), -1, jnp.int32),
+        v_ids=jnp.full((V,), -1, jnp.int32),
+        allow=jnp.asarray([True, False]),
+        en2=jnp.asarray(False))
+
+    def scan(c, need, frs, req, cl, gathered=None):
+        t = slot_tables(c, frs, req)
+        g_rows = root_rows(c)
+        if gathered is not None:  # classify's, under the same vmap
+            l_ok = gathered.ok
+
+            def loc_level(e):
+                return gathered.loc[:, e]
+
+            def rows_at(at):
+                return gathered.loc[at], gathered.usage[at]
+        else:
+            # No copy of the root's rows is kept (the sim program's
+            # block): a window reads its V rows from the world, the walk
+            # the candidates' chains a level at a time.
+            l_ok = jnp.ones((A_l,), bool) if g_rows is None else g_rows >= 0
+            loc_level = cl.loc.__getitem__
+
+            def rows_at(at):
+                g = at if g_rows is None else g_rows[at]
+                held = usage_by_col[t.frs_safe[:, None],
+                                    jnp.maximum(g, 0)[None, :]]  # [S, V]
+                return cl.loc[:, at].T, jnp.where(g >= 0, held, 0).T
+
+        any_need = need & jnp.any(t.need_fr)
+        key, is_cand, variant, same_cq = cl.key, cl.cand, cl.variant, cl.same
         n_cand = jnp.sum(is_cand.astype(jnp.int32))
+
+        def fits_with(usage_l, allow_borrow):
+            g_usage = rows(usage_l, t.loc_c_safe)
+            avail = available_along_chain(
+                t.chain_ok_c, t.sq_c, t.lq_c, t.bl_c, g_usage, depth=depth)
+            ok = jnp.all(jnp.where(t.active, req <= avail, True))
+            # workloadFits without borrowing: usage + req must stay within
+            # the CQ's guaranteed quota (preemption.go:624 borrowingWith).
+            nb_ok = jnp.all(jnp.where(
+                t.active, g_usage[0] + req <= t.sq_c[0], True))
+            return ok & (allow_borrow | nb_ok)
 
         def window(ids):
             """V candidates by local id (-1: none)."""
             at = jnp.maximum(ids, 0)
+            loc, held = rows_at(at)
             return _Window(is_cand[at] & (ids >= 0), variant[at],
-                           same_cq[at], l_loc[at], lca_pos[at],
-                           l_usage[at][:, frs_safe])
+                           same_cq[at], loc, cl.lca_pos[at], held)
 
-        v_ids = order[:V]  # [V]
+        v_ids = cl.v_ids  # [V]
         first = window(v_ids)
 
         # A chain's rows are distinct, so what a level reads of its row
@@ -569,7 +705,7 @@ def classical_targets_impl(
             """resource_node.go:156 removeUsage along one chain."""
             row_ok = loc >= 0
             r = jnp.maximum(loc, 0)
-            ssp = rows(usage_l, r) - rows(lq_l, r)  # [D+1, S]
+            ssp = rows(usage_l, r) - rows(t.lq_l, r)  # [D+1, S]
             for e in range(depth + 1):
                 usage_l = add_row(usage_l, r[e],
                                   jnp.where(row_ok[e], -val, 0))
@@ -581,7 +717,7 @@ def classical_targets_impl(
             """resource_node.go:144 addUsage along one chain."""
             row_ok = loc >= 0
             r = jnp.maximum(loc, 0)
-            la = jnp.maximum(0, rows(lq_l, r) - rows(usage_l, r))
+            la = jnp.maximum(0, rows(t.lq_l, r) - rows(usage_l, r))
             for e in range(depth + 1):
                 usage_l = add_row(usage_l, r[e],
                                   jnp.where(row_ok[e], val, 0))
@@ -606,7 +742,7 @@ def classical_targets_impl(
                 r = jnp.maximum(loc, 0)
                 below = (jnp.arange(depth + 1) < win.lca_pos[i]) & (loc >= 0)
                 wn = jnp.all(jnp.where(
-                    need_fr, rows(sq_l, r) >= rows(usage_l, r), True),
+                    t.need_fr, rows(t.sq_l, r) >= rows(usage_l, r), True),
                     axis=1)
                 wn_bad = jnp.any(below & wn)
                 valid = ok & ~bad_borrow & (win.same[i] | ~wn_bad)
@@ -635,7 +771,7 @@ def classical_targets_impl(
             """An attempt over the first V of the order: (usage, ids,
             variants, taken, found, skipped, targets held)."""
             usage_f, taken, found, skipped, _ = scan_window(
-                first, usage_l0, jnp.asarray(False), allow_borrow)
+                first, t.usage_l0, jnp.asarray(False), allow_borrow)
 
             # Fill-back (preemption.go:334): reverse over targets except
             # the last, re-adding any whose re-addition keeps the fit.
@@ -670,8 +806,8 @@ def classical_targets_impl(
                 # valid at this usage: validity only ever goes, so none
                 # is missed, and the scan checks each again.
                 usage_l, held, found, cursor, skipped, _ = c
-                open_ = att_cand & (same_cq
-                                    | ~path_within_nominal(usage_l))
+                open_ = att_cand & (same_cq | ~path_within_nominal(
+                    t, loc_level, cl.lca_pos, usage_l))
                 w_ids = next_window(key, open_, cursor, V)
                 usage_l, w_taken, found, _, last = scan_window(
                     window(w_ids), usage_l, found, allow_borrow)
@@ -731,16 +867,14 @@ def classical_targets_impl(
         # follows its size. The region is entered only where some slot
         # walks on: with few candidates (n_cand <= V in every slot) a
         # launch pays for the first windows alone.
-        allow = jnp.stack([b1, b2])
-
         def attempt(k, attempts):
-            state = first_window(allow[k])
-            enabled = (k == 0) | (en2 & ~attempts[4][0])
+            state = first_window(cl.allow[k])
+            enabled = (k == 0) | (cl.en2 & ~attempts[4][0])
             walks_on = enabled & any_need & ~state[4] & (n_cand > V)
             _, state = jax.lax.while_loop(
                 lambda c: c[0],
                 lambda c: (jnp.asarray(False), beyond_first_window(
-                    c[1], allow[k], walks_on)),
+                    c[1], cl.allow[k], walks_on)),
                 (walks_on, state))
             return jax.tree.map(lambda x, new: x.at[k].set(new),
                                 attempts, state)
@@ -748,36 +882,37 @@ def classical_targets_impl(
         (u, ids, var, tk, f, s, n_held) = jax.lax.fori_loop(
             0, 2, attempt, jax.tree.map(
                 lambda x: jnp.zeros((2,) + x.shape, x.dtype),
-                jax.eval_shape(first_window, b1)))
+                jax.eval_shape(first_window, cl.allow[0])))
 
         def borrow_after_height(usage_l):
             """FindHeightOfLowestSubtreeThatFits
             (classical/hierarchical_preemption.go:221) against a
             root-local usage state; max over the slot's resources."""
-            usage_c = rows(usage_l, loc_c_safe)  # [D+1, S]
-            lavail = jnp.maximum(0, lq_c - usage_c)
-            borrowing_cq = nom_cq < usage_c[0] + req  # [S]
-            has_par = chain_ok_c[1] if depth >= 1 else jnp.asarray(False)
+            usage_c = rows(usage_l, t.loc_c_safe)  # [D+1, S]
+            lavail = jnp.maximum(0, t.lq_c - usage_c)
+            borrowing_cq = t.nom_cq < usage_c[0] + req  # [S]
+            has_par = (t.chain_ok_c[1] if depth >= 1
+                       else jnp.asarray(False))
             remaining = jnp.maximum(0, req - lavail[0])
             found_b = jnp.zeros((req.shape[0],), bool)
             found_h = jnp.zeros((req.shape[0],), jnp.int32)
             for d in range(1, depth + 1):
-                okd = chain_ok_c[d]
-                borrowing = sq_c[d] < usage_c[d] + remaining
+                okd = t.chain_ok_c[d]
+                borrowing = t.sq_c[d] < usage_c[d] + remaining
                 fits_here = okd & ~borrowing & ~found_b
-                found_h = jnp.where(fits_here, height_l[loc_c_safe[d]],
-                                    found_h)
+                found_h = jnp.where(fits_here,
+                                    t.height_l[t.loc_c_safe[d]], found_h)
                 found_b = found_b | fits_here
                 remaining = jnp.where(okd & ~found_b,
                                       jnp.maximum(0, remaining - lavail[d]),
                                       remaining)
             root_h = jnp.int32(0)
             for d in range(depth + 1):
-                root_h = jnp.where(chain_ok_c[d], height_l[loc_c_safe[d]],
-                                   root_h)
+                root_h = jnp.where(t.chain_ok_c[d],
+                                   t.height_l[t.loc_c_safe[d]], root_h)
             h = jnp.where(~borrowing_cq | ~has_par, 0,
                           jnp.where(found_b, found_h, root_h))
-            return jnp.max(jnp.where(active, h, 0))
+            return jnp.max(jnp.where(t.active, h, 0))
 
         f1, f2 = f[0], f[1]
         # More than V targets is the one thing the packed columns cannot
@@ -785,7 +920,7 @@ def classical_targets_impl(
         big1, big2 = f1 & (n_held[0] > V), f2 & (n_held[1] > V)
         ids1, ids2, var1, var2 = ids[0], ids[1], var[0], var[1]
         t1, t2, u1, u2, s1, s2 = tk[0], tk[1], u[0], u[1], s[0], s[1]
-        use2 = ~f1 & en2 & f2
+        use2 = ~f1 & cl.en2 & f2
         overflow = need & any_need & (big1 | (use2 & big2))
         found = (f1 | use2) & any_need & ~overflow
         taken = jnp.where(found, jnp.where(f1, t1, t2),
@@ -794,7 +929,7 @@ def classical_targets_impl(
         borrow_after = jnp.where(
             f1, borrow_after_height(u1),
             jnp.where(use2, borrow_after_height(u2), 0)).astype(jnp.int32)
-        skipped = s1 + jnp.where(en2 & ~f1, s2, 0)
+        skipped = s1 + jnp.where(cl.en2 & ~f1, s2, 0)
 
         ids_ok = ids >= 0
         ids_safe = jnp.maximum(ids, 0)
@@ -808,15 +943,23 @@ def classical_targets_impl(
         return (found, overflow, jnp.sum(taken.astype(jnp.int32)),
                 borrow_after, g_v_ids, taken, g_variant, skipped)
 
-    if slot_cq is None:
-        slot_cq = jnp.arange(C, dtype=jnp.int32)
-    return jax.vmap(per_slot)(
-        slot_cq, slot_need, slot_pri, slot_ts, slot_fr, slot_req)
+    return classify, scan, fill
 
 
-@partial(jax.jit, static_argnames=("depth", "v_cap"))
+# Rows of the sim program's block classified at a time (sim_targets).
+# The classify scales with the rows it is given, the scans hardly do
+# (a step is bound by its ops, not by the rows' bytes), and a block's
+# live rows are a prefix of it: a launch classifies its live prefix in
+# chunks of this many rows and scans the whole block once. On a v5e a
+# chunk costs its rows and next to nothing more, and in the selective
+# benchmark cell a third of the launches have under 64 live rows:
+# PERF.md's findings have the launch times it was chosen from.
+SIM_CHUNK = 64
+
+
+@partial(jax.jit, static_argnames=("depth", "v_cap", "chunk"))
 def sim_targets(*args, slot_cq, adm_rank, adm_by_root, depth: int,
-                v_cap: int):
+                v_cap: int, chunk: int = SIM_CHUNK):
     """The sim program (preemption_oracle.go:41 SimulatePreemption, one
     row per (head, flavor, resource) cell): the classical preemptor over
     a block of rows, returning only what the fungibility fold reads —
@@ -825,13 +968,41 @@ def sim_targets(*args, slot_cq, adm_rank, adm_by_root, depth: int,
     Reclaim). The victim sets stay on the device: the fold does not
     need them, and the final target selection is the cycle program's.
     A row whose targets are more than the packed columns hold reports
-    overflow, which the bridge hands to the host (`sim-overflow`)."""
+    overflow, which the bridge hands to the host (`sim-overflow`).
+
+    The bridge packs the rows that need a simulation to the front of the
+    block (``slot_need`` False pads it): only the rows up to the last
+    that needs one are classified, ``chunk`` (at most the block) at a
+    time in one loop, and a row past them keeps the fill of a row with
+    no candidate. The scans then run once over the whole block. The
+    answers are classical_targets_impl's, row for row."""
+    slot_need, slot_pri, slot_ts, slot_fr, slot_req = args[:5]
     adm_cq = args[10]
-    out = classical_targets_impl(*args, slot_cq=slot_cq,
-                                 adm_rank=adm_rank,
-                                 adm_by_root=adm_by_root, depth=depth,
-                                 v_cap=v_cap)
-    found, overflow, _n, borrow_after, v_ids, taken, _variant, _skip = out
+    classify, scan, fill = _preemptor_rows(
+        slot_req.shape[1], *args[5:], adm_rank=adm_rank,
+        adm_by_root=adm_by_root, depth=depth, v_cap=v_cap)
+    B = slot_need.shape[0]
+    chunk = min(chunk, B)
+    row_args = (slot_cq, slot_need, slot_pri, slot_ts, slot_fr, slot_req)
+    live = jnp.max(jnp.where(slot_need, jnp.arange(1, B + 1), 0))
+
+    def classify_chunk(i, classified):
+        # Where ``chunk`` does not divide the block, the last chunk
+        # starts early and classifies some rows again.
+        lo = jnp.minimum(i * chunk, B - chunk)
+        part, _gathered = jax.vmap(classify)(*(
+            jax.lax.dynamic_slice_in_dim(x, lo, chunk) for x in row_args))
+        return jax.tree.map(
+            lambda buf, new: jax.lax.dynamic_update_slice_in_dim(
+                buf, new, lo, 0), classified, part)
+
+    with jax.named_scope("kueue.sim_classify_chunk"):
+        classified = jax.lax.fori_loop(
+            0, -(-live // chunk), classify_chunk,
+            jax.tree.map(lambda x: jnp.broadcast_to(x, (B,) + x.shape),
+                         fill))
+    found, overflow, _n, borrow_after, v_ids, taken, _variant, _skip = \
+        jax.vmap(scan)(slot_cq, slot_need, slot_fr, slot_req, classified)
     same = jnp.any(taken & (v_ids >= 0)
                    & (adm_cq[jnp.maximum(v_ids, 0)] == slot_cq[:, None]),
                    axis=1)

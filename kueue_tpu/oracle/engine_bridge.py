@@ -693,6 +693,7 @@ class OracleBridge:
 
         from kueue_tpu.ops import assign as aops
         from kueue_tpu.ops import commit as cops
+        from kueue_tpu.ops import preempt as pops
         from kueue_tpu.ops import quota as qops
         from kueue_tpu.scheduler.flavorassigner import PMode
 
@@ -766,8 +767,13 @@ class OracleBridge:
             demote_cq[ci[overflow]] = True
         box.attrs.update(rows=n_rows, launches=launches,
                          overflow=int(np.count_nonzero(demote_cq)))
-        launch.attrs.update(rows=n_rows, rows_padded=launches * block,
-                            launches=launches)
+        # A launch classifies its live rows in whole chunks
+        # (ops/preempt.sim_targets).
+        chunk = min(pops.SIM_CHUNK, block)
+        launch.attrs.update(
+            rows=n_rows, rows_padded=launches * block, launches=launches,
+            rows_classified=sum(-(-min(block, n_rows - lo) // chunk) * chunk
+                                for lo in range(0, n_rows, block)))
 
         # The fungibility fold (findFlavorForPodSets) over every head at
         # once. A simulated cell is Preempt or Reclaim with the borrow

@@ -124,7 +124,8 @@ def _run_sim_targets(tensors: dict, statics: dict, derived=None,
             t["root_nodes"], t["root_of_cq"],
             slot_cq=t["slot_cq"], adm_rank=t["adm_rank"],
             adm_by_root=t["adm_by_root"],
-            depth=statics["depth"], v_cap=statics["v_cap"])
+            depth=statics["depth"], v_cap=statics["v_cap"],
+            chunk=pops.SIM_CHUNK)
         jax.block_until_ready(out)
         t2 = clock()
     host = [np.asarray(o) for o in out]

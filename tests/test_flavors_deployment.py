@@ -176,9 +176,9 @@ def test_the_nominations_spans_and_counts():
         "flavor_grid", "sim_rows", "sim_launch", "fungibility_fold",
         "sim_targets"]
     launch, p = box.children[2].attrs, phases[-1]
-    assert set(launch) == {"rows", "rows_padded", "launches", "bytes",
-                           "upload_s", "device_wait_s", "readback_s",
-                           "launched_s"}
+    assert set(launch) == {"rows", "rows_padded", "launches",
+                           "rows_classified", "bytes", "upload_s",
+                           "device_wait_s", "readback_s", "launched_s"}
     assert launch["rows_padded"] == launch["launches"] * 32
     assert 0 < (launch["upload_s"] + launch["device_wait_s"]
                 + launch["readback_s"]) <= p["sim_launch"]
@@ -187,9 +187,10 @@ def test_the_nominations_spans_and_counts():
     assert launch["device_wait_s"] <= launch["launched_s"] \
         <= p["sim_launch"]
     assert {"n_sim_heads", "n_sim_rows", "n_sim_launches",
-            "n_sim_overflow"} <= span_mod.COUNT_KEYS
+            "n_sim_overflow", "n_sim_rows_classified"} <= span_mod.COUNT_KEYS
     assert p["n_sim_heads"] == box.attrs["heads"] > 0
     assert p["n_sim_rows"] == launch["rows"]
+    assert p["n_sim_rows_classified"] == launch["rows_classified"]
     assert p["n_sim_launches"] == launch["launches"]
     assert p["n_sim_overflow"] == 0
     leaves = span_mod.leaf_phases(p)

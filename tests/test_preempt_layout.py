@@ -15,6 +15,7 @@ where libtpu cannot describe a v5e. Nothing executes: a compile that
 passes is not a chip run."""
 
 import os
+import re
 
 import pytest
 
@@ -27,6 +28,7 @@ from tools import tpu_layouts
 # defaults): what the compiler decides follows the shapes, and a
 # compile costs the same at any.
 ROWS, ROOT_NODES = 1_024, 201  # a block of rows; K nodes a cohort root
+RUNNING_A_ROOT = 2_048  # the candidate axis, A_l
 
 
 @pytest.fixture(scope="module")
@@ -55,20 +57,27 @@ def one_chip():
 @pytest.fixture(scope="module")
 def regions(one_chip):
     """resources -> the `while` regions of the sim program as compiled
-    (~40 s each)."""
+    (~40 s each): those of its scans, and the one loop that classifies
+    the block's live rows a chunk at a time (ops/preempt.sim_targets,
+    named by its scope)."""
     made = {}
 
-    def of(resources: int) -> list:
+    def of(resources: int) -> tuple:
         if resources not in made:
             args, kwargs = tpu_layouts.sim_targets_args(
-                ROWS, resources, ROOT_NODES, 2_048)
+                ROWS, resources, ROOT_NODES, RUNNING_A_ROOT)
             text = tpu_layouts.compile_for_v5e(
                 pops.sim_targets, *args, one_chip=one_chip,
                 **kwargs).as_text()
-            made[resources] = [
-                r for r in tpu_layouts.layout_report(
-                    text, ratio=8.0)  # over the tool's floor, 1 MiB
+            chunked = set(re.findall(
+                r"^\s+%(while[\w.\-]*) = .*op_name=\"[^\"]*"
+                r"kueue\.sim_classify_chunk/while\"", text, re.M))
+            loops = [r for r in tpu_layouts.layout_report(
+                text, ratio=8.0)  # over the tool's floor, 1 MiB
                 if r.kind == "while"]
+            made[resources] = (
+                [r for r in loops if r.loop not in chunked],
+                [r for r in loops if r.loop in chunked])
         return made[resources]
 
     return of
@@ -85,12 +94,24 @@ def _quota_carries(regions, resources):
 
 @pytest.mark.parametrize("resources", [2, 3])
 def test_no_padded_array_in_a_loop_of_the_preemptor(regions, resources):
-    loops = regions(resources)
+    loops, (classify,) = regions(resources)
     assert len(loops) >= 4  # the greedy scan, the fill-back, the walk
     padded = [(r.loop, a.shape, round(a.ratio, 1))
               for r in loops for a in r.padded]
     assert padded == []
     assert _quota_carries(loops, resources)  # the scans do carry it
+    # The loop that classifies a chunk of rows at a time carries the
+    # block's classified rows unpadded. What it holds padded is the
+    # classify's own candidate tables (a root's rows gathered with
+    # their resource or level axis minor, ROADMAP A1 c) and the running
+    # set's, which the program's entry held before, for the whole block
+    # at once: a chunk's rows of them, never the block's.
+    assert [a for a in classify.carry if ROWS in a.dims
+            and a.ratio > 2.0] == []
+    assert classify.padded
+    for a in classify.padded:
+        assert ROWS not in a.dims, a
+        assert a.dims[0] <= pops.SIM_CHUNK * RUNNING_A_ROOT, a
 
 
 @pytest.mark.parametrize("resources", [1, 2, 3])
@@ -99,7 +120,7 @@ def test_quota_carry_is_not_resource_minor(regions, resources):
     axis is the nodes' (or the rows'), as it was with one resource
     before the tables were laid flat (`u32[B,K,1]{1,0,2}`) — no S is
     special-cased."""
-    carries = _quota_carries(regions(resources), resources)
+    carries = _quota_carries(regions(resources)[0], resources)
     assert carries
     for a in carries:
         assert a.ratio <= 2.0, a
